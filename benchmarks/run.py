@@ -21,6 +21,9 @@ def main() -> None:
                                      bench_round_engine)
     from benchmarks.kernel_bench import bench_kernels
     from benchmarks.roofline_bench import bench_roofline
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     benches = [bench_kernels, bench_roofline, bench_accuracy, bench_loss,
                bench_comm_cost, bench_exec_time, bench_noniid_ablation,

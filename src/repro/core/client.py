@@ -5,6 +5,13 @@ The whole local update is one jit'd function per (task, strategy):
 then G generations of the meta-heuristic on the flattened weights with
 fitness = loss on the client's own data (paper Algorithm 3,
 UpdateClient).
+
+The loops are rolled or unrolled by the target backend
+(:func:`unrolls`): XLA:CPU executes convolutions inside while loops
+(``lax.scan`` / ``fori_loop``) ~20x slower than unrolled (no fast conv
+thunk in loop bodies), so on the CPU the client loops are unrolled in
+Python.  Everywhere else they stay rolled, so the compiled program's
+size does not grow with batches, epochs, generations or population.
 """
 from __future__ import annotations
 
@@ -34,7 +41,6 @@ class ClientHP:
     mh_pop: int = 8
     mh_generations: int = 5
     fitness_batches: int = 2
-    unroll: bool = True
     # Beyond-paper (DESIGN.md §3): evolve a low-dimensional subspace
     # instead of the raw weight vector.  The genome is one multiplicative
     # gain per parameter tensor (dim = #leaves, not #params), so BWO on a
@@ -51,14 +57,16 @@ class ClientHP:
     # time stays flat in n_clients while dispatch overhead amortizes.
     # See repro.core.knobs, engine.resolve_vectorize and DESIGN.md §4-5.
     vectorize: str = "auto"
-    # NOTE on ``unroll``: XLA:CPU executes convolutions inside while
-    # loops (lax.scan / fori_loop) ~20x slower than unrolled (no fast
-    # conv thunk in loop bodies).  Client loops here are short and
-    # static, so we unroll them in Python by default; set False for very
-    # long epoch counts on TPU where compile time would dominate.
 
 
-def make_local_sgd(task: Task, hp: ClientHP, masked: bool = False):
+def unrolls(backend: Optional[str] = None) -> bool:
+    """Whether the client loops are Python-unrolled on ``backend``
+    (default: ``jax.default_backend()``): on the CPU only."""
+    return (backend or jax.default_backend()) == "cpu"
+
+
+def make_local_sgd(task: Task, hp: ClientHP, masked: bool = False,
+                   unroll: bool = True):
     """data: dict of arrays with leading (n_batches, batch, ...) dims.
 
     With ``masked=True`` the returned ``local_sgd`` takes an extra
@@ -99,12 +107,12 @@ def make_local_sgd(task: Task, hp: ClientHP, masked: bool = False):
         n_batches = jax.tree.leaves(data)[0].shape[0]
         (params, _), _ = jax.lax.scan(
             one_batch, (params, rng), (data, mask) if masked else data,
-            unroll=n_batches if hp.unroll else 1)
+            unroll=n_batches if unroll else 1)
         return params
 
     def local_sgd(params, data, rng, mask=None):
         anchor = params if hp.prox_mu > 0 else None   # w_global (FedProx)
-        if hp.unroll:
+        if unroll:
             for _ in range(hp.local_epochs):
                 rng, ekey = jax.random.split(rng)
                 params = sgd_epoch(params, data, ekey, anchor, mask)
@@ -140,12 +148,14 @@ def make_fitness_fn(task: Task, data, unravel, n_batches: int,
                     unroll: bool = True, n_valid=None):
     """Batched population fitness: mean loss over the first n_batches.
 
-    Sequential map (not vmap) over the population: vmapping over *conv
-    weights* lowers to grouped convolutions that are pathologically slow
-    on CPU; population members are independent, so a map keeps each on
-    the fast conv path.  Unrolled by default (see ClientHP.unroll).
-    ``n_valid`` marks the valid-batch count of a padded dataset (see
-    :func:`_fitness_slice`).
+    ``unravel`` decodes one population row (a flat weight vector or a
+    subspace genome) into params.  Sequential map (not vmap) over the
+    population: vmapping over *conv weights* lowers to grouped
+    convolutions that are pathologically slow on CPU; population members
+    are independent, so a map keeps each on the fast conv path.
+    Python-unrolled with ``unroll`` (see :func:`unrolls`), else a
+    ``lax.map``.  ``n_valid`` marks the valid-batch count of a padded
+    dataset (see :func:`_fitness_slice`).
     """
     sub = _fitness_slice(data, n_batches, n_valid)
 
@@ -179,9 +189,28 @@ def make_subspace_map(params, scale: float):
     return len(leaves), apply_z
 
 
+def _evolve(mh: Metaheuristic, hp: ClientHP, rng, state, fit_fn,
+            unroll: bool):
+    """``hp.mh_generations`` meta-heuristic steps, one key split each."""
+    if unroll:
+        for _ in range(hp.mh_generations):
+            rng, k = jax.random.split(rng)
+            state = mh.step(k, state, fit_fn)
+        return state
+
+    def gen(i, carry):
+        state, rng = carry
+        rng, k = jax.random.split(rng)
+        return mh.step(k, state, fit_fn), rng
+
+    state, _ = jax.lax.fori_loop(0, hp.mh_generations, gen, (state, rng))
+    return state
+
+
 def make_client_update(task: Task, hp: ClientHP,
                        mh: Optional[Metaheuristic] = None,
-                       masked: bool = False):
+                       masked: bool = False,
+                       backend: Optional[str] = None):
     """Returns jit-able ``client_update(params, data, rng) ->
     (score, params)``.  With ``mh`` (FedX): SGD then meta-heuristic
     refinement; without (FedAvg): plain SGD, score = post-training loss.
@@ -192,8 +221,12 @@ def make_client_update(task: Task, hp: ClientHP,
     ``mask`` its ``(n_batches,)`` bool validity row.  Padded batches
     contribute no SGD step and no fitness term, so scores and weights
     match the same client's unpadded run on the sequential engine.
+
+    ``backend`` is the platform the update is compiled for (default:
+    ``jax.default_backend()``); it decides :func:`unrolls`.
     """
-    local_sgd = make_local_sgd(task, hp, masked=masked)
+    unroll = unrolls(backend)
+    local_sgd = make_local_sgd(task, hp, masked=masked, unroll=unroll)
 
     def client_update(global_params, data, rng, mask=None):
         r_sgd, r_mh = jax.random.split(rng)
@@ -201,51 +234,19 @@ def make_client_update(task: Task, hp: ClientHP,
         n_valid = None if mask is None else jnp.sum(mask.astype(jnp.int32))
 
         if hp.subspace and mh is not None:
-            n_genes, apply_z = make_subspace_map(params, hp.subspace_scale)
-            sub = _fitness_slice(data, hp.fitness_batches, n_valid)
-
-            def one_z(z):
-                p = apply_z(z)
-                losses = [task.loss_fn(
-                    p, jax.tree.map(lambda a: a[i], sub))[0]
-                    for i in range(hp.fitness_batches)]
-                return jnp.stack(losses).mean()
-
-            def fit_z(zs):
-                return jnp.stack([one_z(zs[i])
-                                  for i in range(zs.shape[0])])
-
-            state = mh.init(r_mh, jnp.ones((n_genes,)), hp.mh_pop, fit_z)
-            rng2 = r_mh
-            for _ in range(hp.mh_generations):
-                rng2, k = jax.random.split(rng2)
-                state = mh.step(k, state, fit_z)
-            best_z, best_fit = best_member(state)
-            return best_fit, apply_z(best_z)
-
-        flat, unravel = ravel_pytree(params)
-        fit_fn = make_fitness_fn(task, data, unravel, hp.fitness_batches,
-                                 unroll=hp.unroll, n_valid=n_valid)
-        if mh is None:
-            score = fit_fn(flat[None])[0]
-            return score, params
-        state = mh.init(r_mh, flat, hp.mh_pop, fit_fn)
-
-        if hp.unroll:
-            rng = r_mh
-            for _ in range(hp.mh_generations):
-                rng, k = jax.random.split(rng)
-                state = mh.step(k, state, fit_fn)
+            n_genes, decode = make_subspace_map(params, hp.subspace_scale)
+            x0 = jnp.ones((n_genes,))
         else:
-            def gen(i, carry):
-                state, rng = carry
-                rng, k = jax.random.split(rng)
-                return mh.step(k, state, fit_fn), rng
-
-            state, _ = jax.lax.fori_loop(0, hp.mh_generations, gen,
-                                         (state, r_mh))
-        best_flat, best_fit = best_member(state)
-        return best_fit, unravel(best_flat)
+            x0, decode = ravel_pytree(params)
+        fit_fn = make_fitness_fn(task, data, decode, hp.fitness_batches,
+                                 unroll=unroll, n_valid=n_valid)
+        if mh is None:
+            score = fit_fn(x0[None])[0]
+            return score, params
+        state = mh.init(r_mh, x0, hp.mh_pop, fit_fn)
+        state = _evolve(mh, hp, r_mh, state, fit_fn, unroll)
+        best, best_fit = best_member(state)
+        return best_fit, decode(best)
 
     if masked:
         def masked_update(global_params, data, mask, rng):

@@ -44,7 +44,6 @@ from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.flatten_util import ravel_pytree
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -198,7 +197,8 @@ def _fedx_round_body(task: Task, hp: ClientHP, mh: Metaheuristic,
     fusion (:func:`make_fused_rounds`) so one XLA program spans a whole
     block of rounds."""
     mode = resolve_vectorize(vectorize, backend)
-    client_update = make_client_update(task, hp, mh, masked=masked)
+    client_update = make_client_update(task, hp, mh, masked=masked,
+                                       backend=backend)
     update = (client_update if masked
               else lambda p, d, m, k: client_update(p, d, k))
 
@@ -262,7 +262,8 @@ def _fedavg_round_body(task: Task, hp: ClientHP, vectorize: str = "auto",
     keys) -> (avg_params, scores)`` over the (already gathered)
     participant axis.  See :func:`_fedx_round_body`."""
     mode = resolve_vectorize(vectorize, backend)
-    client_update = make_client_update(task, hp, None, masked=masked)
+    client_update = make_client_update(task, hp, None, masked=masked,
+                                       backend=backend)
     update = (client_update if masked
               else lambda p, d, m, k: client_update(p, d, k))
 
@@ -589,57 +590,65 @@ def pipeline_blocks(dispatch: Callable[[Any], Any],
 
 
 # ------------------------------------------------------------ sharded --
-def _squeeze0(tree):
-    return jax.tree.map(lambda a: a[0], tree)
+def _mesh_backend(mesh: Mesh) -> str:
+    return mesh.devices.flat[0].platform
 
 
 def make_sharded_fedx_round(task: Task, hp: ClientHP, mh: Metaheuristic,
                             mesh: Mesh, axis: str = "clients"):
-    """Mesh placement of the FedX round: clients map to slices of
-    ``axis``, local training runs with zero collectives, and the
-    cross-slice traffic is one fp32 all_gather (N x 4 bytes) plus one
-    masked-psum winner fetch (M bytes) — see repro.core.distributed.
+    """Mesh placement of the FedX round: the N clients split into equal
+    slices of ``axis``, and each slice runs the single-host round body
+    (:func:`_fedx_round_body`) over its own clients with zero
+    collectives, down to its local winner.  The cross-slice traffic is
+    one fp32 all_gather of every score (N x 4 bytes) plus one
+    masked-psum fetch of the global winner's weights (M bytes) — see
+    repro.core.distributed.
     """
-    client_update = make_client_update(task, hp, mh)
+    backend = _mesh_backend(mesh)
+    local_round = _fedx_round_body(task, hp, mh, hp.vectorize,
+                                   backend=backend)
 
     def per_shard(params, data, keys):
-        data = _squeeze0(data)
-        rng = jax.random.wrap_key_data(keys[0], impl="threefry2x32")
-        score, new_params = client_update(params, data, rng)
-        scores = jax.lax.all_gather(score, axis)            # N x 4 bytes
-        winner = jnp.argmin(scores)
+        local_best, local_scores, _ = local_round(params, data, None, keys)
+        k = local_scores.shape[0]
+        scores = jax.lax.all_gather(local_scores, axis, tiled=True)
+        # the first global minimum lies on shard winner // k, where it is
+        # that shard's own (first) local minimum
+        owner = jnp.argmin(scores) // k
         me = jax.lax.axis_index(axis)
-        mask = (me == winner).astype(jnp.float32)
-        flat, unravel = ravel_pytree(new_params)
+        mask = (me == owner).astype(jnp.float32)
+        flat, unravel = ravel_pytree(local_best)
         best = jax.lax.psum(flat * mask, axis)              # winner fetch
         return unravel(best), scores
 
-    fn = shard_map(per_shard, mesh=mesh,
-                   in_specs=(P(), P(axis), P(axis)),
-                   out_specs=(P(), P()),
-                   check_rep=False)
+    fn = jax.shard_map(per_shard, mesh=mesh,
+                       in_specs=(P(), P(axis), P(axis)),
+                       out_specs=(P(), P()),
+                       check_vma=False)
     return jax.jit(fn)
 
 
 def make_sharded_fedavg_round(task: Task, hp: ClientHP, mesh: Mesh,
                               axis: str = "clients"):
-    """Mesh placement of FedAvg: a full-model all-reduce every round."""
-    client_update = make_client_update(task, hp, mh=None)
+    """Mesh placement of FedAvg: each slice averages its own clients
+    (:func:`_fedavg_round_body`), then one full-model all-reduce per
+    round averages the slices."""
+    backend = _mesh_backend(mesh)
+    local_round = _fedavg_round_body(task, hp, hp.vectorize,
+                                     backend=backend)
 
     def per_shard(params, data, keys):
-        data = _squeeze0(data)
-        rng = jax.random.wrap_key_data(keys[0], impl="threefry2x32")
-        score, new_params = client_update(params, data, rng)
+        local_avg, local_scores = local_round(params, data, None, keys)
         n = jax.lax.psum(1.0, axis)
         avg = jax.tree.map(
-            lambda w: jax.lax.psum(w.astype(jnp.float32), axis) / n,
-            new_params)                                     # M bytes x N
-        scores = jax.lax.all_gather(score, axis)
-        return jax.tree.map(lambda a, ref: a.astype(ref.dtype),
-                            avg, new_params), scores
+            lambda w: (jax.lax.psum(w.astype(jnp.float32), axis) / n
+                       ).astype(w.dtype),
+            local_avg)                                      # M bytes x N
+        scores = jax.lax.all_gather(local_scores, axis, tiled=True)
+        return avg, scores
 
-    fn = shard_map(per_shard, mesh=mesh,
-                   in_specs=(P(), P(axis), P(axis)),
-                   out_specs=(P(), P()),
-                   check_rep=False)
+    fn = jax.shard_map(per_shard, mesh=mesh,
+                       in_specs=(P(), P(axis), P(axis)),
+                       out_specs=(P(), P()),
+                       check_vma=False)
     return jax.jit(fn)

@@ -9,8 +9,13 @@ population and three (P, D) temporaries in HBM: HBM traffic drops from
 ~7 x P x D x 4B (separate HLO ops) to ~4 x P x D x 4B (read p1, p2,
 bits1, bits2; write child).
 
-Block layout: child rows are processed one at a time ((1, db) blocks,
-db a multiple of 128 lanes) because each row gathers different parents.
+Block layout: each length-D row is laid out as ``(rows, 128)`` lanes, so
+one grid step reads a ``(block_rows, 128)`` tile of one child row (the
+population axis is squeezed out of the block, since each child gathers
+different parents).  ``block_rows`` is a multiple of 8 and D is padded
+up to a whole number of tiles, which keeps every block's last two
+dimensions at the TPU's (8, 128) f32 tiling.  The per-row mutation gate
+is a scalar-prefetched SMEM value.
 """
 from __future__ import annotations
 
@@ -21,53 +26,89 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+LANES = 128
+SUBLANES = 8          # f32 sublane tile
+BLOCK_ROWS = 512      # 512 x 128 f32 = 256 KiB per operand block
 
-def _kernel(p1_idx_ref, p2_idx_ref, p1_ref, p2_ref, bits1_ref, bits2_ref,
-            gate_ref, out_ref, *, pm_gene: float, mut_scale: float):
+
+def tile_rows(d: int) -> int:
+    """Sublane rows per block for a length-``d`` row: ``BLOCK_ROWS``,
+    shrunk to the row's own (8-aligned) height when the row is shorter."""
+    rows = -(-d // LANES)
+    return min(BLOCK_ROWS, -(-rows // SUBLANES) * SUBLANES)
+
+
+def padded_dim(d: int) -> int:
+    """``d`` rounded up to a whole number of ``(tile_rows, 128)`` tiles."""
+    tile = tile_rows(d) * LANES
+    return -(-d // tile) * tile
+
+
+def _kernel(p1_idx_ref, p2_idx_ref, gate_ref, p1_ref, p2_ref, bits1_ref,
+            bits2_ref, out_ref, *, pm_gene: float, mut_scale: float):
+    # The random bits arrive bitcast to int32: Mosaic has no uint32 ->
+    # float32 cast.  Every field below is masked to its width first, so
+    # the arithmetic shift of int32 reads the same bits as uint32's.
     p1 = p1_ref[...]
     p2 = p2_ref[...]
     bits1 = bits1_ref[...]
     bits2 = bits2_ref[...]
-    gate = gate_ref[0, 0]
+    gate = gate_ref[pl.program_id(0)].astype(jnp.float32)
 
-    thresh = jnp.uint32(int(pm_gene * 256))
-    mask = ((bits2 & jnp.uint32(0xFF)) < thresh).astype(p1.dtype)
-    u_noise = (((bits2 >> jnp.uint32(8)) & jnp.uint32(0xFFFFFF))
-               .astype(jnp.float32) * (1.0 / float(1 << 24)))
+    mask = ((bits2 & 0xFF) < int(pm_gene * 256)).astype(p1.dtype)
+    u_noise = (((bits2 >> 8) & 0xFFFFFF).astype(jnp.float32)
+               * (1.0 / float(1 << 24)))
     noise = (2.0 * u_noise - 1.0) * mut_scale * (jnp.abs(p1) + 1e-3)
     p1m = p1 + noise.astype(p1.dtype) * mask * gate
-    alpha = (bits1.astype(jnp.float32) * (1.0 / 4294967296.0)).astype(p1.dtype)
+    # float32(uint32 bits1) as one rounded sum of two exact 16-bit halves,
+    # which is the same round-to-nearest the direct conversion does
+    hi = ((bits1 >> 16) & 0xFFFF).astype(jnp.float32)
+    lo = (bits1 & 0xFFFF).astype(jnp.float32)
+    alpha = ((hi * 65536.0 + lo) * (1.0 / 4294967296.0)).astype(p1.dtype)
     out_ref[...] = alpha * p1m + (1.0 - alpha) * p2
 
 
 def bwo_evolve_pallas(pop, p1_idx, p2_idx, bits1, bits2, row_gate, *,
                       pm_gene: float, mut_scale: float,
-                      block_d: int = 512, interpret: bool = False):
-    """pop (P, D) fp32 with D % 128 == 0 (caller pads)."""
-    P, D = pop.shape
-    block_d = min(block_d, D)
-    while D % block_d:                 # D is 128-aligned; find a divisor
-        block_d -= 128
-    assert D % block_d == 0 and block_d % 128 == 0, (D, block_d)
-    grid = (P, D // block_d)
+                      interpret: bool = False):
+    """pop (P, Dp) f32 and bits1, bits2 (P, Dp) uint32, with ``Dp ==
+    padded_dim(Dp)`` (the caller pads); p1_idx, p2_idx, row_gate (P,)
+    int32."""
+    P, Dp = pop.shape
+    br = tile_rows(Dp)
+    if Dp % (br * LANES):
+        raise ValueError(f"row length {Dp} is not padded to whole "
+                         f"({br}, {LANES}) tiles; pad with padded_dim()")
+    rows = Dp // LANES
+    grid = (P, rows // br)
 
     kernel = functools.partial(_kernel, pm_gene=pm_gene,
                                mut_scale=mut_scale)
+    tile = (pl.Squeezed(), br, LANES)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_d), lambda i, j, i1, i2: (i1[i], j)),
-            pl.BlockSpec((1, block_d), lambda i, j, i1, i2: (i2[i], j)),
-            pl.BlockSpec((1, block_d), lambda i, j, i1, i2: (i, j)),
-            pl.BlockSpec((1, block_d), lambda i, j, i1, i2: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j, i1, i2: (i, 0)),
+            pl.BlockSpec(tile, lambda i, j, i1, i2, g: (i1[i], j, 0)),
+            pl.BlockSpec(tile, lambda i, j, i1, i2, g: (i2[i], j, 0)),
+            pl.BlockSpec(tile, lambda i, j, i1, i2, g: (i, j, 0)),
+            pl.BlockSpec(tile, lambda i, j, i1, i2, g: (i, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_d), lambda i, j, i1, i2: (i, j)),
+        out_specs=pl.BlockSpec(tile, lambda i, j, i1, i2, g: (i, j, 0)),
     )
-    return pl.pallas_call(
+
+    def rows3(a):
+        return a.reshape(P, rows, LANES)
+
+    def int_rows3(bits):
+        return rows3(jax.lax.bitcast_convert_type(bits, jnp.int32))
+
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((P, D), pop.dtype),
+        out_shape=jax.ShapeDtypeStruct((P, rows, LANES), pop.dtype),
         interpret=interpret,
-    )(p1_idx, p2_idx, pop, pop, bits1, bits2, row_gate)
+        name="bwo_evolve",
+    )(p1_idx, p2_idx, row_gate, rows3(pop), rows3(pop), int_rows3(bits1),
+      int_rows3(bits2))
+    return out.reshape(P, Dp)

@@ -12,6 +12,7 @@ import json
 
 from repro.core import FLConfig, build_experiment
 from repro.core.api import strategy_names, PARTITIONS, TASKS
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core.knobs import (AUDIT_MODES, validate_audit,
                               validate_engine,
                               validate_pipeline_blocks,
@@ -77,6 +78,7 @@ def main():
                          "prints findings without gating")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = FLConfig(
         strategy=args.strategy, task=args.task, n_clients=args.clients,

@@ -17,6 +17,11 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_host_mesh(n: int = 1, axis: str = "clients"):
-    """Small host-device mesh for FL shard_map tests/examples."""
-    devs = jax.devices()[:n]
+    """Small host-device mesh for FL shard_map tests/examples; raises
+    when the host has fewer than ``n`` devices."""
+    devs = jax.devices()
+    if len(devs) < n:
+        raise ValueError(f"a {n}-device mesh needs {n} devices, but the "
+                         f"{devs[0].platform} host has {len(devs)}")
+    devs = devs[:n]
     return jax.make_mesh((len(devs),), (axis,), devices=devs)

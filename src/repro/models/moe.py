@@ -23,7 +23,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig

@@ -182,8 +182,7 @@ def test_parse_input_output_aliases_header():
 # ------------------------------------------------------------------- no-f64
 
 def test_no_f64_flags_x64_program():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64():
         jaxpr = jax.make_jaxpr(lambda x: x * 2.0)(np.float64(1.0))
     s = ProgramSubject(name="x64", jaxpr=jaxpr)
     errs = _errors(run_rules(_ctx(s), only=("no-f64",)), "no-f64")

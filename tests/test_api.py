@@ -109,3 +109,33 @@ def test_flconfig_is_frozen():
     cfg = FLConfig()
     with pytest.raises(dataclasses.FrozenInstanceError):
         cfg.strategy = "fedavg"
+
+
+# ------------------------------------------------------- compile cache --
+@pytest.mark.parametrize("env_dir", [None, "/cache/from/env"])
+def test_compile_cache_dir(monkeypatch, env_dir):
+    """The entry points' cache goes where JAX_COMPILATION_CACHE_DIR says,
+    else to the fixed ``.jax_cache`` at the root of the checkout."""
+    import pathlib
+    from repro.launch.compile_cache import enable_compile_cache
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs")
+    saved = {n: getattr(jax.config, n) for n in names}
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    try:
+        path = enable_compile_cache()
+        if env_dir is None:
+            repo = pathlib.Path(__file__).resolve().parents[1]
+            assert path == str(repo / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == path
+        else:
+            assert path == env_dir
+            assert (jax.config.jax_compilation_cache_dir
+                    == saved["jax_compilation_cache_dir"])
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    finally:
+        for n, v in saved.items():
+            jax.config.update(n, v)

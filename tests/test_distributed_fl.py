@@ -1,5 +1,6 @@
-"""shard_map FL rounds on an 8-device host mesh (run in a subprocess so
-the forced device count doesn't leak into other tests)."""
+"""shard_map FL rounds on a virtual-CPU host mesh, with one and with two
+clients per device (run in a CPU-only subprocess so the forced device
+count doesn't leak into other tests)."""
 import os
 import subprocess
 import sys
@@ -8,12 +9,14 @@ import textwrap
 import pytest
 
 SCRIPT = textwrap.dedent("""
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    import sys
     import jax, jax.numpy as jnp
     import numpy as np
+    n_devices = int(sys.argv[1])
+    jax.config.update("jax_num_cpu_devices", n_devices)
     from repro.core.client import Task, ClientHP, make_client_update
     from repro.core.distributed import make_fedx_round, make_fedavg_round
+    from repro.core.engine import make_batched_fedx_round
     from repro.launch.mesh import make_host_mesh
     from repro.metaheuristics import bwo
 
@@ -34,7 +37,7 @@ SCRIPT = textwrap.dedent("""
     x = jax.random.normal(rng, (N, 4, 16, 6))
     y = (x @ w_true).argmax(-1).astype(jnp.int32)
     data = {"x": x, "y": y}
-    mesh = make_host_mesh(8)
+    mesh = make_host_mesh(n_devices)
     hp = ClientHP(local_epochs=2, mh_pop=4, mh_generations=2, lr=0.1)
     keys = jax.vmap(jax.random.key_data)(jax.random.split(rng, N))
 
@@ -48,10 +51,20 @@ SCRIPT = textwrap.dedent("""
         if s_prev is not None:
             assert s <= s_prev * 1.5, (r, s, s_prev)
         s_prev = s
-    # winner model must equal the reference client_update of the winner
-    upd = jax.jit(make_client_update(task, hp, bwo()))
-    # (protocol check only: scores finite and improving)
     assert np.isfinite(s), s
+
+    # every client of every shard trains: scores and the adopted winner
+    # match the single-device batched round on the same data and keys
+    p0 = task.init_params(rng)
+    got, got_scores = rnd(p0, data, keys)
+    want, want_scores, _ = make_batched_fedx_round(task, hp, bwo())(
+        p0, data, None, keys)
+    assert got_scores.shape == (N,), got_scores.shape
+    np.testing.assert_allclose(np.asarray(got_scores),
+                               np.asarray(want_scores), rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)
 
     # --- FedAvg: averaged weights identical to manual mean ---
     rnd2 = make_fedavg_round(task, hp, mesh)
@@ -71,10 +84,22 @@ SCRIPT = textwrap.dedent("""
 """)
 
 
-def test_fl_rounds_on_8_device_mesh():
+def _run_script(n_devices):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    res = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
-                         capture_output=True, text=True, timeout=900)
+    env["JAX_PLATFORMS"] = "cpu"
+    res = subprocess.run([sys.executable, "-c", SCRIPT, str(n_devices)],
+                         env=env, capture_output=True, text=True,
+                         timeout=900)
     assert res.returncode == 0, res.stderr[-3000:]
     assert "DISTRIBUTED_OK" in res.stdout
+
+
+def test_fl_rounds_on_8_device_mesh():
+    _run_script(8)
+
+
+def test_fl_rounds_two_clients_per_device():
+    """8 clients on a 4-device mesh: every shard trains both of its
+    clients, not only the first."""
+    _run_script(4)

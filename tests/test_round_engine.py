@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from repro.core import ClientHP, Server, get_strategy
 from repro.core.engine import (BatchedRoundEngine, make_batched_fedx_round,
@@ -162,9 +163,9 @@ def _iter_eqns(jaxpr):
         for val in eqn.params.values():
             subs = val if isinstance(val, (tuple, list)) else (val,)
             for sub in subs:
-                if isinstance(sub, jax.core.ClosedJaxpr):
+                if isinstance(sub, ClosedJaxpr):
                     yield from _iter_eqns(sub.jaxpr)
-                elif isinstance(sub, jax.core.Jaxpr):
+                elif isinstance(sub, Jaxpr):
                     yield from _iter_eqns(sub)
 
 

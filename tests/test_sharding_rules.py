@@ -51,6 +51,7 @@ SCRIPT = textwrap.dedent("""
 def test_param_specs_divide_dims():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"
     res = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                          capture_output=True, text=True, timeout=900)
     assert res.returncode == 0, res.stderr[-3000:]
