@@ -1,0 +1,42 @@
+"""Model FLOPs of federated rounds, counted from shapes.
+
+A forward pass of one image costs ``2 * forward_macs`` FLOPs, from the
+configuration's reference module.  Per round and participating client:
+
+* local SGD: 3 forward-equivalents per valid image-step
+  (``local_epochs * valid_batches * batch`` image-steps);
+* fitness: one forward per image scored, ``mh_pop * fitness_batches *
+  batch * (1 + mh_generations)`` for FedX, ``fitness_batches * batch``
+  (the one score of the trained weights) for FedAvg;
+
+and one forward per test image on each evaluated round.  Padded
+batches, recomputation and BWO's elementwise arithmetic do not count.
+"""
+from __future__ import annotations
+
+import math
+from typing import Sequence
+
+
+def client_flops(fwd: float, proto, valid_batches: int, batch: int) -> float:
+    sgd = 3 * proto.local_epochs * valid_batches * batch
+    if proto.is_fedx:
+        fit = (proto.mh_pop * proto.fitness_batches * batch
+               * (1 + proto.mh_generations))
+    else:
+        fit = proto.fitness_batches * batch
+    return fwd * (sgd + fit)
+
+
+def rounds_flops(fwd: float, proto, valid_batches: Sequence[int], batch: int,
+                 logs: Sequence[dict], n_test: int) -> float:
+    """FLOPs of the rounds in ``logs`` (their participants and evals)."""
+    per_client = [client_flops(fwd, proto, nb, batch) for nb in valid_batches]
+    total = 0.0
+    for log in logs:
+        who = (range(len(valid_batches)) if proto.is_fedx
+               else log["participants"])
+        total += sum(per_client[k] for k in who)
+        if not math.isnan(log["eval_loss"]):
+            total += fwd * n_test
+    return total
