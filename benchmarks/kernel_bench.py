@@ -1,6 +1,6 @@
-"""Kernel micro-benchmarks: fused Pallas path (interpret on CPU — numbers
-are structural, the TPU win is HBM-traffic derived) vs the unfused jnp
-composition, plus oracle-equivalence timing."""
+"""Kernel micro-benchmarks: the jnp reference compositions of the Pallas
+kernels, timed on whatever backend runs them (steady call and first call
+with compilation)."""
 from __future__ import annotations
 
 import time
@@ -40,9 +40,6 @@ def bench_kernels() -> List[tuple]:
     rows.append(("kernel/bwo_evolve_ref_jnp", us_ref, f"P={P},D={D}"))
     rows.append(("kernel/bwo_evolve_ref_jnp_compile", us_first,
                  f"P={P},D={D}"))
-    # HBM-traffic model: fused reads 4 x PD x 4B, unfused ~7 x PD x 4B
-    rows.append(("kernel/bwo_evolve_traffic_model", us_ref,
-                 "fused=4PD vs unfused=7PD bytes -> 1.75x HBM win"))
 
     # flash attention vs blockwise jnp (CPU, small shape)
     q = jax.random.normal(rng, (1, 512, 4, 64))

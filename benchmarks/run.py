@@ -7,7 +7,6 @@
 #   round_engine/*    — sequential vs batched one-dispatch round engine
 #   fused_rounds/*    — rounds_per_dispatch sweep (one dispatch per R rounds)
 #   pipelined_blocks/* — double-buffered block pipeline vs serial driver
-#   roofline/*        — §Roofline terms per (arch x shape x mesh) dry-run
 #   kernel/*          — Pallas kernel micro-benchmarks
 import sys
 import traceback
@@ -20,13 +19,12 @@ def main() -> None:
                                      bench_pipelined_blocks,
                                      bench_round_engine)
     from benchmarks.kernel_bench import bench_kernels
-    from benchmarks.roofline_bench import bench_roofline
     from repro.launch.compile_cache import enable_compile_cache
 
     enable_compile_cache()
 
-    benches = [bench_kernels, bench_roofline, bench_accuracy, bench_loss,
-               bench_comm_cost, bench_exec_time, bench_noniid_ablation,
+    benches = [bench_kernels, bench_accuracy, bench_loss, bench_comm_cost,
+               bench_exec_time, bench_noniid_ablation,
                bench_round_engine, bench_fused_rounds,
                bench_pipelined_blocks]
     print("name,us_per_call,derived")

@@ -231,5 +231,6 @@ class ExperimentResult:
             "final_loss": self.logs[-1].test_loss,
             "comm": meter.summary(),
             "block_timing": meter.timing_summary(),
+            "sgd_steps": meter.sgd_step_summary(),
             f"normalized_cost_vs_fedavg{fedavg_rounds}": cost,
         }

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.flatten_util import ravel_pytree
 
+from repro.core import tracing
 from repro.metaheuristics import Metaheuristic
 from repro.metaheuristics.base import best_member
 
@@ -111,19 +112,21 @@ def make_local_sgd(task: Task, hp: ClientHP, masked: bool = False,
         return params
 
     def local_sgd(params, data, rng, mask=None):
-        anchor = params if hp.prox_mu > 0 else None   # w_global (FedProx)
-        if unroll:
-            for _ in range(hp.local_epochs):
-                rng, ekey = jax.random.split(rng)
-                params = sgd_epoch(params, data, ekey, anchor, mask)
-            return params
+        with jax.named_scope(tracing.LOCAL_SGD):
+            anchor = params if hp.prox_mu > 0 else None  # w_global (FedProx)
+            if unroll:
+                for _ in range(hp.local_epochs):
+                    rng, ekey = jax.random.split(rng)
+                    params = sgd_epoch(params, data, ekey, anchor, mask)
+                return params
 
-        def body(_, carry):
-            params, rng = carry
-            rng, ekey = jax.random.split(rng)
-            return sgd_epoch(params, data, ekey, anchor, mask), rng
-        params, _ = jax.lax.fori_loop(0, hp.local_epochs, body, (params, rng))
-        return params
+            def body(_, carry):
+                params, rng = carry
+                rng, ekey = jax.random.split(rng)
+                return sgd_epoch(params, data, ekey, anchor, mask), rng
+            params, _ = jax.lax.fori_loop(0, hp.local_epochs, body,
+                                          (params, rng))
+            return params
 
     return local_sgd
 
@@ -154,8 +157,9 @@ def make_fitness_fn(task: Task, data, unravel, n_batches: int,
     convolutions that are pathologically slow on CPU; population members
     are independent, so a map keeps each on the fast conv path.
     Python-unrolled with ``unroll`` (see :func:`unrolls`), else a
-    ``lax.map``.  ``n_valid`` marks the valid-batch count of a padded
-    dataset (see :func:`_fitness_slice`).
+    ``lax.map``; traced inside the ``fl.bwo_fitness`` scope.
+    ``n_valid`` marks the valid-batch count of a padded dataset (see
+    :func:`_fitness_slice`).
     """
     sub = _fitness_slice(data, n_batches, n_valid)
 
@@ -166,11 +170,13 @@ def make_fitness_fn(task: Task, data, unravel, n_batches: int,
         losses = [task.loss_fn(params, b)[0] for b in batches]
         return jnp.stack(losses).mean()
 
-    if unroll:
-        def fit_fn(pops):
-            return jnp.stack([one(pops[i]) for i in range(pops.shape[0])])
-        return fit_fn
-    return lambda pops: jax.lax.map(one, pops)
+    def fit_fn(pops):
+        with jax.named_scope(tracing.BWO_FITNESS):
+            if unroll:
+                return jnp.stack([one(pops[i])
+                                  for i in range(pops.shape[0])])
+            return jax.lax.map(one, pops)
+    return fit_fn
 
 
 def make_subspace_map(params, scale: float):
@@ -243,10 +249,11 @@ def make_client_update(task: Task, hp: ClientHP,
         if mh is None:
             score = fit_fn(x0[None])[0]
             return score, params
-        state = mh.init(r_mh, x0, hp.mh_pop, fit_fn)
-        state = _evolve(mh, hp, r_mh, state, fit_fn, unroll)
-        best, best_fit = best_member(state)
-        return best_fit, decode(best)
+        with jax.named_scope(tracing.BWO_EVOLVE):
+            state = mh.init(r_mh, x0, hp.mh_pop, fit_fn)
+            state = _evolve(mh, hp, r_mh, state, fit_fn, unroll)
+            best, best_fit = best_member(state)
+            return best_fit, decode(best)
 
     if masked:
         def masked_update(global_params, data, mask, rng):

@@ -8,7 +8,7 @@ Normalized FedX cost (C=1, fixed N=10):  T_X / (T_Avg * 10)   (Eq. 4)
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List
+from typing import Dict, List, Tuple
 
 SCORE_BYTES = 4  # one fp32 performance score — the paper's headline number
 
@@ -94,8 +94,10 @@ class CommMeter:
     ``kinds`` records each round's protocol (``"fedx"`` / ``"fedavg"``)
     so cost formulas that are strategy-specific (Eq. 4) can verify what
     they are pricing; ``block_timings`` is the per-block wall/sync
-    ledger filled by ``record_block_timing`` (kept out of ``summary()``
-    so byte ledgers of protocol-identical runs stay comparable).
+    ledger filled by ``record_block_timing``, and ``sgd_steps`` the
+    per-round ``(real, computed)`` local SGD step counts filled by
+    ``record_sgd_steps`` (both kept out of ``summary()`` so byte ledgers
+    of protocol-identical runs stay comparable).
     """
     model_bytes: int
     n_clients: int
@@ -103,6 +105,8 @@ class CommMeter:
     downlink: List[int] = dataclasses.field(default_factory=list)
     kinds: List[str] = dataclasses.field(default_factory=list)
     block_timings: List[BlockTiming] = dataclasses.field(
+        default_factory=list)
+    sgd_steps: List[Tuple[int, int]] = dataclasses.field(
         default_factory=list)
 
     def record_fedavg_round(self, n_participants: int):
@@ -135,6 +139,21 @@ class CommMeter:
                 "process_s": sum(t.process_s for t in self.block_timings),
                 "sync_fraction": sync / total if total else 0.0,
                 "round_s": total / rounds if rounds else 0.0}
+
+    def record_sgd_steps(self, real: int, computed: int):
+        """One round's local SGD steps: ``real`` steps trained on a
+        client's own batches, ``computed`` the steps the round program
+        ran, masked padding included (equal when nothing is padded)."""
+        self.sgd_steps.append((int(real), int(computed)))
+
+    def sgd_step_summary(self) -> Dict[str, float]:
+        """Totals of the step ledger and the real share of the
+        computed steps."""
+        real = sum(r for r, _ in self.sgd_steps)
+        computed = sum(c for _, c in self.sgd_steps)
+        return {"rounds": len(self.sgd_steps), "real": real,
+                "computed": computed,
+                "real_frac": real / computed if computed else 0.0}
 
     def record_rounds(self, strategy, n_rounds: int,
                       n_participants: int = None,

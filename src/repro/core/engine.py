@@ -49,6 +49,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.analysis.walker import (CONV_PRIMITIVES, jaxpr_has_primitive,
                                    loss_uses_conv)
+from repro.core import tracing
 from repro.core.client import ClientHP, Task, make_client_update
 from repro.core.knobs import VECTORIZE_MODES, parse_vectorize
 from repro.metaheuristics import Metaheuristic
@@ -206,8 +207,9 @@ def _fedx_round_body(task: Task, hp: ClientHP, mh: Metaheuristic,
         def round_fn(global_params, data, mask, keys):
             scores, new = jax.vmap(update, in_axes=(None, 0, 0, 0))(
                 global_params, data, mask, keys)
-            best = jnp.argmin(scores)
-            winner = jax.tree.map(lambda a: a[best], new)
+            with jax.named_scope(tracing.SERVER_REDUCE):
+                best = jnp.argmin(scores)
+                winner = jax.tree.map(lambda a: a[best], new)
             return winner, scores, best
     else:
         def round_fn(global_params, data, mask, keys):
@@ -217,17 +219,20 @@ def _fedx_round_body(task: Task, hp: ClientHP, mh: Metaheuristic,
                 best_fit, best_params = carry
                 d, msk, k = xs
                 score, params = update(global_params, d, msk, k)
-                take = score < best_fit
-                # streaming winner reduction: carry holds one model
-                best_params = _tree_where(take, params, best_params)
-                best_fit = jnp.minimum(score, best_fit)
+                with jax.named_scope(tracing.SERVER_REDUCE):
+                    take = score < best_fit
+                    # streaming winner reduction: carry holds one model
+                    best_params = _tree_where(take, params, best_params)
+                    best_fit = jnp.minimum(score, best_fit)
                 return (best_fit, best_params), score
 
             init = (jnp.asarray(jnp.inf, jnp.float32), global_params)
             (_, winner), scores = jax.lax.scan(
                 step, init, (data, mask, keys),
                 unroll=_scan_unroll(vectorize, mode, n))
-            return winner, scores, jnp.argmin(scores)
+            with jax.named_scope(tracing.SERVER_REDUCE):
+                best = jnp.argmin(scores)
+            return winner, scores, best
 
     return round_fn
 
@@ -274,17 +279,20 @@ def _fedavg_round_body(task: Task, hp: ClientHP, vectorize: str = "auto",
         if mode == "vmap":
             scores, new = jax.vmap(update, in_axes=(None, 0, 0, 0))(
                 global_params, data, mask, keys)
-            avg = jax.tree.map(lambda a: jnp.mean(a, axis=0), new)
+            with jax.named_scope(tracing.SERVER_REDUCE):
+                avg = jax.tree.map(lambda a: jnp.mean(a, axis=0), new)
             return avg, scores
 
         def step(acc, xs):
             d, msk, k = xs
             score, params = update(global_params, d, msk, k)
             # running mean accumulated in place (carry buffer)
-            acc = jax.tree.map(lambda s, p: s + p / m, acc, params)
+            with jax.named_scope(tracing.SERVER_REDUCE):
+                acc = jax.tree.map(lambda s, p: s + p / m, acc, params)
             return acc, score
 
-        acc0 = jax.tree.map(jnp.zeros_like, global_params)
+        with jax.named_scope(tracing.SERVER_REDUCE):
+            acc0 = jax.tree.map(jnp.zeros_like, global_params)
         avg, scores = jax.lax.scan(
             step, acc0, (data, mask, keys),
             unroll=_scan_unroll(vectorize, mode, m))
@@ -410,12 +418,14 @@ def make_fused_rounds(task: Task, strategy, hp: ClientHP,
                 log = {"scores": scores, "participants": sel}
             if do_eval:
                 due = (round_offset + i + 1) % eval_every == 0
-                loss, acc = jax.lax.cond(
-                    due | (i == n_rounds - 1),
-                    lambda p: tuple(jnp.asarray(v, jnp.float32)
-                                    for v in task.loss_fn(p, eval_batch)),
-                    lambda p: (jnp.full((), jnp.nan, jnp.float32),) * 2,
-                    new_params)
+                with jax.named_scope(tracing.EVAL):
+                    loss, acc = jax.lax.cond(
+                        due | (i == n_rounds - 1),
+                        lambda p: tuple(jnp.asarray(v, jnp.float32)
+                                        for v in task.loss_fn(p,
+                                                              eval_batch)),
+                        lambda p: (jnp.full((), jnp.nan, jnp.float32),) * 2,
+                        new_params)
                 log["eval_loss"], log["eval_acc"] = loss, acc
             return (new_params, rng), log
 
@@ -465,6 +475,8 @@ class BatchedRoundEngine:
                 f"shards or repartition before building the engine")
         self.n_clients = len(client_data)
         self.data = stacked
+        # batches each client row computes, padding included
+        self.n_batches = int(mask.shape[1])
         self.padded = not bool(mask.all())
         self.mask = mask if self.padded else None
         self.is_fedx = strategy.is_fedx
@@ -610,16 +622,17 @@ def make_sharded_fedx_round(task: Task, hp: ClientHP, mh: Metaheuristic,
 
     def per_shard(params, data, keys):
         local_best, local_scores, _ = local_round(params, data, None, keys)
-        k = local_scores.shape[0]
-        scores = jax.lax.all_gather(local_scores, axis, tiled=True)
-        # the first global minimum lies on shard winner // k, where it is
-        # that shard's own (first) local minimum
-        owner = jnp.argmin(scores) // k
-        me = jax.lax.axis_index(axis)
-        mask = (me == owner).astype(jnp.float32)
-        flat, unravel = ravel_pytree(local_best)
-        best = jax.lax.psum(flat * mask, axis)              # winner fetch
-        return unravel(best), scores
+        with jax.named_scope(tracing.SERVER_REDUCE):
+            k = local_scores.shape[0]
+            scores = jax.lax.all_gather(local_scores, axis, tiled=True)
+            # the first global minimum lies on shard winner // k, where it
+            # is that shard's own (first) local minimum
+            owner = jnp.argmin(scores) // k
+            me = jax.lax.axis_index(axis)
+            mask = (me == owner).astype(jnp.float32)
+            flat, unravel = ravel_pytree(local_best)
+            best = jax.lax.psum(flat * mask, axis)          # winner fetch
+            return unravel(best), scores
 
     fn = jax.shard_map(per_shard, mesh=mesh,
                        in_specs=(P(), P(axis), P(axis)),
@@ -639,13 +652,14 @@ def make_sharded_fedavg_round(task: Task, hp: ClientHP, mesh: Mesh,
 
     def per_shard(params, data, keys):
         local_avg, local_scores = local_round(params, data, None, keys)
-        n = jax.lax.psum(1.0, axis)
-        avg = jax.tree.map(
-            lambda w: (jax.lax.psum(w.astype(jnp.float32), axis) / n
-                       ).astype(w.dtype),
-            local_avg)                                      # M bytes x N
-        scores = jax.lax.all_gather(local_scores, axis, tiled=True)
-        return avg, scores
+        with jax.named_scope(tracing.SERVER_REDUCE):
+            n = jax.lax.psum(1.0, axis)
+            avg = jax.tree.map(
+                lambda w: (jax.lax.psum(w.astype(jnp.float32), axis) / n
+                           ).astype(w.dtype),
+                local_avg)                                  # M bytes x N
+            scores = jax.lax.all_gather(local_scores, axis, tiled=True)
+            return avg, scores
 
     fn = jax.shard_map(per_shard, mesh=mesh,
                        in_specs=(P(), P(axis), P(axis)),

@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
 from repro.core.client import ClientHP, Task, make_client_update
 from repro.core.comm import BlockTiming, CommMeter
 from repro.core.engine import (BatchedRoundEngine, pipeline_blocks,
@@ -148,6 +149,9 @@ class Server:
                 f"Dirichlet skew can starve clients, so drop empty "
                 f"shards or repartition (larger alpha / fewer clients / "
                 f"smaller batch size) before constructing the Server")
+        # valid batches per client, from shapes (no device sync)
+        self._client_batches = [jax.tree.leaves(d)[0].shape[0]
+                                for d in self.client_data]
         rng, pkey = jax.random.split(rng)
         self.rng = rng
         self.global_params = task.init_params(pkey)
@@ -193,16 +197,34 @@ class Server:
             self._update = jax.jit(make_client_update(task, hp, strategy.mh))
         # cache the jitted eval fn once: jax.jit(task.loss_fn) per
         # evaluate() call would re-trace and re-compile every round
-        self._eval = jax.jit(task.loss_fn)
+        def eval_loss(params, batch):
+            with jax.named_scope(tracing.EVAL):
+                return task.loss_fn(params, batch)
+        self._eval = jax.jit(eval_loss)
 
     # ------------------------------------------------------------ round --
     def run_round(self) -> dict:
-        keys = jax.random.split(self.rng, self.n_clients + 2)
-        self.rng, sel_key, ckeys = keys[0], keys[1], keys[2:]
-        self.rounds_completed += 1
+        with jax.profiler.TraceAnnotation("Server.run_round"):
+            keys = jax.random.split(self.rng, self.n_clients + 2)
+            self.rng, sel_key, ckeys = keys[0], keys[1], keys[2:]
+            self.rounds_completed += 1
+            if self._engine is not None:
+                return self._run_round_batched(sel_key, ckeys)
+            return self._run_round_sequential(sel_key, ckeys)
+
+    def _record_sgd_steps(self, participants: Optional[Sequence[int]]
+                          = None):
+        """Log one round's real and computed local SGD steps: the
+        participants' (default: every client's) valid batches, and on
+        the batched engine every participant's padded row."""
+        if participants is None:
+            participants = range(self.n_clients)
+        epochs = self.hp.local_epochs
+        real = epochs * sum(self._client_batches[k] for k in participants)
+        computed = real
         if self._engine is not None:
-            return self._run_round_batched(sel_key, ckeys)
-        return self._run_round_sequential(sel_key, ckeys)
+            computed = epochs * len(participants) * self._engine.n_batches
+        self.meter.record_sgd_steps(real, computed)
 
     # ------------------------------------------------------------ block --
     def run_block(self, n_rounds: Optional[int] = None, eval_data=None,
@@ -259,16 +281,18 @@ class Server:
                 "sequential fallback has no async block dispatch to "
                 "pipeline — use run_block, which degrades gracefully")
         n_rounds = int(n_rounds or self.rounds_per_dispatch)
-        t0 = time.perf_counter()
-        offset = self.rounds_completed
-        params, rng, logs = self._engine.run_block(
-            self.global_params, self.rng, n_rounds, eval_batch=eval_data,
-            eval_every=eval_every, round_offset=offset)
-        self.global_params, self.rng = params, rng
-        self.rounds_completed += n_rounds
-        return PendingBlock(n_rounds=n_rounds, round_offset=offset,
-                            logs=logs, t_dispatched=t0,
-                            dispatch_s=time.perf_counter() - t0)
+        with jax.profiler.TraceAnnotation("Server.dispatch_block"):
+            t0 = time.perf_counter()
+            offset = self.rounds_completed
+            params, rng, logs = self._engine.run_block(
+                self.global_params, self.rng, n_rounds,
+                eval_batch=eval_data, eval_every=eval_every,
+                round_offset=offset)
+            self.global_params, self.rng = params, rng
+            self.rounds_completed += n_rounds
+            return PendingBlock(n_rounds=n_rounds, round_offset=offset,
+                                logs=logs, t_dispatched=t0,
+                                dispatch_s=time.perf_counter() - t0)
 
     def finish_block(self, pending: PendingBlock) -> List[dict]:
         """Finish a dispatched block: record its rounds on the meter,
@@ -276,26 +300,34 @@ class Server:
         under the pipeline this host work overlaps the next block's
         device execution), reconstruct the per-round info dicts, and
         append a :class:`~repro.core.comm.BlockTiming` to the meter's
-        block ledger."""
+        block ledger.  Its ``sync_s`` and ``process_s`` time exactly the
+        ``Server.finish_block.sync`` and ``.process`` spans."""
         n_rounds = pending.n_rounds
-        if self.strategy.is_fedx:
-            self.meter.record_rounds(self.strategy, n_rounds,
-                                     fetched_model=True)
-        else:
-            self.meter.record_rounds(
-                self.strategy, n_rounds,
-                n_participants=self._engine.n_participants)
-        t0 = time.perf_counter()
-        # the block's single device->host sync
-        out = jax.device_get(pending.logs)
-        t1 = time.perf_counter()
-        infos = self._block_infos(out, n_rounds)
-        t2 = time.perf_counter()
-        self.meter.record_block_timing(BlockTiming(
-            n_rounds=n_rounds, dispatch_s=pending.dispatch_s,
-            sync_s=t1 - t0, process_s=t2 - t1,
-            total_s=t2 - pending.t_dispatched))
-        return infos
+        with jax.profiler.TraceAnnotation("Server.finish_block"):
+            t0 = time.perf_counter()
+            with jax.profiler.TraceAnnotation("Server.finish_block.sync"):
+                # the block's single device->host sync
+                out = jax.device_get(pending.logs)
+            t1 = time.perf_counter()
+            with jax.profiler.TraceAnnotation("Server.finish_block.process"):
+                if self.strategy.is_fedx:
+                    self.meter.record_rounds(self.strategy, n_rounds,
+                                             fetched_model=True)
+                    for _ in range(n_rounds):
+                        self._record_sgd_steps()
+                else:
+                    self.meter.record_rounds(
+                        self.strategy, n_rounds,
+                        n_participants=self._engine.n_participants)
+                    for sel in out["participants"]:
+                        self._record_sgd_steps(sel)
+                infos = self._block_infos(out, n_rounds)
+            t2 = time.perf_counter()
+            self.meter.record_block_timing(BlockTiming(
+                n_rounds=n_rounds, dispatch_s=pending.dispatch_s,
+                sync_s=t1 - t0, process_s=t2 - t1,
+                total_s=t2 - pending.t_dispatched))
+            return infos
 
     def _block_infos(self, out, n_rounds: int) -> List[dict]:
         """Host-side reconstruction of ``run_round``-shaped info dicts
@@ -384,8 +416,10 @@ class Server:
                 self.global_params, ckeys)
             self.global_params = new_params
             self.meter.record_fedx_round(fetched_model=True)
-            # the round's single device->host sync
-            scores, best = jax.device_get((scores, best))
+            self._record_sgd_steps()
+            with jax.profiler.TraceAnnotation("Server.run_round.sync"):
+                # the round's single device->host sync
+                scores, best = jax.device_get((scores, best))
             best = int(best)
             return {"best_client": best, "score": float(scores[best]),
                     "scores": [float(s) for s in scores],
@@ -394,9 +428,11 @@ class Server:
             self.global_params, sel_key, ckeys)
         self.global_params = new_params
         self.meter.record_fedavg_round(self._engine.n_participants)
-        # the round's single device->host sync; scores align with the
-        # participants list (FedX scores cover all clients)
-        sel, scores = jax.device_get((sel, scores))
+        with jax.profiler.TraceAnnotation("Server.run_round.sync"):
+            # the round's single device->host sync; scores align with the
+            # participants list (FedX scores cover all clients)
+            sel, scores = jax.device_get((sel, scores))
+        self._record_sgd_steps(sel)
         return {"participants": [int(k) for k in sel],
                 "scores": [float(s) for s in scores],
                 "engine": "batched"}
@@ -411,11 +447,13 @@ class Server:
                 scores.append(score)
                 params_list.append(params)
             # one host sync per round, after all clients have dispatched
-            scores = np.asarray(jax.device_get(jnp.stack(scores)))
+            with jax.profiler.TraceAnnotation("Server.run_round.sync"):
+                scores = np.asarray(jax.device_get(jnp.stack(scores)))
             best = int(scores.argmin())
             # GetBestModel: one full-model transfer from the winner only
             self.global_params = params_list[best]
             self.meter.record_fedx_round(fetched_model=True)
+            self._record_sgd_steps()
             return {"best_client": best, "score": float(scores[best]),
                     "scores": [float(s) for s in scores],
                     "engine": "sequential"}
@@ -432,17 +470,21 @@ class Server:
             lambda *xs: jnp.mean(jnp.stack(xs), 0), *new_params)
         # one host sync for the participants' scores, after all have
         # dispatched; aligned with the participants list
-        scores = np.asarray(jax.device_get(jnp.stack(scores)))
+        with jax.profiler.TraceAnnotation("Server.run_round.sync"):
+            scores = np.asarray(jax.device_get(jnp.stack(scores)))
         self.meter.record_fedavg_round(m)
+        self._record_sgd_steps(sel.tolist())
         return {"participants": sel.tolist(),
                 "scores": [float(s) for s in scores],
                 "engine": "sequential"}
 
     # ------------------------------------------------------------- eval --
     def evaluate(self, eval_data) -> Tuple[float, float]:
-        # one device_get for both scalars: float(loss), float(acc) on the
-        # device arrays would block on the device twice (flcheck's
-        # paired-host-conversions lint — the first audit's finding)
-        loss, acc = jax.device_get(self._eval(self.global_params,
-                                              eval_data))
-        return float(loss), float(acc)
+        with jax.profiler.TraceAnnotation("Server.evaluate"):
+            out = self._eval(self.global_params, eval_data)
+            # one device_get for both scalars: float(loss), float(acc) on
+            # the device arrays would block on the device twice (flcheck's
+            # paired-host-conversions lint — the first audit's finding)
+            with jax.profiler.TraceAnnotation("Server.evaluate.sync"):
+                loss, acc = jax.device_get(out)
+            return float(loss), float(acc)
