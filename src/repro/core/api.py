@@ -232,5 +232,6 @@ class ExperimentResult:
             "comm": meter.summary(),
             "block_timing": meter.timing_summary(),
             "sgd_steps": meter.sgd_step_summary(),
+            "bwo_rows": meter.bwo_row_summary(),
             f"normalized_cost_vs_fedavg{fedavg_rounds}": cost,
         }

@@ -94,10 +94,12 @@ class CommMeter:
     ``kinds`` records each round's protocol (``"fedx"`` / ``"fedavg"``)
     so cost formulas that are strategy-specific (Eq. 4) can verify what
     they are pricing; ``block_timings`` is the per-block wall/sync
-    ledger filled by ``record_block_timing``, and ``sgd_steps`` the
+    ledger filled by ``record_block_timing``, ``sgd_steps`` the
     per-round ``(real, computed)`` local SGD step counts filled by
-    ``record_sgd_steps`` (both kept out of ``summary()`` so byte ledgers
-    of protocol-identical runs stay comparable).
+    ``record_sgd_steps``, and ``bwo_rows`` the per-round BWO population
+    rows drawn against a full draw's, filled by ``record_bwo_rows`` (all
+    kept out of ``summary()`` so byte ledgers of protocol-identical runs
+    stay comparable).
     """
     model_bytes: int
     n_clients: int
@@ -107,6 +109,8 @@ class CommMeter:
     block_timings: List[BlockTiming] = dataclasses.field(
         default_factory=list)
     sgd_steps: List[Tuple[int, int]] = dataclasses.field(
+        default_factory=list)
+    bwo_rows: List[Tuple[int, int, int, int]] = dataclasses.field(
         default_factory=list)
 
     def record_fedavg_round(self, n_participants: int):
@@ -154,6 +158,24 @@ class CommMeter:
         return {"rounds": len(self.sgd_steps), "real": real,
                 "computed": computed,
                 "real_frac": real / computed if computed else 0.0}
+
+    def record_bwo_rows(self, drawn: int, full: int, init_drawn: int,
+                        init_full: int):
+        """One round's BWO random rows: the mutation rows its generations
+        drew (``drawn``) against the rows a full ``(P, D)`` draw covers
+        (``full``), and the same for the initial populations."""
+        self.bwo_rows.append((int(drawn), int(full), int(init_drawn),
+                              int(init_full)))
+
+    def bwo_row_summary(self) -> Dict[str, float]:
+        """Totals of the row ledger and the drawn share of the
+        generations' mutation rows."""
+        drawn, full, init_drawn, init_full = (
+            [sum(c) for c in zip(*self.bwo_rows)] if self.bwo_rows
+            else [0, 0, 0, 0])
+        return {"rounds": len(self.bwo_rows), "drawn": drawn, "full": full,
+                "drawn_frac": drawn / full if full else 0.0,
+                "init_drawn": init_drawn, "init_full": init_full}
 
     def record_rounds(self, strategy, n_rounds: int,
                       n_participants: int = None,

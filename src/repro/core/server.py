@@ -49,6 +49,7 @@ from repro.core.knobs import (DEFAULT_PIPELINE_DEPTH,
                               parse_pipeline_blocks,
                               parse_rounds_per_dispatch, validate_engine)
 from repro.metaheuristics import REGISTRY, Metaheuristic
+from repro.metaheuristics.base import init_rows
 
 
 @dataclasses.dataclass(frozen=True)
@@ -160,6 +161,10 @@ class Server:
                               for l in jax.tree.leaves(self.global_params))
         self.meter = CommMeter(model_bytes=model_bytes,
                                n_clients=self.n_clients)
+        # genome length of a client's meta-heuristic (client.py)
+        leaves = jax.tree.leaves(self.global_params)
+        self._genes = (len(leaves) if hp.subspace
+                       else sum(l.size for l in leaves))
         self._engine: Optional[BatchedRoundEngine] = None
         if engine != "sequential" and self.n_clients > 0:
             # measured policy (DESIGN.md §4): on CPU, conv tasks run
@@ -212,11 +217,13 @@ class Server:
                 return self._run_round_batched(sel_key, ckeys)
             return self._run_round_sequential(sel_key, ckeys)
 
-    def _record_sgd_steps(self, participants: Optional[Sequence[int]]
-                          = None):
+    def _record_counters(self, participants: Optional[Sequence[int]]
+                         = None):
         """Log one round's real and computed local SGD steps: the
         participants' (default: every client's) valid batches, and on
-        the batched engine every participant's padded row."""
+        the batched engine every participant's padded row.  Under BWO
+        also log the population rows that the participants' generations
+        and initial draws hashed, against the rows of full draws."""
         if participants is None:
             participants = range(self.n_clients)
         epochs = self.hp.local_epochs
@@ -225,6 +232,14 @@ class Server:
         if self._engine is not None:
             computed = epochs * len(participants) * self._engine.n_batches
         self.meter.record_sgd_steps(real, computed)
+        mh = self.strategy.mh
+        if mh is not None and mh.mutation_rows is not None:
+            n, pop = len(participants), self.hp.mh_pop
+            gens = n * self.hp.mh_generations
+            drawn, full = mh.mutation_rows(pop, self._genes)
+            self.meter.record_bwo_rows(gens * drawn, gens * full,
+                                       n * init_rows(pop, self._genes),
+                                       n * pop)
 
     # ------------------------------------------------------------ block --
     def run_block(self, n_rounds: Optional[int] = None, eval_data=None,
@@ -314,13 +329,13 @@ class Server:
                     self.meter.record_rounds(self.strategy, n_rounds,
                                              fetched_model=True)
                     for _ in range(n_rounds):
-                        self._record_sgd_steps()
+                        self._record_counters()
                 else:
                     self.meter.record_rounds(
                         self.strategy, n_rounds,
                         n_participants=self._engine.n_participants)
                     for sel in out["participants"]:
-                        self._record_sgd_steps(sel)
+                        self._record_counters(sel)
                 infos = self._block_infos(out, n_rounds)
             t2 = time.perf_counter()
             self.meter.record_block_timing(BlockTiming(
@@ -416,7 +431,7 @@ class Server:
                 self.global_params, ckeys)
             self.global_params = new_params
             self.meter.record_fedx_round(fetched_model=True)
-            self._record_sgd_steps()
+            self._record_counters()
             with jax.profiler.TraceAnnotation("Server.run_round.sync"):
                 # the round's single device->host sync
                 scores, best = jax.device_get((scores, best))
@@ -432,7 +447,7 @@ class Server:
             # the round's single device->host sync; scores align with the
             # participants list (FedX scores cover all clients)
             sel, scores = jax.device_get((sel, scores))
-        self._record_sgd_steps(sel)
+        self._record_counters(sel)
         return {"participants": [int(k) for k in sel],
                 "scores": [float(s) for s in scores],
                 "engine": "batched"}
@@ -453,7 +468,7 @@ class Server:
             # GetBestModel: one full-model transfer from the winner only
             self.global_params = params_list[best]
             self.meter.record_fedx_round(fetched_model=True)
-            self._record_sgd_steps()
+            self._record_counters()
             return {"best_client": best, "score": float(scores[best]),
                     "scores": [float(s) for s in scores],
                     "engine": "sequential"}
@@ -473,7 +488,7 @@ class Server:
         with jax.profiler.TraceAnnotation("Server.run_round.sync"):
             scores = np.asarray(jax.device_get(jnp.stack(scores)))
         self.meter.record_fedavg_round(m)
-        self._record_sgd_steps(sel.tolist())
+        self._record_counters(sel.tolist())
         return {"participants": sel.tolist(),
                 "scores": [float(s) for s in scores],
                 "engine": "sequential"}

@@ -13,13 +13,22 @@ generation update is also available as a Pallas kernel
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 
-from repro.metaheuristics.base import (Metaheuristic, init_population,
-                                       select_best)
+from repro.metaheuristics.base import (Metaheuristic, draw_rows,
+                                       init_population, rows_drawable)
+
+
+def _pick(rows, idx):
+    """``rows[idx]`` for a few rows, as a chain of selects.  Under
+    ``vmap`` a row gather lowers on the TPU to chunked gathers, each
+    chunk copied twice more into place; the selects read the rows in
+    one fused pass."""
+    out = jnp.broadcast_to(rows[-1], (idx.shape[0],) + rows.shape[1:])
+    for k in range(rows.shape[0] - 2, -1, -1):
+        out = jnp.where((idx == k)[:, None], rows[k][None], out)
+    return out
 
 
 def bwo(pm: float = 0.4, pc: float = 0.44, pm_gene: float = 0.1,
@@ -27,7 +36,21 @@ def bwo(pm: float = 0.4, pc: float = 0.44, pm_gene: float = 0.1,
         use_pallas: bool = False) -> Metaheuristic:
     """pm: per-individual mutation prob; pc: cannibalism rate (fraction of
     offspring eliminated); procreate_frac: fraction of pop used as parents.
+
+    A generation computes only what it keeps.  Mutation is drawn and
+    applied for the ``n_par`` fittest rows alone, the only rows
+    procreation reads (rows of the same ``(P, D)`` draws,
+    :func:`~repro.metaheuristics.base.draw_rows`); children select from
+    the mutated parents directly; and cannibalism composes its two
+    selections on the fitness vectors, then gathers each surviving row
+    once from the population and the children.  The result equals the
+    plain formulation (mutate all ``P``, rank, cross over, select the
+    best ``n_surv`` children, then the best ``P`` of parents +
+    survivors) bit for bit.
     """
+
+    def n_parents(P):
+        return max(2, int(P * procreate_frac))
 
     def init(rng, x0, pop, fit_fn):
         return init_population(rng, x0, pop, fit_fn)
@@ -43,31 +66,44 @@ def bwo(pm: float = 0.4, pc: float = 0.44, pm_gene: float = 0.1,
                 pop, fit, rng, pm=pm, pm_gene=pm_gene, mut_scale=mut_scale,
                 procreate_frac=procreate_frac)
         else:
-            # ---- 1. mutation (sparse Gaussian, per-individual gated) ----
-            mut_ind = jax.random.bernoulli(r_mut, pm, (P, 1))
-            mut_gene = jax.random.bernoulli(r_mask, pm_gene, (P, D))
-            noise = jax.random.normal(r_noise, (P, D), pop.dtype) * mut_scale
-            noise = noise * (jnp.abs(pop) + 1e-3)
-            mutated = pop + noise * (mut_ind & mut_gene)
+            # ---- 1. mutation of the parents, the fittest n_par rows
+            #         (sparse Gaussian, per-individual gated) ----
+            n_par = n_parents(P)
+            par = jnp.argsort(fit)[:n_par]
+            parents = pop[par]
+            mut_ind = jax.random.bernoulli(r_mut, pm, (P, 1))[par]
+            mut_gene = draw_rows(r_mask, par, (P, D), "bernoulli",
+                                 p=pm_gene)
+            noise = draw_rows(r_noise, par, (P, D), "normal",
+                              pop.dtype) * mut_scale
+            noise = noise * (jnp.abs(parents) + 1e-3)
+            mutated = parents + noise * (mut_ind & mut_gene)
 
-            # ---- 2. procreation: alpha-crossover among the fittest ----
-            n_par = max(2, int(P * procreate_frac))
-            order = jnp.argsort(fit)
-            ranked = mutated[order]
-            p1 = ranked[jax.random.randint(r_sel, (P,), 0, n_par)]
-            p2 = ranked[jax.random.randint(r_sel2, (P,), 0, n_par)]
+            # ---- 2. procreation: alpha-crossover among the parents ----
+            p1 = _pick(mutated, jax.random.randint(r_sel, (P,), 0, n_par))
+            p2 = _pick(mutated, jax.random.randint(r_sel2, (P,), 0, n_par))
             alpha = jax.random.uniform(r_alpha, (P, D), pop.dtype)
             children = alpha * p1 + (1 - alpha) * p2
 
+        # fitness and cannibalism both read the children: written once,
+        # or XLA redoes the crossover and its alpha draw for each reader
+        children = jax.lax.optimization_barrier(children)
         child_fit = fit_fn(children)
 
         # ---- 3. cannibalism: drop the worst pc of offspring, then keep
-        #         the best P of (parents + survivors) ----
+        #         the best P of (parents + survivors); row k < P of that
+        #         union is pop[k], row P + i is children[keep[i]] ----
         n_surv = max(1, int(P * (1 - pc)))
-        surv, surv_fit = select_best(children, child_fit, n_surv)
-        all_pop = jnp.concatenate([pop, surv], 0)
-        all_fit = jnp.concatenate([fit, surv_fit], 0)
-        new_pop, new_fit = select_best(all_pop, all_fit, P)
-        return {"pop": new_pop, "fit": new_fit, "t": state["t"] + 1}
+        keep = jnp.argsort(child_fit)[:n_surv]
+        all_fit = jnp.concatenate([fit, child_fit[keep]])
+        sel = jnp.argsort(all_fit)[:P]
+        src = jnp.where(sel < P, sel, P + keep[jnp.maximum(sel - P, 0)])
+        new_pop = jnp.concatenate([pop, children])[src]
+        return {"pop": new_pop, "fit": all_fit[sel], "t": state["t"] + 1}
 
-    return Metaheuristic("bwo", init, step)
+    def mutation_rows(P, D):
+        drawn = (n_parents(P) if not use_pallas and rows_drawable((P, D))
+                 else P)
+        return drawn, P
+
+    return Metaheuristic("bwo", init, step, mutation_rows)

@@ -87,6 +87,18 @@ def test_build_experiment_smoke_mlp():
         normalized_cost(1, 3, comm["model_bytes"], 30))
 
 
+def test_summary_bwo_rows_half_drawn_at_pop_6():
+    """BWO's defaults at pop 6 mutate its 3 parents: half the rows of a
+    full mutation draw; the initial draw takes 5 of 6 rows."""
+    cfg = FLConfig(strategy="fedbwo", task="mlp", n_clients=2,
+                   n_train=80, n_test=20, batch_size=10, local_epochs=1,
+                   mh_pop=6, mh_generations=2, max_rounds=1, tau=0.99)
+    rows = build_experiment(cfg).run().summary()["bwo_rows"]
+    assert rows == {"rounds": 1, "drawn": 2 * 2 * 3, "full": 2 * 2 * 6,
+                    "drawn_frac": 0.5, "init_drawn": 2 * 5,
+                    "init_full": 2 * 6}
+
+
 def test_build_experiment_overrides():
     """task/client_data/eval_data/hp overrides bypass dataset synthesis
     (benchmarks share one dataset across a strategy sweep)."""

@@ -179,9 +179,10 @@ def _max_intermediate_size(fn, *args):
 def test_fedx_scan_path_streams_weights():
     """The streaming winner reduction must keep peak weight memory at
     O(2 x model): no intermediate of size >= n_clients x n_params."""
-    # n_clients comfortably above mh_pop so the BWO population concat
-    # (pop + survivors, n_params) stays under the weights-stack threshold
-    n_clients, d, classes = 8, 64, 32
+    # n_clients comfortably above 2 x mh_pop so BWO's row union (pop +
+    # children, 2 x mh_pop rows of n_params) stays under the
+    # weights-stack threshold
+    n_clients, d, classes = 12, 64, 32
     task = make_toy_task(d=d, classes=classes)
     n_params = d * classes + classes
     # data deliberately smaller than the weights stack so the threshold

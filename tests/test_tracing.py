@@ -211,3 +211,30 @@ def test_uniform_clients_compute_no_padding():
     server = _server(sizes=(16, 16, 16))
     server.run_round()
     assert server.meter.sgd_steps == [(EPOCHS * 12, EPOCHS * 12)]
+
+
+# ----------------------------------------------------------- row ledger --
+@pytest.mark.parametrize("engine", ["batched", "sequential"])
+def test_bwo_rows_count_parent_rows_of_two_rounds(engine):
+    """pop 3, 1 generation: BWO mutates n_par = 2 of 3 rows and draws
+    rows 1..2 of its initial population, per client and round."""
+    server = _server(engine=engine)
+    assert server.engine == engine
+    server.run_round()
+    server.run_round()
+    n = len(SIZES)
+    assert server.meter.bwo_rows == [(n * 2, n * 3, n * 2, n * 3)] * 2
+    s = server.meter.bwo_row_summary()
+    assert s == {"rounds": 2, "drawn": 2 * n * 2, "full": 2 * n * 3,
+                 "drawn_frac": pytest.approx(2 / 3),
+                 "init_drawn": 2 * n * 2, "init_full": 2 * n * 3}
+    assert "bwo_rows" not in server.meter.summary()
+
+
+def test_bwo_rows_follow_fused_blocks_and_skip_fedavg():
+    server = _server(rounds_per_dispatch=2)
+    server.run_block(2)
+    assert len(server.meter.bwo_rows) == 2
+    fedavg = _server("fedavg")
+    fedavg.run_round()
+    assert fedavg.meter.bwo_rows == []
