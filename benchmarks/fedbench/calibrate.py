@@ -8,8 +8,10 @@
 For each seed, in one process: the program's first rounds at the cell's
 size (as a benchmark run's set-up drives them) against the float32
 reference; on ``--control`` seeds the control, the reference computed in
-bfloat16 in the program's place; on ``--faults`` seeds the reference with
-half of every training batch left out in the program's place.  Every
+bfloat16 (weights and floating inputs; token ids and labels stay
+integers) in the program's place; on ``--faults`` seeds the reference
+with the second half of every training batch (along the leading batch
+axis of every array) left out in the program's place.  Every
 number of ``check`` is printed for each, one JSON line per run.  No
 measured window is needed.  Needs a TPU, like ``run.py``.
 """

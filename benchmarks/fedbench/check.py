@@ -17,7 +17,7 @@ The program's own outputs, re-read by the reference's arithmetic
                       (relative): the adopted weights are the winner's;
   ``eval_loss_gap``   the reported test loss against the test loss of
                       those weights (relative);
-  ``eval_acc_gap``    the same for accuracy (absolute share of images).
+  ``eval_acc_gap``    the same for accuracy (absolute share).
 
 The program's trajectory against the reference's own, both from the
 seed (the reference takes no weights from the program):
@@ -28,6 +28,12 @@ seed (the reference takes no weights from the program):
                        score either way, a fault in training moves them
                        all one way;
   ``loss_gap``         each followed round's test loss (relative);
+  ``loss_gap_r0``      the first round's test loss against the test
+                       loss of the reference's own first-round weights
+                       of the client the program adopted (FedAvg: of
+                       the reference's first round), so a near-tie
+                       that makes the two runs adopt different clients
+                       does not move it (relative);
   ``update_norm_gap``  per weight tensor, the norm of the first kept
                        state's change from the initial weights;
   ``change_norm_gap``  the same at the last kept state.
@@ -103,6 +109,18 @@ def consistency(run, ref, is_fedx: bool) -> Dict[str, float]:
             out["winner_fit_gap"] = max(out["winner_fit_gap"],
                                         _rel(log["scores"][best], fit))
     return out
+
+
+def first_round(run, ref, ref_run, is_fedx: bool) -> Dict[str, float]:
+    """``loss_gap_r0`` of ``run``'s first round."""
+    log = run.logs[0]
+    if math.isnan(log["eval_loss"]):
+        return {"loss_gap_r0": math.inf}
+    if is_fedx:
+        want = ref.evaluate(ref.first_round(log["best"]))[0]
+    else:
+        want = ref_run.logs[0]["eval_loss"]
+    return {"loss_gap_r0": _rel(log["eval_loss"], want)}
 
 
 def trajectory(run, ref_run, is_fedx: bool) -> Dict[str, float]:

@@ -1,15 +1,17 @@
 """Model FLOPs of federated rounds, counted from shapes.
 
-A forward pass of one image costs ``2 * forward_macs`` FLOPs, from the
-configuration's reference module.  Per round and participating client:
+A forward pass of one example (an image, or a sequence of tokens)
+costs ``2 * forward_macs`` FLOPs, from the configuration's reference
+module; where a layer has experts, ``forward_macs`` counts the ones a
+token is routed to.  Per round and participating client:
 
-* local SGD: 3 forward-equivalents per valid image-step
-  (``local_epochs * valid_batches * batch`` image-steps);
-* fitness: one forward per image scored, ``mh_pop * fitness_batches *
+* local SGD: 3 forward-equivalents per valid example-step
+  (``local_epochs * valid_batches * batch`` example-steps);
+* fitness: one forward per example scored, ``mh_pop * fitness_batches *
   batch * (1 + mh_generations)`` for FedX, ``fitness_batches * batch``
   (the one score of the trained weights) for FedAvg;
 
-and one forward per test image on each evaluated round.  Padded
+and one forward per test example on each evaluated round.  Padded
 batches, recomputation and BWO's elementwise arithmetic do not count.
 """
 from __future__ import annotations

@@ -17,11 +17,17 @@ shapes and the same work:
 
 Everything is made on the host in bulk, as a real data set arrives:
 no device program compiles for it.
+
+A workload's ``traffic`` names its kind: absent (or ``"images"``) it is
+the image traffic above (:class:`Traffic`); ``"kind": "tokens"`` is
+token traffic for a language model (:class:`TokenTraffic`): packed
+documents of token ids from a seeded order-1 source per topic, each
+client mixing the topics by a Dirichlet draw (:func:`make_tokens`).
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import List
+from typing import List, Union
 
 import numpy as np
 
@@ -49,13 +55,13 @@ class Traffic:
 @dataclasses.dataclass
 class Dataset:
     """Per-client batched shards and the test set, as host arrays."""
-    clients: List[dict]      # {"images": (nb, B, H, W, C), "labels": (nb, B)}
-    test: dict               # {"images": (n, H, W, C), "labels": (n,)}
+    clients: List[dict]      # arrays with leading (n_batches, batch) axes
+    test: dict               # arrays with a leading example axis
     server_seed: int
 
     @property
     def n_batches(self) -> List[int]:
-        return [c["labels"].shape[0] for c in self.clients]
+        return [c[min(c)].shape[0] for c in self.clients]
 
 
 def seed_streams(seed: int, n: int) -> List[np.random.Generator]:
@@ -142,3 +148,131 @@ def make_dataset(t: Traffic, seed: int) -> Dataset:
     test = {"images": _images(r_test, templates, test_labels, t.noise),
             "labels": test_labels}
     return Dataset(clients=clients, test=test, server_seed=server_seed(seed))
+
+
+# ------------------------------------------------------------ tokens --
+@dataclasses.dataclass(frozen=True)
+class TokenTraffic:
+    """Token traffic for a language model.  Every client holds
+    ``n_batches`` batches of ``batch_size`` packed sequences of
+    ``seq_len + 1`` token ids (inputs and next-token targets); the test
+    set holds ``n_test`` such sequences."""
+    n_clients: int
+    batch_size: int
+    n_batches: int
+    n_test: int
+    seq_len: int
+    vocab_size: int
+    topics: int
+    dirichlet_alpha: float       # each client's mix of the topics
+    doc_len_median: float        # documents: lognormal lengths
+    doc_len_sigma: float
+    p_successor: float           # next token from the successor table
+    successors: int              # successors per token and topic
+    zipf_s: float                # else Zipf(zipf_s) over the topic's ranks
+    partition_seed: int = 1
+
+
+KINDS = {"images": Traffic, "tokens": TokenTraffic}
+
+
+def traffic(d: dict) -> Union[Traffic, TokenTraffic]:
+    """A workload's ``traffic`` as its kind's parameters."""
+    d = dict(d)
+    kind = d.pop("kind", "images")
+    if kind not in KINDS:
+        raise ValueError(f"unknown traffic kind {kind!r}")
+    return KINDS[kind](**d)
+
+
+def generate(t: Union[Traffic, TokenTraffic], seed: int) -> Dataset:
+    if isinstance(t, TokenTraffic):
+        return make_tokens(t, seed)
+    return make_dataset(t, seed)
+
+
+def topic_mix(t: TokenTraffic) -> np.ndarray:
+    """``(n_clients, topics)`` topic shares, from ``partition_seed``
+    only, as the image traffic's class counts."""
+    rng = np.random.default_rng(int(t.partition_seed))
+    return rng.dirichlet([t.dirichlet_alpha] * t.topics, size=t.n_clients)
+
+
+def doc_lengths(rng: np.random.Generator, rows: int, length: int,
+                median: float, sigma: float) -> np.ndarray:
+    """``(rows, k)`` lognormal document lengths of at least 1 token,
+    enough columns that every row's documents fill ``length`` tokens."""
+    k = 2 + int(np.ceil(4 * length / median))
+
+    def draw():
+        x = rng.lognormal(np.log(median), sigma, (rows, k))
+        return np.maximum(1, np.rint(x)).astype(np.int64)
+    out = draw()
+    while (out.sum(1) < length).any():
+        out = np.concatenate([out, draw()], 1)
+    return out
+
+
+def pack(lengths: np.ndarray, length: int) -> np.ndarray:
+    """``(rows, length)`` document ids of each position: row ``i``
+    holds its documents one after the other, the last one cut at the
+    end of the row."""
+    ends = np.cumsum(lengths, 1)
+    pos = np.arange(length)
+    return np.stack([np.searchsorted(e, pos, side="right")
+                     for e in ends]).astype(np.int32)
+
+
+def _sequences(rng: np.random.Generator, mix: np.ndarray, t: TokenTraffic,
+               perm: np.ndarray, succ: np.ndarray, cdf: np.ndarray):
+    """``mix.shape[0]`` packed sequences, row ``i`` with documents of
+    topics drawn from ``mix[i]``: (tokens, segments)."""
+    rows, length = mix.shape[0], t.seq_len + 1
+    segments = pack(doc_lengths(rng, rows, length, t.doc_len_median,
+                                t.doc_len_sigma), length)
+    n_docs = int(segments.max()) + 1
+    u = rng.random((rows, n_docs))
+    doc_topic = (u[:, :, None] > np.cumsum(mix, 1)[:, None, :]).sum(-1)
+    doc_topic = np.minimum(doc_topic, t.topics - 1)
+    topic = np.take_along_axis(doc_topic, segments, 1)
+    start = np.ones((rows, length), bool)
+    start[:, 1:] = segments[:, 1:] != segments[:, :-1]
+
+    rank = np.minimum(np.searchsorted(cdf, rng.random((rows, length)),
+                                      side="right"), t.vocab_size - 1)
+    fresh = perm[topic, rank]
+    follow = (rng.random((rows, length)) < t.p_successor) & ~start
+    pick = rng.integers(0, t.successors, (rows, length))
+    tokens = np.empty((rows, length), np.int32)
+    tokens[:, 0] = fresh[:, 0]
+    for p in range(1, length):
+        nxt = succ[topic[:, p], tokens[:, p - 1], pick[:, p]]
+        tokens[:, p] = np.where(follow[:, p], nxt, fresh[:, p])
+    return tokens, segments
+
+
+def make_tokens(t: TokenTraffic, seed: int) -> Dataset:
+    """Per topic, a random permutation of the vocabulary gives the Zipf
+    rank of each token and a table gives each token's ``successors``;
+    a token follows the one before it from that table with probability
+    ``p_successor`` (never across a document boundary), else it is a
+    fresh Zipf draw.  The test set mixes the topics evenly."""
+    r_tab, r_train, r_test = seed_streams(seed, 3)
+    v = t.vocab_size
+    perm = np.stack([r_tab.permutation(v)
+                     for _ in range(t.topics)]).astype(np.int32)
+    succ = r_tab.integers(0, v, (t.topics, v, t.successors), dtype=np.int32)
+    weights = np.arange(1, v + 1, dtype=np.float64) ** -t.zipf_s
+    cdf = np.cumsum(weights) / weights.sum()
+
+    per_client = t.n_batches * t.batch_size
+    mix = np.repeat(topic_mix(t), per_client, axis=0)
+    tokens, segments = _sequences(r_train, mix, t, perm, succ, cdf)
+    shape = (t.n_clients, t.n_batches, t.batch_size, t.seq_len + 1)
+    tokens, segments = tokens.reshape(shape), segments.reshape(shape)
+    clients = [{"segments": segments[k], "tokens": tokens[k]}
+               for k in range(t.n_clients)]
+    even = np.full((t.n_test, t.topics), 1.0 / t.topics)
+    tt, ts = _sequences(r_test, even, t, perm, succ, cdf)
+    return Dataset(clients=clients, test={"segments": ts, "tokens": tt},
+                   server_seed=server_seed(seed))

@@ -22,7 +22,7 @@ import shutil
 import sys
 import tempfile
 import time
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Union
 
 import jax
 import numpy as np
@@ -81,7 +81,9 @@ def protocol(cell: dict) -> Protocol:
                     mh_generations=fl.get("mh_generations", 0),
                     fitness_batches=p["fitness_batches"],
                     client_ratio=fl.get("client_ratio", 1.0),
-                    bwo=p.get("bwo", {}))
+                    bwo=p.get("bwo", {}),
+                    genome=p.get("genome", "flat"),
+                    genome_scale=p.get("genome_scale", 0.05))
 
 
 def fl_config(cell: dict, cfg: dict, server_seed: int):
@@ -151,7 +153,7 @@ class Prepared:
     cell: dict
     cfg: dict
     proto: Protocol
-    traffic: gen.Traffic
+    traffic: Union[gen.Traffic, gen.TokenTraffic]
     data: gen.Dataset
     flcfg: Any
     exp: Any
@@ -175,8 +177,8 @@ def prepare(cell: dict, seed: int) -> Prepared:
     from repro.core.protocol import StopConditions, run_federated
 
     cfg = spec.config(cell["config"])
-    traffic = gen.Traffic.from_dict(cell["traffic"])
-    data = gen.make_dataset(traffic, seed)
+    traffic = gen.traffic(cell["traffic"])
+    data = gen.generate(traffic, seed)
     flcfg = fl_config(cell, cfg, data.server_seed)
     eval_data = jax.device_put(data.test)
     exp = build_experiment(flcfg, client_data=[jax.device_put(c)
@@ -199,7 +201,7 @@ def prepare(cell: dict, seed: int) -> Prepared:
                     eval_data=eval_data, first=first, per_call=per_call)
 
 
-def exact_counts(server, traffic: gen.Traffic, proto: Protocol,
+def exact_counts(server, traffic, proto: Protocol,
                  logs: List[dict]) -> Dict[str, float]:
     meter = server.meter
     n_part = max(int(proto.client_ratio * traffic.n_clients), 1)
@@ -225,6 +227,7 @@ def judge(run: RunRecord, ref: Reference, ref_run: RunRecord,
           is_fedx: bool) -> Dict[str, float]:
     out = check.consistency(run, ref, is_fedx)
     out.update(check.trajectory(run, ref_run, is_fedx))
+    out.update(check.first_round(run, ref, ref_run, is_fedx))
     return out
 
 

@@ -1,10 +1,14 @@
 """Finds a cell's files by name: ``BENCHMARK.json`` at the root of the
 checkout, ``workloads/<cell>.json``, ``configs/<config>.json`` with
-``configs/<config>.py``, and ``metrics/<metric>.py``."""
+``configs/<config>.py``, and ``metrics/<metric>.py``.  A cell or a
+configuration may also be named by the path of its ``.json`` file
+(relative to the checkout), the configuration's module beside it."""
 from __future__ import annotations
 
 import importlib
+import importlib.util
 import json
+import sys
 from pathlib import Path
 from typing import Callable, Dict, List, Optional
 
@@ -21,11 +25,19 @@ def benchmark(root: Path = CHECKOUT) -> dict:
     return load_json(root / "BENCHMARK.json")
 
 
+def _path(name: str) -> Optional[Path]:
+    """The file a name ending in ``.json`` gives, else None."""
+    path = Path(name)
+    if path.suffix != ".json":
+        return None
+    return path if path.is_absolute() else CHECKOUT / path
+
+
 def workload(name: str, bench: Optional[dict] = None) -> dict:
     """A cell by its name in ``BENCHMARK.json`` (its traffic file under
     ``workloads/``), or by the path of a cell file."""
-    path = Path(name)
-    if path.suffix == ".json":
+    path = _path(name)
+    if path is not None:
         return load_json(path)
     bench = bench if bench is not None else benchmark()
     entry = next((w for w in bench["workloads"] if w["name"] == name), None)
@@ -38,12 +50,22 @@ def workload(name: str, bench: Optional[dict] = None) -> dict:
 
 
 def config(name: str) -> dict:
-    return load_json(HERE / "configs" / f"{name}.json")
+    return load_json(_path(name) or HERE / "configs" / f"{name}.json")
 
 
 def model(name: str):
     """The configuration's plain reference module."""
-    return importlib.import_module(f"fedbench.configs.{name}")
+    path = _path(name)
+    if path is None:
+        return importlib.import_module(f"fedbench.configs.{name}")
+    key = f"fedbench_config:{path}"
+    if key not in sys.modules:
+        found = importlib.util.spec_from_file_location(
+            key, path.with_suffix(".py"))
+        module = importlib.util.module_from_spec(found)
+        found.loader.exec_module(module)
+        sys.modules[key] = module
+    return sys.modules[key]
 
 
 def peaks(kind: str) -> dict:

@@ -7,6 +7,7 @@ import time
 import jax.numpy as jnp
 
 from fedbench import check, harness, spec
+from fedbench.reference import RunRecord
 
 LIMITS_FROM = "mlp2nn_fedbwo_noniid"
 
@@ -54,3 +55,25 @@ def test_control_in_bfloat16_is_not_correct(tmp_path):
     numbers = harness.judge(control, ref, ref_run, p.proto.is_fedx)
     checks = check.compare(numbers, cell["limits"])
     assert not check.passed(checks), checks
+
+
+def test_first_round_loss_follows_the_adopted_client(tmp_path):
+    """A run that adopts another client than the reference in its first
+    round (a near-tie flipped by rounding) reads ``loss_gap_r0`` 0 when
+    its test loss is that client's, where ``loss_gap`` reads the two
+    clients' difference."""
+    cell = spec.workload(str(tiny_cell(tmp_path)))
+    p = harness.prepare(cell, 7)
+    p.exp = None
+    ref = harness.reference(p)
+    ref_run = harness.follow(ref, p.first)
+    other = (ref_run.logs[0]["best"] + 1) % p.traffic.n_clients
+    loss, acc = ref.evaluate(ref.first_round(other))
+    flipped = RunRecord(w0=ref_run.w0, snapshots=ref_run.snapshots,
+                        logs=[dict(ref_run.logs[0], best=other,
+                                   eval_loss=loss, eval_acc=acc)]
+                        + ref_run.logs[1:])
+    numbers = harness.judge(flipped, ref, ref_run, True)
+    assert numbers["loss_gap_r0"] < 1e-6
+    assert numbers["loss_gap"] > 1e-3
+    assert harness.judge(ref_run, ref, ref_run, True)["loss_gap_r0"] < 1e-6

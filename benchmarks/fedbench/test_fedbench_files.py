@@ -6,6 +6,7 @@ import re
 import pytest
 
 from fedbench import gen, spec
+from repro.core.api import TASKS
 
 BENCH = spec.benchmark()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -41,11 +42,11 @@ def test_cell_file(name):
     assert raw["config"] == entry["config"] and raw["why"] == entry["why"]
     assert raw["chips"] == entry["chips"] == 1
     cell = spec.workload(name)
-    gen.Traffic.from_dict(cell["traffic"])
+    gen.traffic(cell["traffic"])
     assert cell["window"]["round_s_hint"] > 0
     assert cell["window"]["check_rounds"] >= 1
     assert cell["limits"], "a cell without limits is never judged"
-    assert spec.config(cell["config"])["task"] in ("cnn", "mlp")
+    assert spec.config(cell["config"])["task"] in TASKS
 
 
 @pytest.mark.parametrize("metric", [m["name"] for m in METRICS])
