@@ -4,8 +4,9 @@
 to its :class:`~repro.configs.base.ArchConfig`.
 """
 from repro.configs.base import (ArchConfig, InputShape, INPUT_SHAPES,
-                                MLAConfig, MoEConfig, SSMConfig)
-from repro.configs import (arctic_480b, deepseek_v2_236b, granite_8b,
+                                MLAConfig, MoEConfig, SSMConfig, YarnConfig)
+from repro.configs import (arctic_480b, deepseek_v2_236b, deepseek_v2_lite,
+                           granite_8b,
                            jamba_v01_52b, llava_next_mistral_7b, olmo_1b,
                            paper_cnn, qwen15_110b, qwen15_4b,
                            whisper_medium, xlstm_1_3b)
@@ -17,6 +18,8 @@ ARCHS = {
     "olmo-1b": olmo_1b.CONFIG,
     "qwen1.5-4b": qwen15_4b.CONFIG,
     "deepseek-v2-236b": deepseek_v2_236b.CONFIG,
+    "deepseek-v2-lite": deepseek_v2_lite.CONFIG,
+    "deepseek-v2-lite-ep8": deepseek_v2_lite.SHARE,
     "granite-8b": granite_8b.CONFIG,
     "qwen1.5-110b": qwen15_110b.CONFIG,
     "arctic-480b": arctic_480b.CONFIG,
@@ -39,5 +42,5 @@ def get_shape(name: str) -> InputShape:
 
 
 __all__ = ["ArchConfig", "InputShape", "INPUT_SHAPES", "MLAConfig",
-           "MoEConfig", "SSMConfig", "ARCHS", "PAPER_CNN", "get_arch",
+           "MoEConfig", "SSMConfig", "YarnConfig", "ARCHS", "PAPER_CNN", "get_arch",
            "get_shape"]

@@ -22,16 +22,34 @@ class MoEConfig:
     expert_d_ff: Optional[int] = None  # defaults to arch d_ff
     router_aux_loss: float = 0.01
     every_n_layers: int = 1          # MoE applied to every n-th block (jamba: 2)
+    # experts of each layer held on this chip (the first ``experts_held``
+    # of ``num_experts``): None holds them all and runs the capacity
+    # dispatch of ``models.moe.moe_apply``; a number runs the dropless
+    # share layer ``models.moe.share_apply``, which routes over all
+    # ``num_experts`` and computes the held experts' part
+    experts_held: Optional[int] = None
+    renormalize: bool = True         # top-k gates rescaled to sum to 1
 
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
     """DeepSeek-V2 Multi-head Latent Attention."""
     kv_lora_rank: int = 512
-    q_lora_rank: int = 1536
+    q_lora_rank: Optional[int] = 1536   # None: the query straight from x
     qk_rope_head_dim: int = 64
     qk_nope_head_dim: int = 128
     v_head_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (``rope_scaling`` of type "yarn")."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -59,7 +77,12 @@ class ArchConfig:
     ffn: str = "swiglu"              # swiglu | gelu | none
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnConfig] = None
     pos_emb: str = "rope"            # rope | learned | none
+    norm_eps: float = 1e-5
+    # leading dense layers before the repeating groups (deepseek's
+    # first_k_dense_replace); counted in num_layers
+    first_k_dense: int = 0
     tie_embeddings: bool = False
     moe: Optional[MoEConfig] = None
     mla: Optional[MLAConfig] = None
@@ -87,10 +110,11 @@ class ArchConfig:
 
     @property
     def num_groups(self) -> int:
-        assert self.num_layers % self.group_size == 0, (
-            f"{self.name}: num_layers={self.num_layers} not divisible by "
-            f"block group {self.group_size}")
-        return self.num_layers // self.group_size
+        stacked = self.num_layers - self.first_k_dense
+        assert stacked % self.group_size == 0, (
+            f"{self.name}: {stacked} layers after the leading dense ones "
+            f"not divisible by block group {self.group_size}")
+        return stacked // self.group_size
 
     def reduced(self) -> "ArchConfig":
         """Smoke-test variant: <=2 groups, d_model<=256, <=4 experts."""
@@ -99,24 +123,31 @@ class ArchConfig:
         kv = min(self.num_kv_heads, heads)
         moe = None
         if self.moe is not None:
+            experts = min(4, self.moe.num_experts)
+            held = self.moe.experts_held
             moe = dataclasses.replace(
-                self.moe, num_experts=min(4, self.moe.num_experts),
+                self.moe, num_experts=experts,
                 top_k=min(2, self.moe.top_k),
                 num_shared_experts=min(1, self.moe.num_shared_experts),
-                expert_d_ff=min(self.moe.expert_d_ff or self.d_ff, 512) or None)
+                expert_d_ff=min(self.moe.expert_d_ff or self.d_ff, 512) or None,
+                experts_held=None if held is None else min(held, experts))
         mla = None
         if self.mla is not None:
-            mla = MLAConfig(kv_lora_rank=64, q_lora_rank=96,
+            mla = MLAConfig(kv_lora_rank=64,
+                            q_lora_rank=(None if self.mla.q_lora_rank is None
+                                         else 96),
                             qk_rope_head_dim=16, qk_nope_head_dim=32,
                             v_head_dim=32)
         ssm = None
         if self.ssm is not None:
             ssm = dataclasses.replace(self.ssm, state_dim=8, chunk=32)
+        first = min(self.first_k_dense, 1)
         return dataclasses.replace(
-            self, num_layers=self.group_size * min(2, self.num_groups),
+            self, num_layers=first + self.group_size * min(2, self.num_groups),
+            first_k_dense=first,
             d_model=d_model, num_heads=heads, num_kv_heads=kv,
             d_ff=min(self.d_ff, 512), vocab_size=min(self.vocab_size, 512),
-            head_dim=d_model // heads if self.head_dim is not None or True else None,
+            head_dim=d_model // heads if self.head_dim is not None else None,
             moe=moe, mla=mla, ssm=ssm,
             encoder_layers=min(self.encoder_layers, 2),
             encoder_seq=min(self.encoder_seq, 64),
@@ -134,8 +165,10 @@ class ArchConfig:
         # attention
         if self.mla is not None:
             m = self.mla
-            attn = (d * m.q_lora_rank
-                    + m.q_lora_rank * self.num_heads * (m.qk_nope_head_dim + m.qk_rope_head_dim)
+            q_out = self.num_heads * (m.qk_nope_head_dim + m.qk_rope_head_dim)
+            q_proj = (d * q_out if m.q_lora_rank is None
+                      else d * m.q_lora_rank + m.q_lora_rank * q_out)
+            attn = (q_proj
                     + d * (m.kv_lora_rank + m.qk_rope_head_dim)
                     + m.kv_lora_rank * self.num_heads * (m.qk_nope_head_dim + m.v_head_dim)
                     + self.num_heads * m.v_head_dim * d)
@@ -154,7 +187,8 @@ class ArchConfig:
         if self.moe is not None:
             edff = self.moe.expert_d_ff or dff
             e_ffn = 3 * d * edff
-            moe_p = (self.moe.num_experts + self.moe.num_shared_experts) * e_ffn
+            held = self.moe.experts_held or self.moe.num_experts
+            moe_p = (held + self.moe.num_shared_experts) * e_ffn
             moe_p += d * self.moe.num_experts  # router
             if self.moe.dense_residual:
                 moe_p += ffn
@@ -172,7 +206,9 @@ class ArchConfig:
         total = V * d  # embedding
         if not self.tie_embeddings:
             total += V * d
-        for i in range(self.num_layers):
+        total += self.first_k_dense * (per_layer["attn"]
+                                       + per_layer["ffn_dense"])
+        for i in range(self.num_layers - self.first_k_dense):
             kind = self.block_pattern[i % self.group_size]
             if kind == "attn":
                 total += per_layer["attn"]
@@ -194,17 +230,20 @@ class ArchConfig:
         return total
 
     def num_active_params(self) -> int:
-        """Active params per token (MoE top-k only)."""
+        """Active params per token (MoE top-k only; of a share of the
+        experts, the top-k slots expected to land on it)."""
         if self.moe is None:
             return self.num_params()
         edff = self.moe.expert_d_ff or self.d_ff
         e_ffn = 3 * self.d_model * edff
-        inactive = (self.moe.num_experts - self.moe.top_k) * e_ffn
+        m = self.moe
+        held = m.experts_held or m.num_experts
+        inactive = (held - m.top_k * held / m.num_experts) * e_ffn
         n_moe_layers = sum(
-            1 for i in range(self.num_layers)
+            1 for i in range(self.num_layers - self.first_k_dense)
             if self.block_pattern[i % self.group_size] in ("attn", "mamba")
             and i % self.moe.every_n_layers == 0)
-        return self.num_params() - n_moe_layers * inactive
+        return int(self.num_params() - n_moe_layers * inactive)
 
 
 @dataclasses.dataclass(frozen=True)

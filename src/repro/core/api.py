@@ -33,8 +33,11 @@ from repro.core.protocol import RoundLog, StopConditions, run_federated
 from repro.core.server import Server, get_strategy
 from repro.metaheuristics import REGISTRY
 
-TASKS = ("cnn", "mlp")
+TASKS = ("cnn", "mlp", "lm")
 PARTITIONS = ("iid", "dirichlet")
+GENOMES = ("flat", "tensor")
+# packed sequence length of the token data build_experiment makes itself
+LM_SEQ_LEN = 32
 
 
 def strategy_names() -> tuple:
@@ -51,7 +54,10 @@ class FLConfig:
     through ``repro.core.knobs``.
     """
     strategy: str = "fedbwo"
-    task: str = "cnn"               # "cnn" (paper) | "mlp" (FedAvg 2NN)
+    # "cnn" (paper) | "mlp" (FedAvg 2NN) | "lm" (a language model of the
+    # registry, ``arch``: next-token loss over packed documents)
+    task: str = "cnn"
+    arch: Optional[str] = None      # repro.configs.ARCHS name (task "lm")
     n_clients: int = 10
     client_ratio: float = 1.0       # C — FedAvg participation ratio
     partition: str = "iid"          # "iid" | "dirichlet"
@@ -63,6 +69,12 @@ class FLConfig:
     lr: float = 0.0025              # paper §IV-A
     mh_pop: int = 6
     mh_generations: int = 3
+    fitness_batches: int = 2        # batches a fitness score is over
+    # the meta-heuristic's genome: "flat", the weight vector (the
+    # paper's), or "tensor", one gain per weight tensor decoded as
+    # w * (1 + genome_scale * (z - 1)) (ClientHP.subspace)
+    genome: str = "flat"
+    genome_scale: float = 0.05
     engine: str = "auto"            # repro.core.knobs.ENGINES
     vectorize: str = "auto"         # knobs.VECTORIZE_MODES, opt. ":k"
     # rounds fused into one device dispatch ("auto" | int >= 1): R > 1
@@ -97,6 +109,13 @@ class FLConfig:
             raise ValueError(f"eval_every={self.eval_every} must be >= 1")
         if self.task not in TASKS:
             raise ValueError(f"task={self.task!r} not in {TASKS}")
+        if (self.task == "lm") != (self.arch is not None):
+            raise ValueError("task='lm' needs arch, and only it takes one")
+        if self.task == "lm" and self.partition != "iid":
+            raise ValueError("task='lm' has no labels to skew; its "
+                             "skew is the data's own")
+        if self.genome not in GENOMES:
+            raise ValueError(f"genome={self.genome!r} not in {GENOMES}")
         if self.partition not in PARTITIONS:
             raise ValueError(
                 f"partition={self.partition!r} not in {PARTITIONS}")
@@ -111,6 +130,9 @@ class FLConfig:
         return ClientHP(local_epochs=self.local_epochs, lr=self.lr,
                         mh_pop=self.mh_pop,
                         mh_generations=self.mh_generations,
+                        fitness_batches=self.fitness_batches,
+                        subspace=self.genome == "tensor",
+                        subspace_scale=self.genome_scale,
                         vectorize=self.vectorize)
 
     def stop_conditions(self) -> StopConditions:
@@ -142,13 +164,24 @@ def build_experiment(cfg: FLConfig, *, task: Optional[Task] = None,
     # module-level import here would cycle through the package inits
     from repro.data.loader import client_batches
     from repro.data.partition import partition_dirichlet, partition_iid
-    from repro.data.synthetic import cnn_task, make_cifar_like, mlp_task
+    from repro.data.synthetic import (cnn_task, lm_task, make_cifar_like,
+                                      make_packed_tokens, mlp_task)
 
     if task is None:
-        task = cnn_task() if cfg.task == "cnn" else mlp_task()
+        if cfg.task == "lm":
+            from repro.configs import get_arch
+            task = lm_task(get_arch(cfg.arch))
+        else:
+            task = cnn_task() if cfg.task == "cnn" else mlp_task()
     if client_data is None or eval_data is None:
-        train, test = make_cifar_like(jax.random.PRNGKey(cfg.data_seed),
-                                      cfg.n_train, cfg.n_test)
+        if cfg.task == "lm":
+            from repro.configs import get_arch
+            train, test = make_packed_tokens(
+                jax.random.PRNGKey(cfg.data_seed), cfg.n_train, cfg.n_test,
+                LM_SEQ_LEN, get_arch(cfg.arch).vocab_size)
+        else:
+            train, test = make_cifar_like(jax.random.PRNGKey(cfg.data_seed),
+                                          cfg.n_train, cfg.n_test)
         if eval_data is None:
             eval_data = test
         if client_data is None:
@@ -233,5 +266,6 @@ class ExperimentResult:
             "block_timing": meter.timing_summary(),
             "sgd_steps": meter.sgd_step_summary(),
             "bwo_rows": meter.bwo_row_summary(),
+            "expert_slots": meter.expert_slot_summary(),
             f"normalized_cost_vs_fedavg{fedavg_rounds}": cost,
         }

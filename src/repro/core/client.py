@@ -29,9 +29,17 @@ from repro.metaheuristics.base import best_member
 
 
 class Task(NamedTuple):
-    """A trainable task: loss_fn(params, batch) -> (loss, acc)."""
+    """A trainable task: loss_fn(params, batch) -> (loss, acc).
+
+    ``slot_loss(params, batch) -> (loss, slots)``, where given, is the
+    training step's loss together with the int32 expert-slot counts of
+    its forward pass (a model whose expert layers hold a share of the
+    experts): local SGD then sums them over its steps and the client
+    update returns them last (``CommMeter.expert_slots``)."""
     init_params: Callable[[jax.Array], Any]
     loss_fn: Callable[[Any, Any], Tuple[jnp.ndarray, jnp.ndarray]]
+    slot_loss: Optional[Callable[[Any, Any], Tuple[jnp.ndarray,
+                                                   jnp.ndarray]]] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -76,57 +84,78 @@ def make_local_sgd(task: Task, hp: ClientHP, masked: bool = False,
     crucially for parity with the same client's unpadded run — the PRNG
     carry only advances past valid batches, so the per-batch dropout
     keys match the sequential engine's bit for bit.
+
+    Where the task counts expert slots (``task.slot_loss``),
+    ``local_sgd`` returns ``(params, slots)``: the counts summed over
+    the valid steps.
     """
+    counted = task.slot_loss is not None
 
     def one_step(params, batch, dkey, anchor=None):
         def obj(p):
-            loss = task.loss_fn(p, {**batch, "rng": dkey})[0]
+            keyed = {**batch, "rng": dkey}
+            loss, slots = (task.slot_loss(p, keyed) if counted
+                           else (task.loss_fn(p, keyed)[0], None))
             if hp.prox_mu > 0 and anchor is not None:   # FedProx
                 sq = sum(jnp.sum(jnp.square(a.astype(jnp.float32)
                                             - b.astype(jnp.float32)))
                          for a, b in zip(jax.tree.leaves(p),
                                          jax.tree.leaves(anchor)))
                 loss = loss + 0.5 * hp.prox_mu * sq
-            return loss
+            return loss, slots
 
-        grads = jax.grad(obj)(params)
+        grads, slots = jax.grad(obj, has_aux=True)(params)
         return jax.tree.map(
-            lambda p, g: p - hp.lr * g.astype(p.dtype), params, grads)
+            lambda p, g: p - hp.lr * g.astype(p.dtype), params,
+            grads), slots
 
-    def sgd_epoch(params, data, rng, anchor, mask):
+    def sgd_epoch(params, data, rng, anchor, mask, total):
         def one_batch(carry, xs):
-            params, rng = carry
+            params, rng, total = carry
             batch, valid = xs if masked else (xs, None)
             rng2, dkey = jax.random.split(rng)
-            new_params = one_step(params, batch, dkey, anchor)
+            new_params, slots = one_step(params, batch, dkey, anchor)
             if masked:
                 new_params = jax.tree.map(
                     lambda n, p: jnp.where(valid, n, p), new_params, params)
                 rng2 = jnp.where(valid, rng2, rng)
-            return (new_params, rng2), None
+                if counted:
+                    slots = jnp.where(valid, slots, 0)
+            if counted:
+                total = total + slots
+            return (new_params, rng2, total), None
 
         n_batches = jax.tree.leaves(data)[0].shape[0]
-        (params, _), _ = jax.lax.scan(
-            one_batch, (params, rng), (data, mask) if masked else data,
+        (params, _, total), _ = jax.lax.scan(
+            one_batch, (params, rng, total),
+            (data, mask) if masked else data,
             unroll=n_batches if unroll else 1)
-        return params
+        return params, total
 
     def local_sgd(params, data, rng, mask=None):
         with jax.named_scope(tracing.LOCAL_SGD):
             anchor = params if hp.prox_mu > 0 else None  # w_global (FedProx)
+            total = None
+            if counted:
+                total = jnp.zeros_like(jax.eval_shape(
+                    task.slot_loss, params,
+                    {**jax.tree.map(lambda a: a[0], data),
+                     "rng": rng})[1])
             if unroll:
                 for _ in range(hp.local_epochs):
                     rng, ekey = jax.random.split(rng)
-                    params = sgd_epoch(params, data, ekey, anchor, mask)
-                return params
-
-            def body(_, carry):
-                params, rng = carry
-                rng, ekey = jax.random.split(rng)
-                return sgd_epoch(params, data, ekey, anchor, mask), rng
-            params, _ = jax.lax.fori_loop(0, hp.local_epochs, body,
-                                          (params, rng))
-            return params
+                    params, total = sgd_epoch(params, data, ekey, anchor,
+                                              mask, total)
+            else:
+                def body(_, carry):
+                    params, rng, total = carry
+                    rng, ekey = jax.random.split(rng)
+                    params, total = sgd_epoch(params, data, ekey, anchor,
+                                              mask, total)
+                    return params, rng, total
+                params, _, total = jax.lax.fori_loop(
+                    0, hp.local_epochs, body, (params, rng, total))
+            return (params, total) if counted else params
 
     return local_sgd
 
@@ -230,13 +259,21 @@ def make_client_update(task: Task, hp: ClientHP,
 
     ``backend`` is the platform the update is compiled for (default:
     ``jax.default_backend()``); it decides :func:`unrolls`.
+
+    Where the task counts expert slots (``task.slot_loss``) the update
+    returns ``(score, params, slots)``, the slots of its local SGD.
     """
     unroll = unrolls(backend)
     local_sgd = make_local_sgd(task, hp, masked=masked, unroll=unroll)
+    counted = task.slot_loss is not None
 
     def client_update(global_params, data, rng, mask=None):
         r_sgd, r_mh = jax.random.split(rng)
         params = local_sgd(global_params, data, r_sgd, mask)
+        tail = ()
+        if counted:
+            params, slots = params
+            tail = (slots,)
         n_valid = None if mask is None else jnp.sum(mask.astype(jnp.int32))
 
         if hp.subspace and mh is not None:
@@ -248,12 +285,12 @@ def make_client_update(task: Task, hp: ClientHP,
                                  unroll=unroll, n_valid=n_valid)
         if mh is None:
             score = fit_fn(x0[None])[0]
-            return score, params
+            return (score, params) + tail
         with jax.named_scope(tracing.BWO_EVOLVE):
             state = mh.init(r_mh, x0, hp.mh_pop, fit_fn)
             state = _evolve(mh, hp, r_mh, state, fit_fn, unroll)
             best, best_fit = best_member(state)
-            return best_fit, decode(best)
+            return (best_fit, decode(best)) + tail
 
     if masked:
         def masked_update(global_params, data, mask, rng):

@@ -10,6 +10,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Tuple
 
+import numpy as np
+
 SCORE_BYTES = 4  # one fp32 performance score — the paper's headline number
 
 # per-round strategy kinds recorded on the CommMeter ledger
@@ -96,10 +98,12 @@ class CommMeter:
     they are pricing; ``block_timings`` is the per-block wall/sync
     ledger filled by ``record_block_timing``, ``sgd_steps`` the
     per-round ``(real, computed)`` local SGD step counts filled by
-    ``record_sgd_steps``, and ``bwo_rows`` the per-round BWO population
-    rows drawn against a full draw's, filled by ``record_bwo_rows`` (all
-    kept out of ``summary()`` so byte ledgers of protocol-identical runs
-    stay comparable).
+    ``record_sgd_steps``, ``bwo_rows`` the per-round BWO population
+    rows drawn against a full draw's, filled by ``record_bwo_rows``, and
+    ``expert_slots`` the per-round token-slots that local SGD routed to
+    each held expert of each expert layer, filled by
+    ``record_expert_slots`` (all kept out of ``summary()`` so byte
+    ledgers of protocol-identical runs stay comparable).
     """
     model_bytes: int
     n_clients: int
@@ -112,6 +116,7 @@ class CommMeter:
         default_factory=list)
     bwo_rows: List[Tuple[int, int, int, int]] = dataclasses.field(
         default_factory=list)
+    expert_slots: List[np.ndarray] = dataclasses.field(default_factory=list)
 
     def record_fedavg_round(self, n_participants: int):
         self.uplink.append(n_participants * self.model_bytes)
@@ -176,6 +181,28 @@ class CommMeter:
         return {"rounds": len(self.bwo_rows), "drawn": drawn, "full": full,
                 "drawn_frac": drawn / full if full else 0.0,
                 "init_drawn": init_drawn, "init_full": init_full}
+
+    def record_expert_slots(self, slots):
+        """One round's expert slots, summed over the participants and
+        their local SGD steps: ``(expert layers, held + 1)``, the slots
+        routed to each held expert of each layer, the absent experts'
+        last."""
+        self.expert_slots.append(np.asarray(slots, np.int64))
+
+    def expert_slot_summary(self) -> Dict[str, float]:
+        """Totals of the slot ledger over rounds and layers: each held
+        expert's slots, the absent experts', the held share of all slots
+        and the busiest held expert's load over the mean held load."""
+        if not self.expert_slots:
+            return {"rounds": 0}
+        total = np.sum(self.expert_slots, axis=0).sum(0)
+        held, absent = total[:-1], int(total[-1])
+        n = int(total.sum())
+        mean = float(held.mean())
+        return {"rounds": len(self.expert_slots),
+                "held": [int(h) for h in held], "absent": absent,
+                "held_share": float(held.sum()) / n if n else 0.0,
+                "max_over_mean": float(held.max()) / mean if mean else 0.0}
 
     def record_rounds(self, strategy, n_rounds: int,
                       n_participants: int = None,

@@ -193,10 +193,11 @@ def _fedx_round_body(task: Task, hp: ClientHP, mh: Metaheuristic,
                      vectorize: str = "auto", masked: bool = False,
                      backend: Optional[str] = None):
     """Un-jitted FedX round: ``round_fn(global_params, data, mask, keys)
-    -> (best_params, scores, best_idx)``.  Jitted standalone by
-    :func:`make_batched_fedx_round`; traced inline by the multi-round
-    fusion (:func:`make_fused_rounds`) so one XLA program spans a whole
-    block of rounds."""
+    -> (best_params, scores, best_idx)``, and the clients' summed expert
+    slots last where the task counts them (``Task.slot_loss``).  Jitted
+    standalone by :func:`make_batched_fedx_round`; traced inline by the
+    multi-round fusion (:func:`make_fused_rounds`) so one XLA program
+    spans a whole block of rounds."""
     mode = resolve_vectorize(vectorize, backend)
     client_update = make_client_update(task, hp, mh, masked=masked,
                                        backend=backend)
@@ -205,12 +206,13 @@ def _fedx_round_body(task: Task, hp: ClientHP, mh: Metaheuristic,
 
     if mode == "vmap":
         def round_fn(global_params, data, mask, keys):
-            scores, new = jax.vmap(update, in_axes=(None, 0, 0, 0))(
+            out = jax.vmap(update, in_axes=(None, 0, 0, 0))(
                 global_params, data, mask, keys)
+            scores, new = out[:2]
             with jax.named_scope(tracing.SERVER_REDUCE):
                 best = jnp.argmin(scores)
                 winner = jax.tree.map(lambda a: a[best], new)
-            return winner, scores, best
+            return (winner, scores, best) + tuple(s.sum(0) for s in out[2:])
     else:
         def round_fn(global_params, data, mask, keys):
             n = keys.shape[0]
@@ -218,21 +220,21 @@ def _fedx_round_body(task: Task, hp: ClientHP, mh: Metaheuristic,
             def step(carry, xs):
                 best_fit, best_params = carry
                 d, msk, k = xs
-                score, params = update(global_params, d, msk, k)
+                score, params, *slots = update(global_params, d, msk, k)
                 with jax.named_scope(tracing.SERVER_REDUCE):
                     take = score < best_fit
                     # streaming winner reduction: carry holds one model
                     best_params = _tree_where(take, params, best_params)
                     best_fit = jnp.minimum(score, best_fit)
-                return (best_fit, best_params), score
+                return (best_fit, best_params), (score, slots)
 
             init = (jnp.asarray(jnp.inf, jnp.float32), global_params)
-            (_, winner), scores = jax.lax.scan(
+            (_, winner), (scores, slots) = jax.lax.scan(
                 step, init, (data, mask, keys),
                 unroll=_scan_unroll(vectorize, mode, n))
             with jax.named_scope(tracing.SERVER_REDUCE):
                 best = jnp.argmin(scores)
-            return winner, scores, best
+            return (winner, scores, best) + tuple(s.sum(0) for s in slots)
 
     return round_fn
 
@@ -265,7 +267,8 @@ def _fedavg_round_body(task: Task, hp: ClientHP, vectorize: str = "auto",
                        backend: Optional[str] = None):
     """Un-jitted FedAvg round: ``round_fn(global_params, data, mask,
     keys) -> (avg_params, scores)`` over the (already gathered)
-    participant axis.  See :func:`_fedx_round_body`."""
+    participant axis, and the summed expert slots last where the task
+    counts them.  See :func:`_fedx_round_body`."""
     mode = resolve_vectorize(vectorize, backend)
     client_update = make_client_update(task, hp, None, masked=masked,
                                        backend=backend)
@@ -277,26 +280,27 @@ def _fedavg_round_body(task: Task, hp: ClientHP, vectorize: str = "auto",
         if on_trace is not None:
             on_trace(m)
         if mode == "vmap":
-            scores, new = jax.vmap(update, in_axes=(None, 0, 0, 0))(
+            out = jax.vmap(update, in_axes=(None, 0, 0, 0))(
                 global_params, data, mask, keys)
+            scores, new = out[:2]
             with jax.named_scope(tracing.SERVER_REDUCE):
                 avg = jax.tree.map(lambda a: jnp.mean(a, axis=0), new)
-            return avg, scores
+            return (avg, scores) + tuple(s.sum(0) for s in out[2:])
 
         def step(acc, xs):
             d, msk, k = xs
-            score, params = update(global_params, d, msk, k)
+            score, params, *slots = update(global_params, d, msk, k)
             # running mean accumulated in place (carry buffer)
             with jax.named_scope(tracing.SERVER_REDUCE):
                 acc = jax.tree.map(lambda s, p: s + p / m, acc, params)
-            return acc, score
+            return acc, (score, slots)
 
         with jax.named_scope(tracing.SERVER_REDUCE):
             acc0 = jax.tree.map(jnp.zeros_like, global_params)
-        avg, scores = jax.lax.scan(
+        avg, (scores, slots) = jax.lax.scan(
             step, acc0, (data, mask, keys),
             unroll=_scan_unroll(vectorize, mode, m))
-        return avg, scores
+        return (avg, scores) + tuple(s.sum(0) for s in slots)
 
     return round_fn
 
@@ -348,6 +352,8 @@ def make_fused_rounds(task: Task, strategy, hp: ClientHP,
 
     * FedX:   ``{"scores": (R, n), "best": (R,)}``
     * FedAvg: ``{"scores": (R, m), "participants": (R, m)}``
+    * plus ``{"expert_slots": (R, ...)}`` where the task counts expert
+      slots (``Task.slot_loss``)
     * plus ``{"eval_loss": (R,), "eval_acc": (R,)}`` when ``eval_every
       > 0`` and an ``eval_batch`` is passed — ``task.loss_fn`` on the
       held-out batch folded into the scan under ``lax.cond``, NaN on
@@ -400,8 +406,8 @@ def make_fused_rounds(task: Task, strategy, hp: ClientHP,
             keys = jax.random.split(rng, n_clients + 2)
             rng, sel_key, ckeys = keys[0], keys[1], keys[2:]
             if is_fedx:
-                new_params, scores, best = round_body(params, data, mask,
-                                                      ckeys)
+                new_params, scores, best, *slots = round_body(
+                    params, data, mask, ckeys)
                 log = {"scores": scores, "best": best}
             else:
                 # on-device sample-then-stack: same choice op and key as
@@ -412,10 +418,11 @@ def make_fused_rounds(task: Task, strategy, hp: ClientHP,
                                    data)
                 msk = (None if mask is None
                        else jnp.take(mask, sel, axis=0))
-                new_params, scores = round_body(params, sub, msk,
-                                                jnp.take(ckeys, sel,
-                                                         axis=0))
+                new_params, scores, *slots = round_body(
+                    params, sub, msk, jnp.take(ckeys, sel, axis=0))
                 log = {"scores": scores, "participants": sel}
+            if slots:
+                log["expert_slots"] = slots[0]
             if do_eval:
                 due = (round_offset + i + 1) % eval_every == 0
                 with jax.named_scope(tracing.EVAL):
@@ -533,11 +540,12 @@ class BatchedRoundEngine:
                      eval_batch, jnp.asarray(round_offset, jnp.int32))
 
     def fedx_round(self, global_params, keys):
-        """-> (winner_params, scores, best_idx); one dispatch, no sync."""
+        """-> (winner_params, scores, best_idx[, expert slots]); one
+        dispatch, no sync."""
         return self._round(global_params, self.data, self.mask, keys)
 
     def fedavg_round(self, global_params, sel_key, keys):
-        """-> (avg_params, scores, sel).
+        """-> (avg_params, scores, sel[, expert slots]).
 
         Sample-then-stack: the participant choice is materialized on
         host, the ``(m, ...)`` shards are gathered outside the round
@@ -548,9 +556,9 @@ class BatchedRoundEngine:
         sub = jax.tree.map(lambda a: jnp.take(a, sel, axis=0), self.data)
         mask = (None if self.mask is None
                 else jnp.take(self.mask, sel, axis=0))
-        avg, scores = self._round(global_params, sub, mask,
-                                  jnp.take(keys, sel, axis=0))
-        return avg, scores, sel
+        avg, scores, *slots = self._round(global_params, sub, mask,
+                                          jnp.take(keys, sel, axis=0))
+        return (avg, scores, sel) + tuple(slots)
 
 
 # ----------------------------------------------------------- pipeline --
@@ -621,7 +629,7 @@ def make_sharded_fedx_round(task: Task, hp: ClientHP, mh: Metaheuristic,
                                    backend=backend)
 
     def per_shard(params, data, keys):
-        local_best, local_scores, _ = local_round(params, data, None, keys)
+        local_best, local_scores = local_round(params, data, None, keys)[:2]
         with jax.named_scope(tracing.SERVER_REDUCE):
             k = local_scores.shape[0]
             scores = jax.lax.all_gather(local_scores, axis, tiled=True)
@@ -651,7 +659,7 @@ def make_sharded_fedavg_round(task: Task, hp: ClientHP, mesh: Mesh,
                                      backend=backend)
 
     def per_shard(params, data, keys):
-        local_avg, local_scores = local_round(params, data, None, keys)
+        local_avg, local_scores = local_round(params, data, None, keys)[:2]
         with jax.named_scope(tracing.SERVER_REDUCE):
             n = jax.lax.psum(1.0, axis)
             avg = jax.tree.map(

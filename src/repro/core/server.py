@@ -325,6 +325,8 @@ class Server:
                 out = jax.device_get(pending.logs)
             t1 = time.perf_counter()
             with jax.profiler.TraceAnnotation("Server.finish_block.process"):
+                for slots in out.get("expert_slots", ()):
+                    self.meter.record_expert_slots(slots)
                 if self.strategy.is_fedx:
                     self.meter.record_rounds(self.strategy, n_rounds,
                                              fetched_model=True)
@@ -427,26 +429,30 @@ class Server:
 
     def _run_round_batched(self, sel_key, ckeys) -> dict:
         if self.strategy.is_fedx:
-            new_params, scores, best = self._engine.fedx_round(
+            new_params, scores, best, *slots = self._engine.fedx_round(
                 self.global_params, ckeys)
             self.global_params = new_params
             self.meter.record_fedx_round(fetched_model=True)
             self._record_counters()
             with jax.profiler.TraceAnnotation("Server.run_round.sync"):
                 # the round's single device->host sync
-                scores, best = jax.device_get((scores, best))
+                scores, best, slots = jax.device_get((scores, best, slots))
+            for s in slots:
+                self.meter.record_expert_slots(s)
             best = int(best)
             return {"best_client": best, "score": float(scores[best]),
                     "scores": [float(s) for s in scores],
                     "engine": "batched"}
-        new_params, scores, sel = self._engine.fedavg_round(
+        new_params, scores, sel, *slots = self._engine.fedavg_round(
             self.global_params, sel_key, ckeys)
         self.global_params = new_params
         self.meter.record_fedavg_round(self._engine.n_participants)
         with jax.profiler.TraceAnnotation("Server.run_round.sync"):
             # the round's single device->host sync; scores align with the
             # participants list (FedX scores cover all clients)
-            sel, scores = jax.device_get((sel, scores))
+            sel, scores, slots = jax.device_get((sel, scores, slots))
+        for s in slots:
+            self.meter.record_expert_slots(s)
         self._record_counters(sel)
         return {"participants": [int(k) for k in sel],
                 "scores": [float(s) for s in scores],
@@ -455,15 +461,19 @@ class Server:
     def _run_round_sequential(self, sel_key, ckeys) -> dict:
         if self.strategy.is_fedx:
             # every client trains + refines, uploads only its score
-            scores, params_list = [], []
+            scores, params_list, slots = [], [], []
             for k in range(self.n_clients):
-                score, params = self._update(self.global_params,
-                                             self.client_data[k], ckeys[k])
+                score, params, *counts = self._update(
+                    self.global_params, self.client_data[k], ckeys[k])
                 scores.append(score)
                 params_list.append(params)
+                slots += counts
             # one host sync per round, after all clients have dispatched
             with jax.profiler.TraceAnnotation("Server.run_round.sync"):
-                scores = np.asarray(jax.device_get(jnp.stack(scores)))
+                scores, slots = jax.device_get((jnp.stack(scores), slots))
+                scores = np.asarray(scores)
+            if slots:
+                self.meter.record_expert_slots(sum(slots))
             best = int(scores.argmin())
             # GetBestModel: one full-model transfer from the winner only
             self.global_params = params_list[best]
@@ -475,18 +485,22 @@ class Server:
         # ---- FedAvg ----
         m = max(int(self.strategy.client_ratio * self.n_clients), 1)
         sel = jax.random.choice(sel_key, self.n_clients, (m,), replace=False)
-        scores, new_params = [], []
+        scores, new_params, slots = [], [], []
         for k in sel.tolist():
-            score, params = self._update(self.global_params,
-                                         self.client_data[k], ckeys[k])
+            score, params, *counts = self._update(
+                self.global_params, self.client_data[k], ckeys[k])
             scores.append(score)
             new_params.append(params)
+            slots += counts
         self.global_params = jax.tree.map(
             lambda *xs: jnp.mean(jnp.stack(xs), 0), *new_params)
         # one host sync for the participants' scores, after all have
         # dispatched; aligned with the participants list
         with jax.profiler.TraceAnnotation("Server.run_round.sync"):
-            scores = np.asarray(jax.device_get(jnp.stack(scores)))
+            scores, slots = jax.device_get((jnp.stack(scores), slots))
+            scores = np.asarray(scores)
+        if slots:
+            self.meter.record_expert_slots(sum(slots))
         self.meter.record_fedavg_round(m)
         self._record_counters(sel.tolist())
         return {"participants": sel.tolist(),

@@ -13,6 +13,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
+from repro.configs.base import ArchConfig
 from repro.configs.paper_cnn import CNNConfig
 from repro.core.client import Task
 from repro.models import cnn as cnn_lib
@@ -123,3 +125,73 @@ def make_token_dataset(rng, n_seqs: int, seq_len: int, vocab: int,
             "labels": jnp.concatenate(
                 [toks[:, 1:], jnp.full((n_seqs, 1), -1, toks.dtype)],
                 axis=1).astype(jnp.int32)}
+
+
+def make_packed_tokens(rng, n_train: int, n_test: int, seq_len: int,
+                       vocab: int) -> Tuple[dict, dict]:
+    """(train, test) dicts of packed sequences for :func:`lm_task`:
+    ``tokens`` (N, seq_len + 1) from :func:`make_token_dataset` and
+    ``segments``, two documents a row split at a random position."""
+    r_tok, r_cut = jax.random.split(rng)
+    n = n_train + n_test
+    tokens = make_token_dataset(r_tok, n, seq_len + 1, vocab)["tokens"]
+    cut = jax.random.randint(r_cut, (n, 1), 1, seq_len + 1)
+    segments = (jnp.arange(seq_len + 1)[None, :] >= cut).astype(jnp.int32)
+    data = {"segments": segments, "tokens": tokens}
+    return (jax.tree.map(lambda a: a[:n_train], data),
+            jax.tree.map(lambda a: a[n_train:], data))
+
+
+def lm_task(cfg: ArchConfig) -> Task:
+    """Next-token language modelling with a registry architecture.
+
+    A batch holds packed sequences ``{"tokens", "segments"}`` of shape
+    (B, T + 1): token ids and the document id of each position.  The
+    model reads positions ``0..T-1`` (positions restart at each document
+    and no query attends outside its document) and predicts the next
+    token; only targets in the same document as their input count.  The
+    loss and accuracy are means over those targets of the whole batch.
+    ``slot_loss``, the training loss where the model's expert layers
+    hold a share of the experts, also returns the expert slots of its
+    forward pass, ``(expert layers, held + 1)``.  No auxiliary routing
+    loss enters the loss.
+    """
+    from repro.models.transformer import build_model
+    model = build_model(cfg)
+
+    def init_params(rng):
+        return model.init(rng)
+
+    def sums(params, tokens, segments):
+        """(nll sum, hits, targets, slots) of sequences (B, T + 1)."""
+        logits, _, _, slots = model.apply(
+            params, {"tokens": tokens[:, :-1], "segments": segments[:, :-1]},
+            mode="train", with_slots=True)
+        with jax.named_scope(scopes.LM_HEAD):
+            target = tokens[:, 1:]
+            same = (segments[:, 1:] == segments[:, :-1]).astype(jnp.float32)
+            logp = jax.nn.log_softmax(logits)
+            nll = -jnp.take_along_axis(logp, target[..., None], -1)[..., 0]
+            hit = (logits.argmax(-1) == target).astype(jnp.float32)
+            return (nll * same).sum(), (hit * same).sum(), same.sum(), slots
+
+    def loss_fn(params, batch):
+        # one sequence at a time: a test set of any size costs one
+        # sequence's activations
+        def one(carry, seq):
+            return carry + jnp.stack(sums(params, seq[0][None],
+                                          seq[1][None])[:3]), None
+        (nll, hit, n), _ = jax.lax.scan(
+            one, jnp.zeros((3,), jnp.float32),
+            (batch["tokens"], batch["segments"]))
+        n = jnp.maximum(n, 1.0)
+        return nll / n, hit / n
+
+    def slot_loss(params, batch):
+        # the whole batch at once: a scan would carry a gradient
+        # accumulator of the weights it closes over
+        nll, _, n, slots = sums(params, batch["tokens"], batch["segments"])
+        return nll / jnp.maximum(n, 1.0), slots
+
+    counted = cfg.moe is not None and cfg.moe.experts_held is not None
+    return Task(init_params, loss_fn, slot_loss if counted else None)

@@ -11,7 +11,7 @@ import argparse
 import json
 
 from repro.core import FLConfig, build_experiment
-from repro.core.api import strategy_names, PARTITIONS, TASKS
+from repro.core.api import GENOMES, strategy_names, PARTITIONS, TASKS
 from repro.launch.compile_cache import enable_compile_cache
 from repro.core.knobs import (AUDIT_MODES, validate_audit,
                               validate_engine,
@@ -26,7 +26,13 @@ def main():
                     choices=list(strategy_names()))
     ap.add_argument("--task", default="cnn", choices=list(TASKS),
                     help="cnn = the paper's CNN; mlp = FedAvg 2NN "
-                         "(dense — batches on every backend)")
+                         "(dense — batches on every backend); lm = a "
+                         "language model of the registry (--arch)")
+    ap.add_argument("--arch", default=None,
+                    help="repro.configs.ARCHS name of the lm task's model")
+    ap.add_argument("--genome", default="flat", choices=list(GENOMES),
+                    help="flat = BWO over the weight vector (the "
+                         "paper's); tensor = one gain per weight tensor")
     ap.add_argument("--clients", type=int, default=10)
     ap.add_argument("--rounds", type=int, default=8)
     ap.add_argument("--client-ratio", type=float, default=1.0)
@@ -81,7 +87,8 @@ def main():
     enable_compile_cache()
 
     cfg = FLConfig(
-        strategy=args.strategy, task=args.task, n_clients=args.clients,
+        strategy=args.strategy, task=args.task, arch=args.arch,
+        genome=args.genome, n_clients=args.clients,
         client_ratio=args.client_ratio,
         partition="dirichlet" if args.non_iid else "iid",
         dirichlet_alpha=args.alpha, n_train=args.train, n_test=args.test,

@@ -9,11 +9,13 @@ the numerical oracle for the Pallas flash-attention kernel
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
 from repro.configs.base import ArchConfig
 from repro.models import modules as nn
 
@@ -23,18 +25,22 @@ NEG_INF = -1e30
 # ----------------------------------------------------------------- core --
 def blockwise_attention(q, k, v, *, causal: bool, window: Optional[int],
                         q_offset=0, kv_len: Optional[jnp.ndarray] = None,
-                        q_block: int = 1024):
+                        q_block: int = 1024, scale: Optional[float] = None,
+                        segments: Optional[jnp.ndarray] = None):
     """Memory-bounded attention.
 
     q: (B, Sq, H, hd);  k/v: (B, Sk, KV, hd) — GQA via head repeat.
     ``q_offset``: absolute position of q[0] (decode / chunked prefill).
     ``window``: sliding-window size (None = full).
     ``kv_len``: optional dynamic valid length of k/v (decode).
+    ``scale``: softmax scale (default ``hd ** -0.5``).
+    ``segments``: optional (B, S) document ids of a packed self-attention
+    sequence (Sq == Sk): a query attends only within its document.
     """
     B, Sq, H, hd = q.shape
     Sk, KV = k.shape[1], k.shape[2]
     rep = H // KV
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
     kT = k.transpose(0, 2, 3, 1)                      # (B, KV, hd, Sk)
     vT = v.transpose(0, 2, 1, 3)                      # (B, KV, Sk, hd)
     kv_pos = jnp.arange(Sk)
@@ -45,6 +51,9 @@ def blockwise_attention(q, k, v, *, causal: bool, window: Optional[int],
     pad = nb * q_block - Sq
     qp = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0))) if pad else q
     qp = qp.reshape(B, nb, q_block, H, hd)
+    if segments is not None:
+        seg_q = (jnp.pad(segments, ((0, 0), (0, pad)), constant_values=-1)
+                 if pad else segments).reshape(B, nb, q_block)
 
     def one_block(args):
         qb, block_idx = args                          # (B, q_block, H, hd)
@@ -63,6 +72,10 @@ def blockwise_attention(q, k, v, *, causal: bool, window: Optional[int],
         if kv_len_vec:                                # (B,) per-slot lengths
             mask = mask & (kv_pos[None, :] <
                            kv_len[:, None])[:, None, None, None]
+        if segments is not None:                      # same document only
+            sq = jax.lax.dynamic_index_in_dim(seg_q, block_idx, 1, False)
+            mask = mask & (sq[:, :, None] == segments[:, None, :]
+                           )[:, None, None]
         s = jnp.where(mask, s, NEG_INF)
         p = jax.nn.softmax(s, axis=-1)
         o = jnp.einsum("bgrqk,bgkd->bgrqd", p, vT.astype(jnp.float32))
@@ -145,9 +158,11 @@ def _slice_at(buf, start, length):
 
 def gqa_apply(p, x, *, cfg: ArchConfig, mode: str, positions,
               cache=None, cache_pos=None, kv_source=None,
-              window: Optional[int] = None, cross: bool = False):
+              window: Optional[int] = None, cross: bool = False,
+              segments=None):
     """Returns (y, new_cache).  kv_source: encoder output for cross-attn
-    (may be None during decode when the cross K/V cache is prefilled)."""
+    (may be None during decode when the cross K/V cache is prefilled).
+    ``segments``: (B, S) document ids of packed training sequences."""
     B, S, _ = x.shape
     hd = cfg.resolved_head_dim
     H, KV = cfg.num_heads, cfg.num_kv_heads
@@ -224,7 +239,8 @@ def gqa_apply(p, x, *, cfg: ArchConfig, mode: str, positions,
     else:  # train / prefill: full causal; encoder: bidirectional
         out = blockwise_attention(q, k, v, causal=(mode != "encode"),
                                   window=window,
-                                  q_block=min(1024, max(8, S)))
+                                  q_block=min(1024, max(8, S)),
+                                  segments=segments)
         if mode == "prefill" and cache is not None:
             if "k_scale" in cache:
                 kq, ks = _quantize_kv(k)
@@ -251,21 +267,83 @@ def gqa_apply(p, x, *, cfg: ArchConfig, mode: str, positions,
 
 # ------------------------------------------------------------------ MLA --
 def mla_init(rng, cfg: ArchConfig):
+    """MLA weights; with ``q_lora_rank`` None the query comes straight
+    from the hidden state through ``wq`` (DeepSeek-V2-Lite)."""
     m = cfg.mla
     d, H = cfg.d_model, cfg.num_heads
     r = jax.random.split(rng, 6)
     dt = cfg.param_dtype
     qk_dim = m.qk_nope_head_dim + m.qk_rope_head_dim
+    if m.q_lora_rank is None:
+        q = {"wq": nn.dense_init(r[0], d, H * qk_dim, dtype=dt)}
+    else:
+        q = {"wq_a": nn.dense_init(r[0], d, m.q_lora_rank, dtype=dt),
+             "q_norm": nn.norm_init("rmsnorm", m.q_lora_rank, dt),
+             "wq_b": nn.dense_init(r[1], m.q_lora_rank, H * qk_dim,
+                                   dtype=dt)}
     return {
-        "wq_a": nn.dense_init(r[0], d, m.q_lora_rank, dtype=dt),
-        "q_norm": nn.norm_init("rmsnorm", m.q_lora_rank, dt),
-        "wq_b": nn.dense_init(r[1], m.q_lora_rank, H * qk_dim, dtype=dt),
+        **q,
         "wkv_a": nn.dense_init(r[2], d, m.kv_lora_rank + m.qk_rope_head_dim, dtype=dt),
         "kv_norm": nn.norm_init("rmsnorm", m.kv_lora_rank, dt),
         "wkv_b": nn.dense_init(r[3], m.kv_lora_rank,
                                H * (m.qk_nope_head_dim + m.v_head_dim), dtype=dt),
         "wo": nn.dense_init(r[4], H * m.v_head_dim, d, dtype=dt),
     }
+
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_inv_freq(cfg: ArchConfig, dim: int):
+    """Inverse rotary frequencies of a ``dim``-wide rope; under YaRN
+    (``cfg.rope_scaling``) the blend of interpolated and original
+    frequencies of ``DeepseekV2YarnRotaryEmbedding`` in DeepSeek-V2's
+    reference modelling code (``modeling_deepseek.py``)."""
+    base = cfg.rope_theta
+    extra = nn.rope_freqs(dim, base)
+    y = cfg.rope_scaling
+    if y is None:
+        return extra
+
+    def correction_dim(rotations):
+        return (dim * math.log(y.original_max_position_embeddings
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+    low = max(math.floor(correction_dim(y.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(y.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp                   # 1: the original frequency
+    return extra / y.factor * (1.0 - keep) + extra * keep
+
+
+def mla_softmax_scale(cfg: ArchConfig) -> float:
+    """``(qk_nope + qk_rope) ** -0.5``, times YaRN's ``mscale ** 2`` with
+    ``mscale = 0.1 * mscale_all_dim * ln(factor) + 1`` where the config
+    sets ``mscale_all_dim`` (DeepSeek-V2's ``DeepseekV2Attention``)."""
+    m = cfg.mla
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    y = cfg.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        scale *= _yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
+def _rope(x, positions, cfg: ArchConfig):
+    """Rotary embedding of x (..., S, H, r) at ``positions``, with YaRN's
+    cos/sin factor ``mscale(mscale) / mscale(mscale_all_dim)``."""
+    out = nn.apply_rope(x, positions, cfg.rope_theta,
+                        freqs=rope_inv_freq(cfg, x.shape[-1]))
+    y = cfg.rope_scaling
+    if y is not None:
+        factor = (_yarn_mscale(y.factor, y.mscale)
+                  / _yarn_mscale(y.factor, y.mscale_all_dim or 1.0))
+        if factor != 1.0:
+            out = out * jnp.asarray(factor, out.dtype)
+    return out
 
 
 def mla_cache_init(cfg: ArchConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
@@ -279,24 +357,36 @@ def _mla_qkr(p, x, cfg, positions):
     m = cfg.mla
     B, S, _ = x.shape
     H = cfg.num_heads
-    q = nn.dense_apply(p["wq_b"], nn.norm_apply("rmsnorm", p["q_norm"],
-                                                nn.dense_apply(p["wq_a"], x)))
+    if "wq" in p:
+        q = nn.dense_apply(p["wq"], x)
+    else:
+        q = nn.dense_apply(p["wq_b"], nn.norm_apply(
+            "rmsnorm", p["q_norm"], nn.dense_apply(p["wq_a"], x),
+            eps=cfg.norm_eps))
     q = q.reshape(B, S, H, m.qk_nope_head_dim + m.qk_rope_head_dim)
     q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_rope = nn.apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = _rope(q_rope, positions, cfg)
     kv_a = nn.dense_apply(p["wkv_a"], x)
     c_kv, k_rope = jnp.split(kv_a, [m.kv_lora_rank], axis=-1)
-    c_kv = nn.norm_apply("rmsnorm", p["kv_norm"], c_kv)
-    k_rope = nn.apply_rope(k_rope[:, :, None, :], positions,
-                           cfg.rope_theta)[:, :, 0, :]
+    c_kv = nn.norm_apply("rmsnorm", p["kv_norm"], c_kv, eps=cfg.norm_eps)
+    k_rope = _rope(k_rope[:, :, None, :], positions, cfg)[:, :, 0, :]
     return q_nope, q_rope, c_kv, k_rope
 
 
 def mla_apply(p, x, *, cfg: ArchConfig, mode: str, positions,
-              cache=None, cache_pos=None, absorb: bool = True, **_):
+              cache=None, cache_pos=None, absorb: bool = True,
+              segments=None, **_):
     """DeepSeek-V2 MLA.  Decode uses the *absorbed* formulation (attend in
     latent space; W_uk folded into q, W_uv applied post-attention) so the
-    cache stays (kv_lora + rope) per position — the paper's memory win."""
+    cache stays (kv_lora + rope) per position — the paper's memory win.
+    ``segments``: (B, S) document ids of packed training sequences."""
+    with jax.named_scope(scopes.MLA):
+        return _mla(p, x, cfg=cfg, mode=mode, positions=positions,
+                    cache=cache, cache_pos=cache_pos, absorb=absorb,
+                    segments=segments)
+
+
+def _mla(p, x, *, cfg, mode, positions, cache, cache_pos, absorb, segments):
     m = cfg.mla
     B, S, _ = x.shape
     H = cfg.num_heads
@@ -318,7 +408,7 @@ def mla_apply(p, x, *, cfg: ArchConfig, mode: str, positions,
             # q_lat: (B,S,H,L) = q_nope absorbed through W_uk
             q_lat = jnp.einsum("bshn,lhn->bshl", q_nope.astype(jnp.float32),
                                w_uk.astype(jnp.float32))
-            scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+            scale = mla_softmax_scale(cfg)
             s = (jnp.einsum("bshl,btl->bhst", q_lat,
                             c_cache.astype(jnp.float32))
                  + jnp.einsum("bshr,btr->bhst", q_rope.astype(jnp.float32),
@@ -341,7 +431,8 @@ def mla_apply(p, x, *, cfg: ArchConfig, mode: str, positions,
             out = blockwise_attention(q_full, k_full.astype(q_full.dtype),
                                       v_full.astype(q_full.dtype),
                                       causal=False, window=None,
-                                      kv_len=kv_len, q_block=8)
+                                      kv_len=kv_len, q_block=8,
+                                      scale=mla_softmax_scale(cfg))
     else:
         # train / prefill: materialize per-head K/V (naive, paper-faithful)
         k_nope = jnp.einsum("btl,lhn->bthn", c_kv.astype(jnp.float32),
@@ -353,7 +444,9 @@ def mla_apply(p, x, *, cfg: ArchConfig, mode: str, positions,
                                       (*k_rope.shape[:2], H, m.qk_rope_head_dim))], -1)
         q_full = jnp.concatenate([q_nope, q_rope], -1)
         out = blockwise_attention(q_full, k_full, v_full, causal=True,
-                                  window=None, q_block=min(1024, max(8, S)))
+                                  window=None, q_block=min(1024, max(8, S)),
+                                  scale=mla_softmax_scale(cfg),
+                                  segments=segments)
         if mode == "prefill" and cache is not None:
             new_cache = {
                 "c_kv": jax.lax.dynamic_update_slice(

@@ -77,10 +77,12 @@ def rope_freqs(head_dim: int, theta: float):
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (..., S, H, hd); positions: (..., S)."""
+def apply_rope(x, positions, theta: float, freqs=None):
+    """x: (..., S, H, hd); positions: (..., S); ``freqs`` (hd/2,) the
+    inverse frequencies (default: ``rope_freqs(hd, theta)``)."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                       # (hd/2,)
+    if freqs is None:
+        freqs = rope_freqs(hd, theta)                   # (hd/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, hd/2)
     angles = angles[..., None, :]                       # (..., S, 1, hd/2)
     cos, sin = jnp.cos(angles), jnp.sin(angles)

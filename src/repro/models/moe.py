@@ -15,6 +15,10 @@ with ~1e14 link bytes per step on deepseek-v2 — see EXPERIMENTS.md §Perf
 for the before/after.
 
 Supports DeepSeek-V2 shared experts and Arctic's parallel dense residual.
+
+A config whose ``MoEConfig.experts_held`` is set runs :func:`share_apply`
+instead: the chip's share of an expert-parallel layer (the first
+``experts_held`` of ``num_experts`` experts), dropless, with no capacity.
 """
 from __future__ import annotations
 
@@ -26,6 +30,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
+from repro import scopes
 from repro.configs.base import ArchConfig
 from repro.models import modules as nn
 from repro.sharding import batch_axes, constrain
@@ -33,9 +38,12 @@ from repro.sharding.context import current_mesh
 
 
 def moe_init(rng, cfg: ArchConfig):
+    """Router over all ``num_experts``; expert weights of the experts
+    held (``experts_held``, default all)."""
     m = cfg.moe
     d = cfg.d_model
     dff = m.expert_d_ff or cfg.d_ff
+    held = m.experts_held or m.num_experts
     r = jax.random.split(rng, 6)
     dt = cfg.param_dtype
     scale = d ** -0.5
@@ -46,9 +54,9 @@ def moe_init(rng, cfg: ArchConfig):
     p = {
         "router": {"w": (jax.random.normal(r[0], (d, m.num_experts),
                                            jnp.float32) * scale)},
-        "wi": stack(r[1], (m.num_experts, d, dff)),
-        "wg": stack(r[2], (m.num_experts, d, dff)),
-        "wo": (jax.random.normal(r[3], (m.num_experts, dff, d), jnp.float32)
+        "wi": stack(r[1], (held, d, dff)),
+        "wg": stack(r[2], (held, d, dff)),
+        "wo": (jax.random.normal(r[3], (held, dff, d), jnp.float32)
                * dff ** -0.5).astype(dt),
     }
     if m.num_shared_experts:
@@ -75,24 +83,6 @@ def _gather_local(yb, e_flat, pos_c):
     return yb[bidx, e_flat, pos_c]
 
 
-def _gather_psum(yb_loc, e_flat, pos_c, *, E_loc):
-    """Expert-parallel combine: each model shard gathers only the slots
-    owned by its local experts and psums the partial result.
-
-    Moves 2 x (B,SK,d) over `model` instead of all-gathering the full
-    (B,E,C,d) buffer — a ~3.4x link-byte win at deepseek scale
-    (EXPERIMENTS.md §Perf deepseek iteration 3)."""
-    me = jax.lax.axis_index("model")
-    lo = me * E_loc
-    local = (e_flat >= lo) & (e_flat < lo + E_loc)
-    e_loc = jnp.clip(e_flat - lo, 0, E_loc - 1)
-    Bl, SK = e_flat.shape
-    bidx = jnp.broadcast_to(jnp.arange(Bl, dtype=jnp.int32)[:, None],
-                            (Bl, SK))
-    part = yb_loc[bidx, e_loc, pos_c] * local[..., None].astype(yb_loc.dtype)
-    return jax.lax.psum(part, "model")
-
-
 def _scatter_masked(contrib, e_flat, pos_c, *, E_loc, C):
     """Per-model-rank dispatch: scatter only the slots owned by local
     experts, producing an (B, E_loc, C, d) buffer that is *born* sharded
@@ -113,7 +103,10 @@ def _scatter_masked(contrib, e_flat, pos_c, *, E_loc, C):
 def _local_dispatch_fns(B: int, E: int, C: int):
     """shard_map-wrapped scatter/gather when a mesh is active and the
     batch divides the data axes; plain local ops otherwise (smoke tests,
-    B=1 decode)."""
+    B=1 decode).  The combine all-gathers the dispatch buffer: a psum of
+    each model shard's own slots was measured on deepseek-v2 train_4k
+    and moved 28% more link bytes (2 x (B,SK,d) a pass against the
+    all-gather's (E,C,d), within ~1.5x of each other at K=6, cf=1.25)."""
     import functools
     scatter = functools.partial(_scatter_local, E=E, C=C)
     mesh = current_mesh()
@@ -137,25 +130,10 @@ def _local_dispatch_fns(B: int, E: int, C: int):
             scatter, mesh=mesh,
             in_specs=(P(baxes, None, None), bs, bs),
             out_specs=P(baxes, None, None, None), check_vma=False)
-    import os
-    use_psum = os.environ.get("REPRO_MOE_COMBINE", "gather") == "psum"
-    # Measured on deepseek-v2 train_4k: the psum combine moves
-    # 2 x (B,SK,d) per pass vs the all-gather's (E,C,d) — with K=6 and
-    # cf=1.25 those are within ~1.5x and psum LOST (+28% link bytes).
-    # Hypothesis refuted; kept selectable for low-K configs where
-    # SK*d << E*C*d.  See EXPERIMENTS.md §Perf.
-    if use_psum and "model" in mesh.axis_names \
-            and E % mesh.shape["model"] == 0:
-        E_loc = E // mesh.shape["model"]
-        gather_sm = shard_map(
-            functools.partial(_gather_psum, E_loc=E_loc), mesh=mesh,
-            in_specs=(P(baxes, "model", None, None), bs, bs),
-            out_specs=P(baxes, None, None), check_vma=False)
-    else:
-        gather_sm = shard_map(
-            _gather_local, mesh=mesh,
-            in_specs=(P(baxes, None, None, None), bs, bs),
-            out_specs=P(baxes, None, None), check_vma=False)
+    gather_sm = shard_map(
+        _gather_local, mesh=mesh,
+        in_specs=(P(baxes, None, None, None), bs, bs),
+        out_specs=P(baxes, None, None), check_vma=False)
     return scatter_sm, gather_sm
 
 
@@ -170,7 +148,8 @@ def moe_apply(p, x, cfg: ArchConfig, *, capacity_factor: float = 1.25
     logits = x.astype(jnp.float32) @ p["router"]["w"]             # (B,S,E)
     probs = jax.nn.softmax(logits, axis=-1)
     gate, eidx = jax.lax.top_k(probs, K)                          # (B,S,K)
-    gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+    if m.renormalize:
+        gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
 
     # ---- load-balance auxiliary loss (Switch-style) ----
     me = probs.mean((0, 1))                                       # (E,)
@@ -219,3 +198,101 @@ def moe_apply(p, x, cfg: ArchConfig, *, capacity_factor: float = 1.25
     if m.dense_residual:
         y = y + nn.ffn_apply("swiglu", p["dense"], x)
     return y, aux
+
+
+# ------------------------------------------------------- the chip's share --
+def route(p, x, cfg: ArchConfig):
+    """Softmax over all ``num_experts`` router logits in float32, greedy
+    top-k; the gates are renormalised only where the config says so
+    (DeepSeek-V2's ``norm_topk_prob``).  x (N, d) -> gates, experts
+    (N, K)."""
+    logits = x.astype(jnp.float32) @ p["router"]["w"].astype(jnp.float32)
+    gate, eidx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                               cfg.moe.top_k)
+    if cfg.moe.renormalize:
+        gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+    return gate, eidx
+
+
+@jax.custom_vjp
+def _permute(a, order, inverse):
+    """The rows of ``a`` in the order ``order``, whose inverse permutation
+    is ``inverse``.  Its transpose is the inverse gather, where autodiff
+    of a plain ``take`` would scatter-add."""
+    return jnp.take(a, order, axis=0)
+
+
+def _permute_fwd(a, order, inverse):
+    return _permute(a, order, inverse), (order, inverse)
+
+
+def _permute_bwd(res, g):
+    order, inverse = res
+    return jnp.take(g, inverse, axis=0), None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def share_routed(p, x, cfg: ArchConfig, first_expert: int = 0):
+    """The held experts' part of an expert layer, dropless.
+
+    x (N, d).  Every token is routed over all ``num_experts``; of its
+    top-k slots, those that land on the held experts ``first_expert ..
+    first_expert + H - 1`` (H = the leading axis of ``p["wi"]``) are
+    sorted by expert and run as one grouped SwiGLU product over exactly
+    those slots (``jax.lax.ragged_dot``: no capacity, no slot dropped);
+    slots on absent experts add nothing.  Returns ``(y, slots)``: the
+    gate-weighted sum of the held experts' outputs (N, d), and the
+    slot count of each held expert with the absent experts' count last
+    (H + 1,) int32.
+
+    The products run over at least N rows: where fewer slots are held,
+    the last held group is stretched over zeroed absent slots, so the
+    grouped product does the same work for every routing that holds
+    fewer than N slots (an even router holds N * K * H / num_experts).
+    The slot permutation and its inverse are gathers both ways, and the
+    counts a comparison, so the layer's gradient scatters no slot row
+    to where the routing points."""
+    n, d = x.shape
+    held = p["wi"].shape[0]
+    K = cfg.moe.top_k
+    with jax.named_scope(scopes.MOE_ROUTE):
+        gate, eidx = route(p, x, cfg)
+        local = eidx.reshape(-1) - first_expert                 # (N*K,)
+        mine = (local >= 0) & (local < held)
+        group = jnp.where(mine, local, held).astype(jnp.int32)
+        slots = (group[:, None] == jnp.arange(held + 1)).sum(
+            0, dtype=jnp.int32)
+        order = jnp.argsort(group, stable=True)
+        back = jnp.argsort(order)
+        is_held = (group[order] < held)[:, None]
+        xs = jnp.where(is_held, _permute(jnp.repeat(x, K, axis=0), order,
+                                         back), 0)
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        sizes = slots[:held]
+        sizes = sizes.at[held - 1].add(jnp.maximum(n - sizes.sum(), 0))
+        # rows past the held groups belong to no group: ragged_dot
+        # leaves them unspecified, so each product's rows are masked
+        h = (jax.nn.silu(jax.lax.ragged_dot(xs, p["wg"], sizes))
+             * jax.lax.ragged_dot(xs, p["wi"], sizes))
+        h = jnp.where(is_held, h, 0)
+        ys = jnp.where(is_held, jax.lax.ragged_dot(h, p["wo"], sizes), 0)
+    with jax.named_scope(scopes.MOE_ROUTE):
+        y_slot = _permute(ys, back, order).reshape(n, K, d)
+        y = (y_slot * gate.astype(ys.dtype)[..., None]).sum(1)
+    return y.astype(x.dtype), slots
+
+
+def share_apply(p, x, cfg: ArchConfig, first_expert: int = 0):
+    """The chip's share of an expert layer: x (B, S, d) -> (y, slots),
+    the held experts' routed part (:func:`share_routed`) plus the shared
+    experts, which every chip computes for every token.  No auxiliary
+    loss."""
+    B, S, d = x.shape
+    y, slots = share_routed(p, x.reshape(B * S, d), cfg, first_expert)
+    y = y.reshape(B, S, d)
+    if cfg.moe.num_shared_experts:
+        with jax.named_scope(scopes.DENSE_FFN):
+            y = y + nn.ffn_apply("swiglu", p["shared"], x)
+    return y, slots

@@ -6,6 +6,8 @@ group params are *stacked* along a leading axis so the stack runs under
 ``jax.lax.scan`` — an 80-layer config compiles as one group body.  Each
 sublayer kind (attn / mamba / mlstm / slstm) exposes
 ``init / cache_init / apply`` and the group body dispatches statically.
+Leading dense layers (``first_k_dense``) are a stack of their own,
+``params["dense"]``, scanned before the groups.
 """
 from __future__ import annotations
 
@@ -16,6 +18,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
 from repro.configs.base import ArchConfig
 from repro.models import attention as attn
 from repro.models import modules as nn
@@ -33,6 +36,12 @@ def _has_moe(cfg: ArchConfig, sub_idx: int) -> bool:
         return False
     return sub_idx % cfg.moe.every_n_layers == (cfg.moe.every_n_layers - 1) \
         if cfg.moe.every_n_layers > 1 else True
+
+
+def _leading(cfg: ArchConfig) -> ArchConfig:
+    """The config of a leading dense layer: the arch's mixer and its
+    dense FFN of width ``d_ff``."""
+    return dataclasses.replace(cfg, moe=None, block_pattern=("attn",))
 
 
 def _mixer_fns(cfg: ArchConfig, kind: str):
@@ -100,19 +109,35 @@ def _cache_sublayer(cfg: ArchConfig, sub_idx: int, batch: int, max_len: int,
     raise ValueError(kind)
 
 
+def _no_slots() -> jnp.ndarray:
+    return jnp.zeros((0,), jnp.int32)
+
+
+def _slots_of(cfg: ArchConfig) -> jnp.ndarray:
+    """A layer's zero slot counts: one per held expert and one for the
+    absent experts where the layer holds a share, else none."""
+    m = cfg.moe
+    if m is None or m.experts_held is None:
+        return _no_slots()
+    return jnp.zeros((m.experts_held + 1,), jnp.int32)
+
+
 def _apply_sublayer(p, x, *, cfg: ArchConfig, sub_idx: int, mode: str,
-                    positions, cache_entry, cache_pos, enc_out, window):
+                    positions, cache_entry, cache_pos, enc_out, window,
+                    segments=None):
+    """-> (x, new_cache, aux_loss, expert slots)."""
     kind = cfg.block_pattern[sub_idx]
     _, apply_fn = _mixer_fns(cfg, kind)
     has_cross = "cross" in p
     nested = has_cross and isinstance(cache_entry, dict) \
         and "self" in cache_entry
     self_entry = cache_entry["self"] if nested else cache_entry
-    h = nn.norm_apply(cfg.norm, p["norm1"], x)
+    h = nn.norm_apply(cfg.norm, p["norm1"], x, eps=cfg.norm_eps)
     if kind == "attn":
+        docs = {} if segments is None else {"segments": segments}
         y, new_self = apply_fn(p["mixer"], h, cfg=cfg, mode=mode,
                                positions=positions, cache=self_entry,
-                               cache_pos=cache_pos, window=window)
+                               cache_pos=cache_pos, window=window, **docs)
     else:
         y, new_self = apply_fn(p["mixer"], h, cfg=cfg, mode=mode,
                                state=self_entry)
@@ -121,7 +146,7 @@ def _apply_sublayer(p, x, *, cfg: ArchConfig, sub_idx: int, mode: str,
     x = x + y
     new_cache = new_self
     if has_cross:
-        h = nn.norm_apply(cfg.norm, p["norm_x"], x)
+        h = nn.norm_apply(cfg.norm, p["norm_x"], x, eps=cfg.norm_eps)
         cross_entry = cache_entry["cross"] if nested else None
         y, new_cross = attn.gqa_apply(p["cross"], h, cfg=cfg, mode=mode,
                                       positions=positions,
@@ -133,18 +158,23 @@ def _apply_sublayer(p, x, *, cfg: ArchConfig, sub_idx: int, mode: str,
                          "cross": new_cross if new_cross is not None
                          else cross_entry}
     aux = jnp.zeros((), jnp.float32)
+    slots = _no_slots()
     if "moe" in p:
-        h = nn.norm_apply(cfg.norm, p["norm2"], x)
-        y, aux = moe_lib.moe_apply(p["moe"], h, cfg)
+        h = nn.norm_apply(cfg.norm, p["norm2"], x, eps=cfg.norm_eps)
+        if cfg.moe.experts_held is None:
+            y, aux = moe_lib.moe_apply(p["moe"], h, cfg)
+        else:
+            y, slots = moe_lib.share_apply(p["moe"], h, cfg)
         x = x + y
     elif "ffn" in p:
-        h = nn.norm_apply(cfg.norm, p["norm2"], x)
-        y = nn.ffn_apply(cfg.ffn, p["ffn"], h)
+        h = nn.norm_apply(cfg.norm, p["norm2"], x, eps=cfg.norm_eps)
+        with jax.named_scope(scopes.DENSE_FFN):
+            y = nn.ffn_apply(cfg.ffn, p["ffn"], h)
         y = constrain(y, batch_axes(), None, None)
         x = x + y
     if mode == "decode" and new_cache is None:
         new_cache = cache_entry
-    return x, new_cache, aux
+    return x, new_cache, aux, slots
 
 
 # ---------------------------------------------------------------- model --
@@ -177,6 +207,11 @@ class Model:
 
         params["groups"] = jax.vmap(init_group)(
             jax.random.split(r[3], cfg.num_groups))
+        if cfg.first_k_dense:
+            lead = _leading(cfg)
+            params["dense"] = jax.vmap(
+                lambda k: _init_sublayer(k, lead, 0))(
+                jax.random.split(r[6], cfg.first_k_dense))
 
         if cfg.encoder_layers:
             enc_cfg = dataclasses.replace(cfg, block_pattern=("attn",),
@@ -197,13 +232,22 @@ class Model:
     # ---------------- cache ----------------
     def cache_init(self, batch: int, max_len: int,
                    quantized: bool = False) -> Dict[str, Any]:
+        """The groups' stacked caches; with leading dense layers,
+        ``{"dense": their stacked caches, "groups": ...}``."""
         cfg = self.cfg
         one_group = {f"sub{i}": _cache_sublayer(cfg, i, batch, max_len,
                                                 quantized=quantized)
                      for i in range(cfg.group_size)}
-        return jax.tree.map(
+        groups = jax.tree.map(
             lambda a: jnp.zeros((cfg.num_groups, *a.shape), a.dtype),
             one_group)
+        if not cfg.first_k_dense:
+            return groups
+        one = {"sub0": _cache_sublayer(_leading(cfg), 0, batch, max_len,
+                                       quantized=quantized)}
+        dense = jax.tree.map(
+            lambda a: jnp.zeros((cfg.first_k_dense, *a.shape), a.dtype), one)
+        return {"dense": dense, "groups": groups}
 
     # ---------------- encoder ----------------
     def _encode(self, params, enc_embeds):
@@ -218,25 +262,32 @@ class Model:
         positions = jnp.arange(S)[None]
 
         def body(x, lparams):
-            h = nn.norm_apply(cfg.norm, lparams["norm1"], x)
+            h = nn.norm_apply(cfg.norm, lparams["norm1"], x, eps=cfg.norm_eps)
             y, _ = attn.gqa_apply(lparams["mixer"], h, cfg=enc_cfg,
                                   mode="encode", positions=positions)
             x = x + y
-            h = nn.norm_apply(cfg.norm, lparams["norm2"], x)
+            h = nn.norm_apply(cfg.norm, lparams["norm2"], x, eps=cfg.norm_eps)
             x = x + nn.ffn_apply(cfg.ffn, lparams["ffn"], h)
             return x, None
 
         x, _ = jax.lax.scan(body, x, params["encoder"])
-        return nn.norm_apply(cfg.norm, params["enc_norm"], x)
+        return nn.norm_apply(cfg.norm, params["enc_norm"], x,
+                             eps=cfg.norm_eps)
 
     # ---------------- main apply ----------------
     def apply(self, params, batch: Dict[str, Any], *, mode: str,
-              cache=None, cache_pos=None, window: Optional[int] = None):
-        """Returns (logits, new_cache, aux_loss).
+              cache=None, cache_pos=None, window: Optional[int] = None,
+              with_slots: bool = False):
+        """Returns (logits, new_cache, aux_loss), and with ``with_slots``
+        the expert-slot counts (num_groups, ...) of each group's share
+        layers last.
 
-        batch keys: tokens (B,S) int32; optional encoder_embeds
-        (B,enc_S,d); optional image_embeds (B,V,d); decode also needs
-        enc_out precomputed in batch (enc-dec serving).
+        batch keys: tokens (B,S) int32; optional segments (B,S) int32,
+        the document id of each position of packed sequences (positions
+        restart at each document, and no query attends outside its
+        document); optional encoder_embeds (B,enc_S,d); optional
+        image_embeds (B,V,d); decode also needs enc_out precomputed in
+        batch (enc-dec serving).
         """
         cfg = self.cfg
         tokens = batch["tokens"]
@@ -250,8 +301,16 @@ class Model:
             x = jnp.concatenate([img, x], axis=1)
         Sx = x.shape[1]
 
+        segments = batch.get("segments")
         if mode == "decode":
             positions = jnp.broadcast_to(cache_pos, (B,))[:, None]
+        elif segments is not None:
+            idx = jnp.arange(Sx)
+            start = jnp.concatenate(
+                [jnp.ones_like(segments[:, :1], bool),
+                 segments[:, 1:] != segments[:, :-1]], axis=1)
+            positions = idx - jax.lax.cummax(jnp.where(start, idx, 0),
+                                             axis=1)
         else:
             positions = jnp.arange(Sx)[None]
         if cfg.pos_emb == "learned":
@@ -269,46 +328,77 @@ class Model:
             else:
                 enc_out = self._encode(params, batch["encoder_embeds"])
 
-        gcfg = cfg
-
-        def group_body(carry, xs):
-            x, aux = carry
-            gparams, gcache = xs
-            new_cache = {}
-            for i in range(gcfg.group_size):
-                entry = None if gcache is None else gcache[f"sub{i}"]
-                x, nc, a = _apply_sublayer(
-                    gparams[f"sub{i}"], x, cfg=gcfg, sub_idx=i, mode=mode,
-                    positions=positions, cache_entry=entry,
-                    cache_pos=cache_pos, enc_out=enc_out, window=window)
-                x = constrain(x, batch_axes(), None, None)
-                new_cache[f"sub{i}"] = nc
-                aux = aux + a
-            return (x, aux), new_cache
-
-        if mode == "train":
-            group_body = jax.checkpoint(group_body)
-
-        aux0 = jnp.zeros((), jnp.float32)
-        if cache is None:
-            (x, aux), _ = jax.lax.scan(
-                lambda c, gp: (group_body(c, (gp, None))[0], None),
-                (x, aux0), params["groups"])
-            new_cache = None
-        else:
-            (x, aux), new_cache = jax.lax.scan(
-                group_body, (x, aux0), (params["groups"], cache))
-
-        x = nn.norm_apply(cfg.norm, params["final_norm"], x)
-        if cfg.tie_embeddings:
-            logits = nn.embedding_attend(params["embed"], x)
-        else:
-            logits = nn.dense_apply(
-                nn.tp_weight(params["lm_head"], None, "model"), x)
+        x, new_cache, aux, slots = self._layers(
+            params, x, mode=mode, positions=positions, cache=cache,
+            cache_pos=cache_pos, enc_out=enc_out, window=window,
+            segments=segments)
+        x = nn.norm_apply(cfg.norm, params["final_norm"], x,
+                          eps=cfg.norm_eps)
+        with jax.named_scope(scopes.LM_HEAD):
+            if cfg.tie_embeddings:
+                logits = nn.embedding_attend(params["embed"], x)
+            else:
+                logits = nn.dense_apply(
+                    nn.tp_weight(params["lm_head"], None, "model"), x)
         if n_prefix:
             logits = logits[:, n_prefix:]
         logits = constrain(logits, batch_axes(), None, "model")
-        return logits.astype(jnp.float32), new_cache, aux
+        out = (logits.astype(jnp.float32), new_cache, aux)
+        return out + (slots,) if with_slots else out
+
+    # ---------------- layers ----------------
+    def _layers(self, params, x, *, mode: str, positions, cache=None,
+                cache_pos=None, enc_out=None, window=None, segments=None):
+        """The leading dense layers, then the groups, each stack under
+        ``lax.scan`` (rematerialised per layer in training) ->
+        ``(x, new_cache, aux_loss, slots)``; ``slots`` (num_groups, ...)
+        are the expert-slot counts of each group's share layers."""
+        cfg = self.cfg
+
+        def stack(lcfg, x, aux, stacked, caches):
+            def body(carry, xs):
+                x, aux = carry
+                gparams, gcache = xs
+                new_cache = {}
+                slots = _slots_of(lcfg)
+                for i in range(lcfg.group_size):
+                    entry = None if gcache is None else gcache[f"sub{i}"]
+                    x, nc, a, s = _apply_sublayer(
+                        gparams[f"sub{i}"], x, cfg=lcfg, sub_idx=i,
+                        mode=mode, positions=positions, cache_entry=entry,
+                        cache_pos=cache_pos, enc_out=enc_out, window=window,
+                        segments=segments)
+                    x = constrain(x, batch_axes(), None, None)
+                    new_cache[f"sub{i}"] = nc
+                    aux = aux + a
+                    if s.shape[0]:
+                        slots = slots + s
+                return (x, aux), (new_cache, slots)
+
+            if mode == "train":
+                body = jax.checkpoint(body)
+            if caches is None:
+                (x, aux), (_, slots) = jax.lax.scan(
+                    lambda c, gp: body(c, (gp, None)), (x, aux), stacked)
+                return x, aux, None, slots
+            (x, aux), (new_cache, slots) = jax.lax.scan(
+                body, (x, aux), (stacked, caches))
+            return x, aux, new_cache, slots
+
+        aux = jnp.zeros((), jnp.float32)
+        dense_cache, group_cache = cache, cache
+        if cfg.first_k_dense:
+            if cache is not None:
+                dense_cache, group_cache = cache["dense"], cache["groups"]
+            x, aux, dense_cache, _ = stack(_leading(cfg), x, aux,
+                                           {"sub0": params["dense"]},
+                                           dense_cache)
+        x, aux, group_cache, slots = stack(cfg, x, aux, params["groups"],
+                                           group_cache)
+        new_cache = group_cache
+        if cfg.first_k_dense and cache is not None:
+            new_cache = {"dense": dense_cache, "groups": group_cache}
+        return x, new_cache, aux, slots
 
 
 def build_model(cfg: ArchConfig, max_seq: int = 4096) -> Model:
