@@ -267,5 +267,6 @@ class ExperimentResult:
             "sgd_steps": meter.sgd_step_summary(),
             "bwo_rows": meter.bwo_row_summary(),
             "expert_slots": meter.expert_slot_summary(),
+            "attention_paths": dict(meter.attention_paths),
             f"normalized_cost_vs_fedavg{fedavg_rounds}": cost,
         }

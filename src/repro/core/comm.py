@@ -102,8 +102,11 @@ class CommMeter:
     rows drawn against a full draw's, filled by ``record_bwo_rows``, and
     ``expert_slots`` the per-round token-slots that local SGD routed to
     each held expert of each expert layer, filled by
-    ``record_expert_slots`` (all kept out of ``summary()`` so byte
-    ledgers of protocol-identical runs stay comparable).
+    ``record_expert_slots``, and ``attention_paths`` the full-sequence
+    attention calls traced into the round programs, by path (``flash``,
+    ``blockwise``), filled by ``record_attention_paths`` (all kept out
+    of ``summary()`` so byte ledgers of protocol-identical runs stay
+    comparable).
     """
     model_bytes: int
     n_clients: int
@@ -117,6 +120,8 @@ class CommMeter:
     bwo_rows: List[Tuple[int, int, int, int]] = dataclasses.field(
         default_factory=list)
     expert_slots: List[np.ndarray] = dataclasses.field(default_factory=list)
+    attention_paths: Dict[str, int] = dataclasses.field(
+        default_factory=dict)
 
     def record_fedavg_round(self, n_participants: int):
         self.uplink.append(n_participants * self.model_bytes)
@@ -203,6 +208,15 @@ class CommMeter:
                 "held": [int(h) for h in held], "absent": absent,
                 "held_share": float(held.sum()) / n if n else 0.0,
                 "max_over_mean": float(held.max()) / mean if mean else 0.0}
+
+    def record_attention_paths(self, paths):
+        """Add the attention calls of newly traced programs, counted by
+        path at trace time (``repro.models.attention.
+        count_attention_paths``): a compiled program adds nothing when
+        it runs again."""
+        for path, n in paths.items():
+            self.attention_paths[path] = (self.attention_paths.get(path, 0)
+                                          + int(n))
 
     def record_rounds(self, strategy, n_rounds: int,
                       n_participants: int = None,

@@ -30,6 +30,7 @@ host pays one dispatch + one log sync per R rounds (DESIGN.md §6).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 import time
@@ -50,6 +51,7 @@ from repro.core.knobs import (DEFAULT_PIPELINE_DEPTH,
                               parse_rounds_per_dispatch, validate_engine)
 from repro.metaheuristics import REGISTRY, Metaheuristic
 from repro.metaheuristics.base import init_rows
+from repro.models.attention import count_attention_paths
 
 
 @dataclasses.dataclass(frozen=True)
@@ -207,9 +209,18 @@ class Server:
                 return task.loss_fn(params, batch)
         self._eval = jax.jit(eval_loss)
 
+    @contextlib.contextmanager
+    def _counting_paths(self):
+        """Record on the meter the attention paths of the programs that
+        the block traces (its first call of each)."""
+        with count_attention_paths() as paths:
+            yield
+        self.meter.record_attention_paths(paths)
+
     # ------------------------------------------------------------ round --
     def run_round(self) -> dict:
-        with jax.profiler.TraceAnnotation("Server.run_round"):
+        with jax.profiler.TraceAnnotation("Server.run_round"), \
+                self._counting_paths():
             keys = jax.random.split(self.rng, self.n_clients + 2)
             self.rng, sel_key, ckeys = keys[0], keys[1], keys[2:]
             self.rounds_completed += 1
@@ -296,7 +307,8 @@ class Server:
                 "sequential fallback has no async block dispatch to "
                 "pipeline — use run_block, which degrades gracefully")
         n_rounds = int(n_rounds or self.rounds_per_dispatch)
-        with jax.profiler.TraceAnnotation("Server.dispatch_block"):
+        with jax.profiler.TraceAnnotation("Server.dispatch_block"), \
+                self._counting_paths():
             t0 = time.perf_counter()
             offset = self.rounds_completed
             params, rng, logs = self._engine.run_block(
@@ -509,7 +521,8 @@ class Server:
 
     # ------------------------------------------------------------- eval --
     def evaluate(self, eval_data) -> Tuple[float, float]:
-        with jax.profiler.TraceAnnotation("Server.evaluate"):
+        with jax.profiler.TraceAnnotation("Server.evaluate"), \
+                self._counting_paths():
             out = self._eval(self.global_params, eval_data)
             # one device_get for both scalars: float(loss), float(acc) on
             # the device arrays would block on the device twice (flcheck's

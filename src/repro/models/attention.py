@@ -2,12 +2,16 @@
 (DeepSeek-V2 latent attention), cross-attention, with decode KV caches.
 
 The full-sequence path is *blockwise* over query chunks so 32k-prefill
-never materializes an (S, S) score matrix.  The blockwise routine is also
-the numerical oracle for the Pallas flash-attention kernel
-(``repro.kernels.flash_attention``).
+never materializes an (S, S) score matrix.  On the TPU, MLA's causal
+train/prefill attention runs the fused flash kernel
+``repro.kernels.flash_attention.splash`` instead (:func:`attention_path`
+decides), which keeps each block's scores on chip; the blockwise routine
+is that kernel's numerical oracle and serves every other call.
 """
 from __future__ import annotations
 
+import collections
+import contextlib
 import functools
 import math
 from typing import Optional
@@ -17,9 +21,49 @@ import jax.numpy as jnp
 
 from repro import scopes
 from repro.configs.base import ArchConfig
+from repro.kernels.flash_attention import splash
 from repro.models import modules as nn
 
 NEG_INF = -1e30
+
+FLASH, BLOCKWISE = "flash", "blockwise"
+# precisions at which a float32 matmul takes one bfloat16 pass, the
+# arithmetic of the flash kernel
+_ONE_PASS = (None, "default", "bfloat16", "BF16_BF16_F32")
+_path_counts: list = []          # the Counters of open count_attention_paths
+
+
+def attention_path(backend: str, mode: str, seq_len: int,
+                   precision: Optional[str] = None) -> str:
+    """``FLASH`` where the fused kernel serves a causal self-attention
+    call: on the TPU, in train or prefill mode, at a sequence length
+    that is a multiple of the kernel's block, at a matmul precision
+    (``jax_default_matmul_precision``) of one bfloat16 pass; else
+    ``BLOCKWISE``."""
+    if (backend == "tpu" and mode in ("train", "prefill")
+            and seq_len % splash.BLOCK == 0 and precision in _ONE_PASS):
+        return FLASH
+    return BLOCKWISE
+
+
+@contextlib.contextmanager
+def count_attention_paths():
+    """Yields a ``Counter`` of the paths (``FLASH`` / ``BLOCKWISE``)
+    taken by the full-sequence self-attention calls (train, prefill,
+    encode) traced inside the block; GQA's always read ``BLOCKWISE``.  A
+    count of traced calls: a program that is already compiled adds
+    nothing, and a layer scan traces its body once."""
+    counts = collections.Counter()
+    _path_counts.append(counts)
+    try:
+        yield counts
+    finally:
+        _path_counts.remove(counts)
+
+
+def _count_path(path: str):
+    for counts in _path_counts:
+        counts[path] += 1
 
 
 # ----------------------------------------------------------------- core --
@@ -237,6 +281,7 @@ def gqa_apply(p, x, *, cfg: ArchConfig, mode: str, positions,
                 new_cache = {"ck": k.astype(cache["ck"].dtype),
                              "cv": v.astype(cache["cv"].dtype)}
     else:  # train / prefill: full causal; encoder: bidirectional
+        _count_path(BLOCKWISE)
         out = blockwise_attention(q, k, v, causal=(mode != "encode"),
                                   window=window,
                                   q_block=min(1024, max(8, S)),
@@ -443,10 +488,19 @@ def _mla(p, x, *, cfg, mode, positions, cache, cache_pos, absorb, segments):
             [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
                                       (*k_rope.shape[:2], H, m.qk_rope_head_dim))], -1)
         q_full = jnp.concatenate([q_nope, q_rope], -1)
-        out = blockwise_attention(q_full, k_full, v_full, causal=True,
-                                  window=None, q_block=min(1024, max(8, S)),
-                                  scale=mla_softmax_scale(cfg),
-                                  segments=segments)
+        path = attention_path(jax.default_backend(), mode, S,
+                              jax.config.jax_default_matmul_precision)
+        _count_path(path)
+        if path == FLASH:
+            out = splash.causal_flash_attention(
+                q_full, k_full, v_full, segments,
+                scale=mla_softmax_scale(cfg))
+        else:
+            out = blockwise_attention(q_full, k_full, v_full, causal=True,
+                                      window=None,
+                                      q_block=min(1024, max(8, S)),
+                                      scale=mla_softmax_scale(cfg),
+                                      segments=segments)
         if mode == "prefill" and cache is not None:
             new_cache = {
                 "c_kv": jax.lax.dynamic_update_slice(

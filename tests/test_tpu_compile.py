@@ -2,7 +2,8 @@
 
 The TPU compiler refuses what interpret mode and the CPU accept (block
 shapes off the (8, 128) tiling, casts Mosaic lacks), so the main path's
-kernel is compiled here at the paper CNN's width.  The topology is
+kernels are compiled here: BWO evolution at the paper CNN's width, and
+latent attention's flash kernel at the token cell's.  The topology is
 described inside a fixture: only the worker that runs these tests loads
 the TPU library.
 """
@@ -14,6 +15,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.client import ClientHP, make_client_update
 from repro.data.loader import batch_dataset
 from repro.kernels.bwo_evolve.ops import bwo_evolve
+from repro.kernels.flash_attention import splash
 from repro.metaheuristics import bwo
 
 from conftest import make_toy_data, make_toy_task
@@ -46,6 +48,23 @@ def test_bwo_evolve_compiles_for_v5e(one_chip, pop):
             shape((pop, PAPER_CNN_PARAMS), jnp.float32),
             shape((pop,), jnp.float32), shape((2,), jnp.uint32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_attention_compiles_for_v5e(one_chip):
+    """DeepSeek-V2-Lite's latent attention in the token cell: 16 heads,
+    query/key width 192, value width 128, 4,096 packed tokens; the
+    forward and the gradient of q, k and v."""
+    def shape(s, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    def loss(q, k, v, segments):
+        return splash.causal_flash_attention(
+            q, k, v, segments, scale=0.1, interpret=False).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        shape((1, 4096, 16, 192)), shape((1, 4096, 16, 192)),
+        shape((1, 4096, 16, 128)), shape((1, 4096), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2
 
 
 def test_client_loops_roll_off_the_cpu():
