@@ -5,15 +5,12 @@ Fig. 4  accuracy comparison      -> bench_accuracy
 Fig. 5  loss comparison          -> bench_loss
 Fig. 6  communication cost       -> bench_comm_cost (Eqs. 1-4)
 Fig. 7  execution time           -> bench_exec_time
-plus    round-engine comparison  -> bench_round_engine (sequential vs
-                                    batched one-dispatch rounds)
-plus    block pipeline           -> bench_pipelined_blocks (serial vs
-                                    double-buffered fused-block driver)
 
-Scale knobs (1-core CPU container): REPRO_BENCH_TRAIN, REPRO_BENCH_ROUNDS,
-REPRO_BENCH_CLIENTS, REPRO_BENCH_EPOCHS, REPRO_BENCH_ENGINE
-(auto|batched|sequential).  The protocol/accounting is exact regardless
-of scale; only absolute accuracies shift.  Cached results in
+Scale knobs: REPRO_BENCH_TRAIN, REPRO_BENCH_TEST, REPRO_BENCH_ROUNDS,
+REPRO_BENCH_CLIENTS, REPRO_BENCH_EPOCHS.  The protocol/accounting is
+exact regardless of scale; only absolute accuracies shift.  Times are
+those of whatever backend runs the script, not chip measurements (the
+chip's are ``benchmarks/fedbench``'s).  Cached results in
 results/bench/fl_runs.json are invalidated automatically when these
 knobs change.
 """
@@ -29,10 +26,6 @@ import jax
 from repro.core import FLConfig, build_experiment, normalized_cost
 from repro.data import (client_batches, cnn_task, make_cifar_like,
                         partition_iid)
-
-# engine selection for the figure runs: "auto" routes rounds through the
-# batched one-dispatch engine (repro.core.engine) when client data stacks
-ENGINE = os.environ.get("REPRO_BENCH_ENGINE", "auto")
 
 # defaults sized for the 1-core CPU container (~20 min total); scale up
 # with the env knobs for a fuller reproduction
@@ -96,7 +89,7 @@ def _run_all() -> Dict[str, dict]:
             cfg = FLConfig(strategy=name, client_ratio=c,
                            n_clients=N_CLIENTS, batch_size=BATCH,
                            local_epochs=LOCAL_EPOCHS, mh_pop=6,
-                           mh_generations=3, engine=ENGINE,
+                           mh_generations=3,
                            max_rounds=ROUNDS, patience=PATIENCE, tau=TAU)
             t0 = time.perf_counter()
             exp = build_experiment(cfg, task=task, client_data=clients,
@@ -117,7 +110,6 @@ def _run_all() -> Dict[str, dict]:
                 "wall_s": wall,
                 "compile_round_s": logs[0].round_time_s,
                 "steady_round_s": sum(steady) / len(steady),
-                "engine": server.engine,
                 "model_bytes": server.meter.model_bytes,
                 "uplink_bytes": server.meter.total_uplink,
             }
@@ -169,8 +161,8 @@ def bench_noniid_ablation() -> List[tuple]:
     """Beyond-paper ablation: FedBWO under IID vs Dirichlet(0.5) label
     skew (the paper only evaluates IID).  Winner-takes-all aggregation
     is expected to degrade under skew — one client's model can't cover
-    absent classes.  The Dirichlet run exercises the batched engine's
-    pad+mask path (DESIGN.md §5)."""
+    absent classes.  The Dirichlet run exercises the engine's pad+mask
+    path (DESIGN.md §5)."""
     out = []
     for label, part in [("iid", "iid"), ("dirichlet0.5", "dirichlet")]:
         cfg = FLConfig(strategy="fedbwo", partition=part, n_clients=5,
@@ -194,229 +186,3 @@ def bench_exec_time() -> List[tuple]:
     return [(f"fig7_exec_time/{k}", w * 1e6, round(w / mx, 4))
             for k, w in walls.items()]
 
-
-def _time_engines(task, clients, eval_data, cfg_kw, label,
-                  steady_rounds) -> List[tuple]:
-    rows, steady = [], {}
-    for engine in ("sequential", "batched"):
-        cfg = FLConfig(strategy="fedbwo", engine=engine, **cfg_kw)
-        server = build_experiment(cfg, task=task, client_data=clients,
-                                  eval_data=eval_data).server
-        t0 = time.perf_counter()
-        server.run_round()
-        jax.block_until_ready(server.global_params)
-        first = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        for _ in range(steady_rounds):
-            server.run_round()
-        jax.block_until_ready(server.global_params)
-        steady[engine] = (time.perf_counter() - t0) / steady_rounds
-        rows.append((f"round_engine/{label}_{engine}_first", first * 1e6,
-                     f"clients={N_CLIENTS}"))
-        rows.append((f"round_engine/{label}_{engine}_steady",
-                     steady[engine] * 1e6,
-                     f"clients={N_CLIENTS},rounds={steady_rounds}"))
-        print(f"  [engine:{label}/{engine}] first={first:.1f}s "
-              f"steady={steady[engine]:.2f}s/round", flush=True)
-    rows.append((f"round_engine/{label}_steady_speedup",
-                 steady["batched"] * 1e6,
-                 round(steady["sequential"] / steady["batched"], 4)))
-    return rows
-
-
-def bench_fused_rounds() -> List[tuple]:
-    """Multi-round fusion sweep (DESIGN.md §6): rounds_per_dispatch in
-    {1, 5, 10} on FedBWO x the dense ``mlp_task``, batched engine.
-
-    R=1 is the PR-7 baseline — one round dispatch plus one host-side
-    eval dispatch per round.  R>1 dispatches one fused XLA program per
-    R-round block with eval folded in at cadence 1, paying one
-    device->host log sync per block.  Reports the compile (first
-    dispatch) / steady-state split; the derived column of the
-    ``*_steady`` rows is the per-round speedup vs R=1.  Full numbers
-    land in ``BENCH_fused_rounds.json``.
-    """
-    from repro.data import mlp_task
-
-    sweep = (1, 5, 10)
-    steady_target = int(os.environ.get("REPRO_BENCH_FUSED_ROUNDS", 10))
-    rng = jax.random.PRNGKey(0)
-    train, test = make_cifar_like(rng, N_TRAIN, 16)
-    clients = client_batches(
-        partition_iid(jax.random.PRNGKey(1), train, N_CLIENTS), BATCH)
-    task = mlp_task()
-    results, rows = {}, []
-    for r in sweep:
-        cfg = FLConfig(strategy="fedbwo", task="mlp", engine="batched",
-                       n_clients=N_CLIENTS, batch_size=BATCH,
-                       local_epochs=LOCAL_EPOCHS, mh_pop=4,
-                       mh_generations=2, rounds_per_dispatch=r)
-        server = build_experiment(cfg, task=task, client_data=clients,
-                                  eval_data=test).server
-
-        def block():
-            if r == 1:
-                # unfused baseline: round dispatch + host eval round-trip
-                server.run_round()
-                jax.block_until_ready(server.global_params)
-                server.evaluate(test)
-            else:
-                server.run_block(r, eval_data=test, eval_every=1)
-                jax.block_until_ready(server.global_params)
-
-        t0 = time.perf_counter()
-        block()                                   # pays XLA compilation
-        first = time.perf_counter() - t0
-        n_blocks = max(1, steady_target // r)
-        t0 = time.perf_counter()
-        for _ in range(n_blocks):
-            block()
-        steady = (time.perf_counter() - t0) / (n_blocks * r)
-        results[str(r)] = {"rounds_per_dispatch": r, "compile_s": first,
-                           "steady_round_s": steady,
-                           "steady_rounds_measured": n_blocks * r}
-        print(f"  [fused:R={r}] first={first:.2f}s "
-              f"steady={steady:.3f}s/round", flush=True)
-    base = results["1"]["steady_round_s"]
-    for r in sweep:
-        entry = results[str(r)]
-        entry["speedup_vs_r1"] = round(base / entry["steady_round_s"], 4)
-        rows.append((f"fused_rounds/R{r}_first",
-                     entry["compile_s"] * 1e6, f"clients={N_CLIENTS}"))
-        rows.append((f"fused_rounds/R{r}_steady",
-                     entry["steady_round_s"] * 1e6,
-                     entry["speedup_vs_r1"]))
-    payload = {"config": _bench_config(), "backend": jax.default_backend(),
-               "strategy": "fedbwo", "task": "mlp",
-               "eval_every": 1, "sweep": results}
-    with open("BENCH_fused_rounds.json", "w") as f:
-        json.dump(payload, f, indent=1)
-    return rows
-
-
-def bench_pipelined_blocks() -> List[tuple]:
-    """Double-buffered block pipeline (DESIGN.md §7): serial run_block
-    loop vs ``run_pipelined`` on FedBWO x ``mlp_task``, batched engine,
-    rounds_per_dispatch=5.
-
-    Both drivers execute identical device programs (the parity tests
-    prove bit-exactness); the pipeline only moves host-side block
-    overhead — dispatch, the log `device_get`, info/meter processing —
-    off the critical path by keeping one block in flight.  On a 1-core
-    CPU container "device" compute shares the core with the host, so
-    the expected result is parity within noise (the hideable host work
-    is a few ms per ~seconds-long block); the overlap mechanism itself
-    is visible in the BlockTiming ledger as the pipelined driver's
-    sync_fraction dropping well below the serial driver's ~1.0.  To
-    resolve a few-percent effect under container timing noise the
-    drivers run interleaved and report best-of-``REPRO_BENCH_PIPE_TRIALS``
-    (default 4).  Full numbers land in ``BENCH_pipelined_blocks.json``.
-    """
-    from repro.data import mlp_task
-
-    R = 5
-    n_blocks = max(2, int(os.environ.get("REPRO_BENCH_PIPE_BLOCKS", 6)))
-    trials = max(1, int(os.environ.get("REPRO_BENCH_PIPE_TRIALS", 4)))
-    # lighter than the figure runs: per-block host overhead is fixed,
-    # so a smaller device program makes the effect proportionally larger
-    n_train = min(N_TRAIN, 240)
-    rng = jax.random.PRNGKey(0)
-    train, test = make_cifar_like(rng, n_train, 16)
-    clients = client_batches(
-        partition_iid(jax.random.PRNGKey(1), train, N_CLIENTS), BATCH)
-    task = mlp_task()
-
-    servers, results, rows = {}, {}, []
-    for mode in ("serial", "pipelined"):
-        cfg = FLConfig(strategy="fedbwo", task="mlp", engine="batched",
-                       n_clients=N_CLIENTS, batch_size=BATCH,
-                       local_epochs=LOCAL_EPOCHS, mh_pop=2,
-                       mh_generations=1, rounds_per_dispatch=R,
-                       pipeline_blocks=(mode == "pipelined"))
-        server = build_experiment(cfg, task=task, client_data=clients,
-                                  eval_data=test).server
-        # pay XLA compilation outside the timed region
-        t0 = time.perf_counter()
-        server.run_block(R, eval_data=test, eval_every=1)
-        jax.block_until_ready(server.global_params)
-        servers[mode] = server
-        results[mode] = {"compile_s": time.perf_counter() - t0,
-                         "trial_round_s": [], "blocks_per_trial": n_blocks,
-                         "rounds_per_dispatch": R}
-
-    for trial in range(trials):
-        order = ("serial", "pipelined") if trial % 2 == 0 \
-            else ("pipelined", "serial")
-        for mode in order:
-            server = servers[mode]
-            t0 = time.perf_counter()
-            if mode == "pipelined":
-                server.run_pipelined(n_blocks * R, eval_data=test,
-                                     eval_every=1)
-            else:
-                for _ in range(n_blocks):
-                    server.run_block(R, eval_data=test, eval_every=1)
-            jax.block_until_ready(server.global_params)
-            results[mode]["trial_round_s"].append(
-                (time.perf_counter() - t0) / (n_blocks * R))
-
-    for mode in ("serial", "pipelined"):
-        entry = results[mode]
-        entry["steady_round_s"] = min(entry["trial_round_s"])
-        # ledger spans compile + all trials; sync_fraction is the story
-        entry["timing"] = servers[mode].meter.timing_summary()
-        print(f"  [pipe:{mode}] first={entry['compile_s']:.2f}s "
-              f"best={entry['steady_round_s']:.3f}s/round "
-              f"(trials {[round(t, 3) for t in entry['trial_round_s']]}) "
-              f"sync_fraction={entry['timing']['sync_fraction']:.2f}",
-              flush=True)
-    speedup = (results["serial"]["steady_round_s"]
-               / results["pipelined"]["steady_round_s"])
-    results["pipelined"]["speedup_vs_serial"] = round(speedup, 4)
-    for mode in ("serial", "pipelined"):
-        rows.append((f"pipelined_blocks/{mode}_steady",
-                     results[mode]["steady_round_s"] * 1e6,
-                     results[mode]["timing"]["sync_fraction"]))
-    rows.append(("pipelined_blocks/speedup",
-                 results["pipelined"]["steady_round_s"] * 1e6,
-                 round(speedup, 4)))
-    payload = {"config": dict(_bench_config(), train=n_train),
-               "backend": jax.default_backend(),
-               "strategy": "fedbwo", "task": "mlp",
-               "rounds_per_dispatch": R, "eval_every": 1,
-               "trials": trials, "results": results}
-    with open("BENCH_pipelined_blocks.json", "w") as f:
-        json.dump(payload, f, indent=1)
-    return rows
-
-
-def bench_round_engine() -> List[tuple]:
-    """Tentpole benchmark: sequential per-client jit loop vs the batched
-    one-dispatch-per-round engine (repro.core.engine).
-
-    Default workload is FedBWO on the dense ``mlp_task`` (the original
-    FedAvg paper's 2NN on the same CIFAR-like images) — the regime the
-    batched engine targets, where it streams all clients through one
-    ``lax.scan`` dispatch.  The paper CNN is opt-in via
-    REPRO_BENCH_ENGINE_CNN=1: on XLA:CPU conv tasks run faster as
-    per-client dispatches under every batched traversal (DESIGN.md §4
-    records the measurements), and engine="batched" forces the
-    comparison anyway at real wall-clock cost.
-
-    Derived column of the ``*_steady_speedup`` rows is
-    sequential_steady / batched_steady (>1 means batched wins)."""
-    from repro.data import mlp_task
-
-    steady_rounds = int(os.environ.get("REPRO_BENCH_ENGINE_ROUNDS", 3))
-    rng = jax.random.PRNGKey(0)
-    train, test = make_cifar_like(rng, N_TRAIN, 16)
-    clients = client_batches(
-        partition_iid(jax.random.PRNGKey(1), train, N_CLIENTS), BATCH)
-    cfg_kw = dict(n_clients=N_CLIENTS, batch_size=BATCH,
-                  local_epochs=LOCAL_EPOCHS, mh_pop=4, mh_generations=2)
-    rows = _time_engines(mlp_task(), clients, test, cfg_kw, "fedbwo_mlp",
-                         steady_rounds)
-    if os.environ.get("REPRO_BENCH_ENGINE_CNN"):
-        rows += _time_engines(cnn_task(), clients, test, cfg_kw,
-                              "fedbwo_cnn", steady_rounds)
-    return rows

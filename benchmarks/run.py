@@ -4,9 +4,6 @@
 #   fig5_loss/*       — paper Fig. 5 (loss per strategy)
 #   fig6_comm_cost/*  — paper Fig. 6 (normalized communication cost)
 #   fig7_exec_time/*  — paper Fig. 7 (normalized execution time)
-#   round_engine/*    — sequential vs batched one-dispatch round engine
-#   fused_rounds/*    — rounds_per_dispatch sweep (one dispatch per R rounds)
-#   pipelined_blocks/* — double-buffered block pipeline vs serial driver
 #   kernel/*          — Pallas kernel micro-benchmarks
 import sys
 import traceback
@@ -14,19 +11,15 @@ import traceback
 
 def main() -> None:
     from benchmarks.fl_bench import (bench_accuracy, bench_comm_cost,
-                                     bench_exec_time, bench_fused_rounds,
-                                     bench_loss, bench_noniid_ablation,
-                                     bench_pipelined_blocks,
-                                     bench_round_engine)
+                                     bench_exec_time, bench_loss,
+                                     bench_noniid_ablation)
     from benchmarks.kernel_bench import bench_kernels
     from repro.launch.compile_cache import enable_compile_cache
 
     enable_compile_cache()
 
     benches = [bench_kernels, bench_accuracy, bench_loss, bench_comm_cost,
-               bench_exec_time, bench_noniid_ablation,
-               bench_round_engine, bench_fused_rounds,
-               bench_pipelined_blocks]
+               bench_exec_time, bench_noniid_ablation]
     print("name,us_per_call,derived")
     failures = 0
     for bench in benches:

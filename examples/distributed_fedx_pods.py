@@ -15,7 +15,8 @@ import jax.numpy as jnp
 jax.config.update("jax_num_cpu_devices", 8)
 
 from repro.core.client import ClientHP, Task
-from repro.core.distributed import make_fedavg_round, make_fedx_round
+from repro.core.engine import (make_sharded_fedavg_round,
+                               make_sharded_fedx_round)
 from repro.launch.mesh import make_host_mesh
 from repro.metaheuristics import bwo
 
@@ -49,8 +50,9 @@ keys = jax.vmap(jax.random.key_data)(jax.random.split(rng, N))
 
 print(f"mesh: {mesh.shape} — each device holds {N // n_devices} "
       f"federation clients")
-for label, rnd in [("FedBWO", make_fedx_round(task, hp, bwo(), mesh)),
-                   ("FedAvg", make_fedavg_round(task, hp, mesh))]:
+for label, rnd in [("FedBWO", make_sharded_fedx_round(task, hp, bwo(),
+                                                     mesh)),
+                   ("FedAvg", make_sharded_fedavg_round(task, hp, mesh))]:
     params = task.init_params(jax.random.PRNGKey(3))
     nbytes = sum(l.size * l.dtype.itemsize for l in jax.tree.leaves(params))
     print(f"\n{label}: model = {nbytes:,} bytes")

@@ -10,24 +10,19 @@ stop conditions — hangs off one ``FLConfig`` (repro.core.api).
 """
 from repro.core import FLConfig, build_experiment
 
-# ``engine="auto"`` compiles the whole round (all clients + server
-# argmin/averaging) into ONE device dispatch whenever the batched
-# traversal is a measured win: on CPU, conv tasks like this CNN stay on
-# the sequential per-client loop (XLA:CPU conv thunks beat every
-# batched mode — DESIGN.md §4) while dense tasks (task="mlp") batch via
-# an O(2 x model) streaming lax.scan.  Ragged (partition="dirichlet")
-# client shards batch too, via pad+mask stacking (DESIGN.md §5).
-# ``vectorize`` picks the client-axis traversal inside the batched
-# engine: "auto" = scan on CPU, vmap on TPU/GPU; "scan:k" chunks the
-# scan; "unroll" trades compile time for straight-line code.
+# Every round compiles to ONE device dispatch: all clients' local
+# training plus the server's argmin/averaging.  Ragged
+# (partition="dirichlet") client shards are stacked too, via pad+mask
+# (DESIGN.md §5).  ``vectorize`` picks the client-axis traversal inside
+# the round program: "auto" = an O(2 x model) streaming lax.scan on
+# CPU, vmap on TPU/GPU; "scan:k" chunks the scan.
 cfg = FLConfig(strategy="fedbwo", task="cnn", n_clients=5,
                n_train=600, n_test=200, batch_size=10,
                local_epochs=1, mh_pop=4, mh_generations=2,
-               engine="auto", vectorize="auto",
+               vectorize="auto",
                max_rounds=3, tau=0.95,
                data_seed=0, partition_seed=1, server_seed=7)
 exp = build_experiment(cfg)
-print(f"round engine = {exp.server.engine}")
 print(f"FedBWO | {cfg.n_clients} clients | model = "
       f"{exp.meter.model_bytes / 1e6:.1f} MB")
 

@@ -3,25 +3,24 @@
 Machine-checks the invariants the engine's performance story depends on
 (DESIGN.md §8): one device->host sync per fused block, honored buffer
 donation, no f64 / weak-type leaks into round programs, no host
-callbacks inside fused scans, the conv-on-CPU engine policy, and
-compile-cache stability under permuted participant sets — over (a) the
-jaxprs of engine-built round programs, (b) the compiled HLO text, and
-(c) the Python AST of ``src/repro``.
+callbacks inside fused scans, and compile-cache stability under
+permuted participant sets — over (a) the jaxprs of the server's block
+programs, (b) the compiled HLO text, and (c) the Python AST of
+``src/repro``.
 
     python -m repro.analysis.cli --task mlp --strategy fedbwo --strict
 
-NOTE: this module is imported *by* ``repro.core.engine`` (the shared
-jaxpr walker drives its conv auto policy), so only the dependency-free
-pieces (walker, report) are imported eagerly; the audit/rules layers —
-which import ``repro.core`` back — load lazily on first attribute
-access.
+NOTE: ``repro.core`` imports this package (for ``AuditError``), so only
+the dependency-free pieces (walker, report) are imported eagerly; the
+audit/rules layers — which import ``repro.core`` — load lazily on first
+attribute access.
 """
 from repro.analysis.report import (AuditError, Finding, Report,
                                    SEVERITIES)
-from repro.analysis.walker import (CALLBACK_PRIMITIVES, CONV_PRIMITIVES,
-                                   EqnSite, count_primitives, iter_avals,
+from repro.analysis.walker import (CALLBACK_PRIMITIVES, EqnSite,
+                                   count_primitives, iter_avals,
                                    iter_sites, jaxpr_has_primitive,
-                                   loss_uses_conv, walk_jaxpr)
+                                   walk_jaxpr)
 
 _LAZY = {
     "RULES": "repro.analysis.rules",
@@ -36,9 +35,9 @@ _LAZY = {
 }
 
 __all__ = ["AuditError", "Finding", "Report", "SEVERITIES",
-           "CALLBACK_PRIMITIVES", "CONV_PRIMITIVES", "EqnSite",
+           "CALLBACK_PRIMITIVES", "EqnSite",
            "count_primitives", "iter_avals", "iter_sites",
-           "jaxpr_has_primitive", "loss_uses_conv", "walk_jaxpr",
+           "jaxpr_has_primitive", "walk_jaxpr",
            *_LAZY]
 
 

@@ -1,10 +1,11 @@
 """flcheck orchestration: build program subjects from a live experiment
 and run the rule catalogue + AST lint over them.
 
-``collect_subjects`` traces (and, by default, compiles) the engine-built
-round programs exactly as the server would dispatch them — the
-single-round program, the fused R-round block, and the jitted eval fn —
-so the audited jaxprs/HLO are the real artifacts, not re-derivations.
+``collect_subjects`` traces (and, by default, compiles) the server's
+block programs exactly as the server would dispatch them — the block of
+one that ``run_round`` dispatches, the R-round block of
+``run_pipelined``, and the jitted eval fn — so the audited jaxprs/HLO
+are the real artifacts, not re-derivations.
 ``audit_experiment`` is the one entry point: the CLI
 (``repro.analysis.cli``), the opt-in build hook
 (``build_experiment(..., audit=...)``), and the end-to-end test all call
@@ -22,18 +23,15 @@ from repro.analysis.pylint_jax import lint_paths
 from repro.analysis.report import AuditError, Finding, Report
 from repro.analysis.rules import run_rules
 from repro.core.engine import _donate_argnums
-from repro.core.knobs import DEFAULT_ROUNDS_PER_DISPATCH
 
 
 @dataclasses.dataclass
 class ProgramSubject:
-    """One engine-built program under audit."""
+    """One server-built program under audit."""
     name: str
     jaxpr: Any = None             # ClosedJaxpr from jax.make_jaxpr
     hlo: Optional[str] = None     # compiled.as_text(), when compiled
     expect_donation: tuple = ()   # argnums the build asked to donate
-    is_round: bool = False        # a client-training round program
-    is_fused: bool = False        # the R-round block program
 
 
 @dataclasses.dataclass
@@ -44,7 +42,6 @@ class AuditContext:
     task: str = ""
     strategy: str = ""
     backend: str = ""
-    engine: str = "sequential"
 
 
 def _sds(shape, dtype):
@@ -52,10 +49,8 @@ def _sds(shape, dtype):
 
 
 def _subject(name: str, fn, args, *, compile: bool, expect_donation=(),
-             is_round: bool = False, is_fused: bool = False,
              findings: Optional[List[Finding]] = None) -> ProgramSubject:
-    s = ProgramSubject(name=name, expect_donation=tuple(expect_donation),
-                       is_round=is_round, is_fused=is_fused)
+    s = ProgramSubject(name=name, expect_donation=tuple(expect_donation))
     try:
         s.jaxpr = jax.make_jaxpr(fn)(*args)
     except Exception as e:            # surface, don't crash the audit
@@ -77,71 +72,48 @@ def collect_subjects(server, eval_data=None, eval_every: int = 1,
                      compile: bool = True,
                      findings: Optional[List[Finding]] = None
                      ) -> List[ProgramSubject]:
-    """Trace/compile the server's round programs as audit subjects.
+    """Trace/compile the server's block programs as audit subjects.
 
-    Batched engine: the single-round program (FedX or FedAvg at its
-    participant count), the fused ``rounds_per_dispatch``-round block
-    (using the knobs default when the server runs single-round
-    dispatches, so the fused contract is audited regardless), and the
-    jitted eval fn.  Sequential engine: the per-client update program
-    and the eval fn.  Shapes come from the server's real data; nothing
-    is executed — ``lower().compile()`` only.
+    ``round[...]`` is the block of one, without eval, that
+    ``run_round`` dispatches; ``block[... xR]`` is the
+    ``rounds_per_dispatch``-round block with on-device eval that
+    ``run_pipelined`` dispatches when R > 1; ``eval`` is the jitted eval
+    fn.  Shapes come from the server's real data; nothing is executed —
+    ``lower().compile()`` only.
     """
     subjects: List[ProgramSubject] = []
     eng = server._engine
-    params = server.global_params
     # the audit's own make_jaxpr/lower calls fire the engine's on_trace
     # hook; those traces are not dispatch-cache misses, so keep them out
     # of the traced_participant_counts ledger the cache-stability rule
     # reads (the hook holds a reference to the list — mutate in place)
-    ledger = getattr(eng, "traced_participant_counts", None)
-    snapshot = list(ledger) if ledger is not None else None
+    ledger = eng.traced_participant_counts
+    snapshot = list(ledger)
     try:
-        _collect(subjects, server, eng, params, eval_data, eval_every,
-                 compile, findings)
+        _collect(subjects, server, eng, eval_data, eval_every, compile,
+                 findings)
     finally:
-        if ledger is not None:
-            ledger[:] = snapshot
+        ledger[:] = snapshot
     return subjects
 
 
-def _collect(subjects, server, eng, params, eval_data, eval_every,
-             compile, findings):
-    if eng is not None:
-        n, m = eng.n_clients, eng.n_participants
-        keys = _sds((m, 2), jnp.uint32)
-        donate = _donate_argnums(True, backend=eng.backend)
-        if eng.is_fedx:
-            round_args = (params, eng.data, eng.mask, keys)
-        else:
-            sub = jax.tree.map(
-                lambda a: _sds((m,) + a.shape[1:], a.dtype), eng.data)
-            mask = (None if eng.mask is None else
-                    _sds((m,) + eng.mask.shape[1:], eng.mask.dtype))
-            round_args = (params, sub, mask, keys)
+def _collect(subjects, server, eng, eval_data, eval_every, compile,
+             findings):
+    params = server.global_params
+    name = server.strategy.name
+    rpd = server.rounds_per_dispatch
+    programs = [(f"round[{name}]", 1, 0, None)]
+    if rpd > 1:
+        programs.append((f"block[{name} x{rpd}]", rpd,
+                         eval_every if eval_data is not None else 0,
+                         eval_data))
+    donate = _donate_argnums(True, argnums=(0, 1), backend=eng.backend)
+    for label, n_rounds, every, batch in programs:
+        args = (params, _sds((2,), jnp.uint32), eng.data, eng.mask, batch,
+                _sds((), jnp.int32))
         subjects.append(_subject(
-            f"round[{server.strategy.name}]", eng._round, round_args,
-            compile=compile, expect_donation=donate, is_round=True,
-            findings=findings))
-        rpd = (server.rounds_per_dispatch
-               if server.rounds_per_dispatch > 1
-               else DEFAULT_ROUNDS_PER_DISPATCH)
-        block = eng.fused_rounds(
-            rpd, eval_every if eval_data is not None else 0)
-        block_args = (params, _sds((2,), jnp.uint32), eng.data, eng.mask,
-                      eval_data, _sds((), jnp.int32))
-        subjects.append(_subject(
-            f"block[{server.strategy.name} x{rpd}]", block, block_args,
-            compile=compile,
-            expect_donation=_donate_argnums(True, argnums=(0, 1),
-                                            backend=eng.backend),
-            is_round=True, is_fused=True, findings=findings))
-    else:
-        key = _sds((2,), jnp.uint32)
-        subjects.append(_subject(
-            f"client_update[{server.strategy.name}]", server._update,
-            (params, server.client_data[0], key), compile=compile,
-            is_round=True, findings=findings))
+            label, eng.fused_rounds(n_rounds, every), args,
+            compile=compile, expect_donation=donate, findings=findings))
     if eval_data is not None:
         subjects.append(_subject(
             "eval", server._eval, (params, eval_data), compile=compile,
@@ -153,7 +125,7 @@ def audit_experiment(experiment, *, compile: bool = True,
                      lint_roots: Optional[Sequence[str]] = None,
                      strict: bool = False) -> Report:
     """Audit a built :class:`repro.core.api.Experiment` (or any object
-    with ``.server`` / ``.eval_data``): run every rule over its round
+    with ``.server`` / ``.eval_data``): run every rule over its block
     programs plus the AST lint over the package source.
 
     ``strict=True`` raises :class:`AuditError` when any error-severity
@@ -171,9 +143,7 @@ def audit_experiment(experiment, *, compile: bool = True,
         subjects=subjects, server=server,
         task=getattr(cfg, "task", ""),
         strategy=server.strategy.name,
-        backend=(server._engine.backend if server._engine is not None
-                 else jax.default_backend()),
-        engine=server.engine)
+        backend=server._engine.backend)
     report.extend(run_rules(ctx))
     if lint:
         report.extend(lint_paths(lint_roots))

@@ -29,7 +29,7 @@ def build_audit_config(args) -> FLConfig:
         n_clients=args.clients, client_ratio=args.client_ratio,
         partition=args.partition, n_train=240, n_test=60, batch_size=8,
         local_epochs=1, mh_pop=2, mh_generations=1,
-        engine=args.engine, rounds_per_dispatch=args.rounds_per_dispatch,
+        rounds_per_dispatch=args.rounds_per_dispatch,
         max_rounds=2)
 
 
@@ -44,7 +44,6 @@ def main(argv=None) -> int:
                     choices=list(PARTITIONS))
     ap.add_argument("--clients", type=int, default=4)
     ap.add_argument("--client-ratio", type=float, default=1.0)
-    ap.add_argument("--engine", default="auto")
     ap.add_argument("--rounds-per-dispatch", default="auto")
     ap.add_argument("--strict", action="store_true",
                     help="exit 1 when error-severity findings survive")
@@ -62,7 +61,6 @@ def main(argv=None) -> int:
     cfg = build_audit_config(args)
     exp = build_experiment(cfg)
     print(f"flcheck: auditing task={cfg.task} strategy={cfg.strategy} "
-          f"engine={exp.server.engine} "
           f"rounds_per_dispatch={exp.server.rounds_per_dispatch} "
           f"clients={cfg.n_clients}", flush=True)
     report = audit_experiment(exp, compile=not args.no_compile,

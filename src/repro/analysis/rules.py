@@ -3,9 +3,9 @@
 Each rule is a function ``check(ctx) -> Iterable[Finding]`` registered
 with the :func:`rule` decorator; :func:`run_rules` runs the whole
 catalogue over an ``AuditContext`` (``repro.analysis.audit``) holding
-the program subjects (jaxpr + compiled HLO per engine-built round
-program) and the live server/engine.  Every rule degrades to an ``info``
-finding when its subject is absent (e.g. no compiled HLO in a
+the program subjects (jaxpr + compiled HLO per block program the
+server builds) and the live server/engine.  Every rule degrades to an
+``info`` finding when its subject is absent (e.g. no compiled HLO in a
 ``--no-compile`` run) — silence never means "checked and clean".
 
 The catalogue (DESIGN.md §8):
@@ -21,13 +21,11 @@ no-f64                 error    no f64/c128 value in any round program
 no-weak-type-promotion warning  no weakly-typed program output
 no-host-callback-in-   error    no pure/io/debug callback inside a
 scan                            fused scan body (it would fire xR)
-conv-policy            error    conv tasks stay off the batched CPU path
 compile-cache-         error    one executable per participant count;
 stability                       avals independent of WHICH participants
 ====================== ======== ==========================================
 
-Pure helpers (``check_donation``, ``check_conv_policy``,
-``check_cache_stability``) carry the rule logic so tests can drive each
+Pure helpers (``check_donation``, ``check_cache_stability``) carry the rule logic so tests can drive each
 rule's known-bad branch without building a bad engine.
 """
 from __future__ import annotations
@@ -38,9 +36,8 @@ from typing import Callable, Dict, Iterable, List, Sequence
 import numpy as np
 
 from repro.analysis.report import Finding
-from repro.analysis.walker import (CALLBACK_PRIMITIVES, CONV_PRIMITIVES,
-                                   iter_avals, iter_sites,
-                                   jaxpr_has_primitive)
+from repro.analysis.walker import (CALLBACK_PRIMITIVES, iter_avals,
+                                   iter_sites)
 from repro.launch.hlo_analysis import (count_host_transfers,
                                        parse_input_output_aliases)
 
@@ -227,37 +224,6 @@ def check_no_callback_in_scan(ctx) -> Iterable[Finding]:
     return out
 
 
-# -------------------------------------------------------------- conv-policy
-def check_conv_policy(has_conv: bool, backend: str,
-                      engine: str, subject: str = "") -> List[Finding]:
-    """Pure rule core: conv tasks must not run on the batched CPU path
-    (measured slower under every batched traversal, DESIGN.md §4)."""
-    if has_conv and backend == "cpu" and engine == "batched":
-        return [Finding(
-            "conv-policy", "error",
-            "convolution task on the batched CPU engine — XLA:CPU runs "
-            "convs slower under every batched client-axis traversal "
-            "(grouped convs under vmap, no fast conv thunk in loop "
-            "bodies); route it to the sequential engine",
-            subject=subject)]
-    return [Finding(
-        "conv-policy", "info",
-        f"ok (conv={has_conv}, backend={backend}, engine={engine})",
-        subject=subject)]
-
-
-@rule("conv-policy")
-def check_conv_policy_rule(ctx) -> Iterable[Finding]:
-    out: List[Finding] = []
-    for s in ctx.subjects:
-        if s.jaxpr is None or not s.is_round:
-            continue
-        has_conv = jaxpr_has_primitive(s.jaxpr, CONV_PRIMITIVES)
-        out.extend(check_conv_policy(has_conv, ctx.backend, ctx.engine,
-                                     subject=s.name))
-    return out
-
-
 # ---------------------------------------------------- compile-cache-stability
 def check_cache_stability(aval_sets: Sequence, traced_counts: Sequence[int]
                           = (), subject: str = "") -> List[Finding]:
@@ -265,8 +231,8 @@ def check_cache_stability(aval_sets: Sequence, traced_counts: Sequence[int]
 
     ``aval_sets``: one hashable (shape, dtype) signature per permuted
     participant selection — all must be identical, or each distinct
-    participant subset compiles its own executable (the sample-then-
-    stack contract caps the cache at one executable per participant
+    participant subset compiles its own executable (the in-program
+    participant gather caps the cache at one executable per participant
     count ``m``).  ``traced_counts``: the engine's
     ``traced_participant_counts`` ledger — a repeated entry means one
     ``m`` was traced twice (a cache miss on an already-seen shape).
@@ -311,10 +277,7 @@ def check_cache_stability_rule(ctx) -> Iterable[Finding]:
     key) depend only on the participant count ``m``."""
     import jax
 
-    eng = getattr(ctx, "server", None) and ctx.server._engine
-    if not eng:
-        return [Finding("compile-cache-stability", "info",
-                        "no batched engine; nothing to check")]
+    eng = ctx.server._engine
     m = eng.n_participants
     n = eng.n_clients
     rng = np.random.default_rng(0)

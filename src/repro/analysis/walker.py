@@ -1,9 +1,8 @@
 """Shared recursive jaxpr walker (flcheck's traversal core).
 
-One traversal, many callers: the round engine's conv-on-CPU auto policy
-(:func:`repro.core.engine.task_uses_conv`), the flcheck rules
-(``repro.analysis.rules``), and any future jaxpr-shaped question all
-walk programs through :func:`iter_sites` instead of keeping private
+One traversal, many callers: the flcheck rules
+(``repro.analysis.rules``) and any future jaxpr-shaped question walk
+programs through :func:`iter_sites` instead of keeping private
 recursions.  Each equation is yielded as an :class:`EqnSite` carrying
 
 * ``multiplier`` — the product of the enclosing ``lax.scan`` lengths
@@ -23,7 +22,6 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable, Dict, Iterable, Iterator, Tuple
 
-import jax
 
 # Primitives that call back into the host from inside a traced program.
 # Any of these inside a fused block is a device->host edge the
@@ -34,8 +32,6 @@ CALLBACK_PRIMITIVES = ("pure_callback", "io_callback", "debug_callback",
 # Loop-shaped higher-order primitives: an equation whose ``path``
 # crosses one of these runs repeatedly per dispatch.
 LOOP_PRIMITIVES = ("scan", "while", "fori", "map")
-
-CONV_PRIMITIVES = ("conv_general_dilated",)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -139,16 +135,3 @@ def iter_avals(jaxpr) -> Iterator[Any]:
             aval = getattr(v, "aval", None)
             if aval is not None:
                 yield aval
-
-
-def loss_uses_conv(loss_fn, params, sample_batch) -> bool:
-    """Abstractly trace ``loss_fn(params, batch)`` and report whether it
-    lowers to convolutions.  Drives the round engine's CPU engine="auto"
-    decision (DESIGN.md §4) and flcheck's ``conv-policy`` rule.  Returns
-    True (the conservative answer) when the trace fails.
-    """
-    try:
-        jaxpr = jax.make_jaxpr(loss_fn)(params, sample_batch)
-        return jaxpr_has_primitive(jaxpr, CONV_PRIMITIVES)
-    except Exception:
-        return True

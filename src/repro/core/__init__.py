@@ -4,23 +4,18 @@ FedAvg/FedPSO/FedGWO/FedSCA baselines.
 
 ``FLConfig`` -> ``build_experiment()`` -> ``run()`` (repro.core.api) is
 the one construction path for experiments; the lower-level pieces
-(``Server``, ``ClientHP``, the round engines) remain directly usable.
+(``Server``, ``ClientHP``, the round engine) remain directly usable.
 """
 from repro.core.client import ClientHP, Task, make_client_update
 from repro.core.comm import (BlockTiming, CommMeter, fedavg_total,
                              fedx_total, normalized_cost, SCORE_BYTES)
-from repro.core.engine import (BatchedRoundEngine, make_batched_fedavg_round,
-                               make_batched_fedx_round, make_fused_rounds,
+from repro.core.engine import (BatchedRoundEngine, make_fused_rounds,
                                pipeline_blocks, resolve_vectorize,
                                stack_clients)
 from repro.core.knobs import (AUDIT_MODES, DEFAULT_PIPELINE_DEPTH,
-                              DEFAULT_ROUNDS_PER_DISPATCH, ENGINES,
-                              PIPELINE_MODES, VECTORIZE_MODES,
-                              parse_audit, parse_pipeline_blocks,
-                              parse_rounds_per_dispatch,
+                              DEFAULT_ROUNDS_PER_DISPATCH, VECTORIZE_MODES,
+                              parse_audit, parse_rounds_per_dispatch,
                               parse_vectorize, validate_audit,
-                              validate_engine,
-                              validate_pipeline_blocks,
                               validate_rounds_per_dispatch,
                               validate_vectorize)
 from repro.core.protocol import RoundLog, StopConditions, run_federated
@@ -35,16 +30,13 @@ from repro.analysis.report import AuditError
 __all__ = ["ClientHP", "Task", "make_client_update", "BlockTiming",
            "CommMeter",
            "fedavg_total", "fedx_total", "normalized_cost", "SCORE_BYTES",
-           "BatchedRoundEngine", "make_batched_fedavg_round",
-           "make_batched_fedx_round", "make_fused_rounds",
+           "BatchedRoundEngine", "make_fused_rounds",
            "pipeline_blocks", "resolve_vectorize", "stack_clients",
            "DEFAULT_PIPELINE_DEPTH", "DEFAULT_ROUNDS_PER_DISPATCH",
-           "AUDIT_MODES", "ENGINES", "PIPELINE_MODES", "VECTORIZE_MODES",
-           "parse_audit", "parse_pipeline_blocks",
-           "parse_rounds_per_dispatch",
-           "parse_vectorize", "validate_audit", "validate_engine",
-           "validate_pipeline_blocks", "validate_rounds_per_dispatch",
-           "validate_vectorize",
+           "AUDIT_MODES", "VECTORIZE_MODES",
+           "parse_audit", "parse_rounds_per_dispatch",
+           "parse_vectorize", "validate_audit",
+           "validate_rounds_per_dispatch", "validate_vectorize",
            "RoundLog", "StopConditions", "run_federated",
            "PendingBlock", "PipelineResult", "Server", "Strategy",
            "get_strategy",

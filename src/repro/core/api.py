@@ -25,9 +25,7 @@ import jax
 
 from repro.core.client import ClientHP, Task
 from repro.core.comm import fedavg_total, normalized_cost
-from repro.core.knobs import (parse_audit, validate_engine,
-                              validate_pipeline_blocks,
-                              validate_rounds_per_dispatch,
+from repro.core.knobs import (parse_audit, validate_rounds_per_dispatch,
                               validate_vectorize)
 from repro.core.protocol import RoundLog, StopConditions, run_federated
 from repro.core.server import Server, get_strategy
@@ -75,20 +73,12 @@ class FLConfig:
     # w * (1 + genome_scale * (z - 1)) (ClientHP.subspace)
     genome: str = "flat"
     genome_scale: float = 0.05
-    engine: str = "auto"            # repro.core.knobs.ENGINES
     vectorize: str = "auto"         # knobs.VECTORIZE_MODES, opt. ":k"
     # rounds fused into one device dispatch ("auto" | int >= 1): R > 1
-    # runs blocks of R rounds as one XLA program with one host sync per
-    # block (DESIGN.md §6); "auto" = measured default on the batched
-    # engine, 1 on the sequential fallback
+    # runs pipelined blocks of R rounds, each one XLA program with one
+    # host sync (DESIGN.md §6-§7); "auto" = knobs'
+    # DEFAULT_ROUNDS_PER_DISPATCH
     rounds_per_dispatch: Any = 1
-    # double-buffer fused block dispatches against host-side log
-    # processing ("auto" | "on" | "off" | bool, DESIGN.md §7): block
-    # k+1 runs on device while block k's logs sync and the stopping
-    # conditions are checked (one-block stopping overshoot, trimmed
-    # from the logs); "auto" pipelines whenever there is a fused
-    # batched block to overlap
-    pipeline_blocks: Any = "auto"
     # evaluate the global model every k-th round; with fused blocks the
     # cadence runs on device, so skipped evals cost neither compute nor
     # a sync (block boundaries always evaluate)
@@ -101,10 +91,8 @@ class FLConfig:
     server_seed: int = 7
 
     def __post_init__(self):
-        validate_engine(self.engine)
         validate_vectorize(self.vectorize)
         validate_rounds_per_dispatch(self.rounds_per_dispatch)
-        validate_pipeline_blocks(self.pipeline_blocks)
         if self.eval_every < 1:
             raise ValueError(f"eval_every={self.eval_every} must be >= 1")
         if self.task not in TASKS:
@@ -147,7 +135,7 @@ def build_experiment(cfg: FLConfig, *, task: Optional[Task] = None,
                      audit: Any = "off") -> "Experiment":
     """Materialize an :class:`Experiment` from a config: synthesize the
     dataset, partition and batch it across clients, and construct the
-    ``Server`` (which picks the round engine per ``cfg.engine``).
+    ``Server``.
 
     Any of ``task`` / ``client_data`` / ``eval_data`` / ``hp`` may be
     passed to override the config-derived default — benchmarks use this
@@ -155,7 +143,7 @@ def build_experiment(cfg: FLConfig, *, task: Optional[Task] = None,
 
     ``audit`` opts the build into the flcheck static auditor
     (``repro.analysis``, knobs.AUDIT_MODES): ``"report"`` runs the rule
-    catalogue over the engine-built round programs and prints the
+    catalogue over the server's block programs and prints the
     findings; ``"strict"`` (or ``audit=True``) additionally raises
     :class:`repro.analysis.AuditError` on any error-severity finding,
     so a contract regression fails the build before any round runs.
@@ -197,9 +185,7 @@ def build_experiment(cfg: FLConfig, *, task: Optional[Task] = None,
                                  client_ratio=cfg.client_ratio),
                     hp if hp is not None else cfg.client_hp(),
                     client_data, jax.random.PRNGKey(cfg.server_seed),
-                    engine=cfg.engine,
-                    rounds_per_dispatch=cfg.rounds_per_dispatch,
-                    pipeline_blocks=cfg.pipeline_blocks)
+                    rounds_per_dispatch=cfg.rounds_per_dispatch)
     experiment = Experiment(cfg=cfg, server=server, eval_data=eval_data,
                             stop=cfg.stop_conditions())
     mode = parse_audit(audit)
@@ -256,9 +242,7 @@ class ExperimentResult:
             "strategy": self.cfg.strategy,
             "task": self.cfg.task,
             "partition": self.cfg.partition,
-            "engine": self.server.engine,
             "rounds_per_dispatch": self.server.rounds_per_dispatch,
-            "pipeline_blocks": self.server.pipeline_blocks,
             "rounds": len(self.logs),
             "final_acc": self.logs[-1].test_acc,
             "final_loss": self.logs[-1].test_loss,

@@ -60,9 +60,8 @@ class ClientHP:
     # FedProx proximal term (Li et al. 2020, paper's related work [18]):
     # local objective += (mu/2) * ||w - w_global||^2.  0 disables.
     prox_mu: float = 0.0
-    # How the batched round engine (repro.core.engine) traverses the
-    # client axis: "vmap" | "scan" | "unroll" | "auto" (scan on CPU,
-    # vmap elsewhere).  "scan:k" chunks the scan (unroll=k) so compile
+    # How the round engine (repro.core.engine) traverses the client
+    # axis: "vmap" | "scan" | "auto" (scan on CPU, vmap elsewhere).  "scan:k" chunks the scan (unroll=k) so compile
     # time stays flat in n_clients while dispatch overhead amortizes.
     # See repro.core.knobs, engine.resolve_vectorize and DESIGN.md §4-5.
     vectorize: str = "auto"
@@ -83,7 +82,7 @@ def make_local_sgd(task: Task, hp: ClientHP, masked: bool = False,
     update of a padded batch is discarded with ``jnp.where`` and —
     crucially for parity with the same client's unpadded run — the PRNG
     carry only advances past valid batches, so the per-batch dropout
-    keys match the sequential engine's bit for bit.
+    keys match the unpadded run's bit for bit.
 
     Where the task counts expert slots (``task.slot_loss``),
     ``local_sgd`` returns ``(params, slots)``: the counts summed over
@@ -167,8 +166,8 @@ def _fitness_slice(data, n_batches: int, n_valid=None):
     batches) this replicates the unpadded ``a[:n_batches][i]`` clamp
     semantics with a gather at ``min(i, n_valid - 1)``: a client with
     fewer than ``n_batches`` valid batches scores the same duplicated
-    trailing batch as it does on the sequential engine, never a padded
-    zero batch.
+    trailing batch as its unpadded run does, never a padded zero
+    batch.
     """
     if n_valid is None:
         return jax.tree.map(lambda a: a[:n_batches], data)
@@ -252,10 +251,10 @@ def make_client_update(task: Task, hp: ClientHP,
 
     With ``masked=True`` the signature becomes ``client_update(params,
     data, mask, rng)``: ``data`` is one client's row of a pad+mask stack
-    (:func:`repro.core.engine.stack_clients` with ``pad=True``) and
-    ``mask`` its ``(n_batches,)`` bool validity row.  Padded batches
-    contribute no SGD step and no fitness term, so scores and weights
-    match the same client's unpadded run on the sequential engine.
+    (:func:`repro.core.engine.stack_clients`) and ``mask`` its
+    ``(n_batches,)`` bool validity row.  Padded batches contribute no
+    SGD step and no fitness term, so scores and weights match the same
+    client's unpadded run.
 
     ``backend`` is the platform the update is compiled for (default:
     ``jax.default_backend()``); it decides :func:`unrolls`.

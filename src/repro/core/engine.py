@@ -1,10 +1,8 @@
-"""Batched FL round engine: one jit'd device dispatch per round.
+"""The FL round engine: every round runs as a block of a fused program.
 
-The sequential ``Server`` loop dispatches one jit call per client and
-synchronizes with the host in between; for FedX it also materializes a
-full model copy per client before the argmin.  This module compiles the
-*entire round* — every selected client's local update plus the server
-aggregation — into a single XLA program:
+:func:`make_fused_rounds` compiles a block of ``R`` rounds — every
+selected client's local update plus the server aggregation, for each
+round — into one XLA program; ``Server.run_round`` is a block of one.
 
 * client datasets are stacked along a leading ``(n_clients, ...)`` axis
   (:func:`stack_clients`); ragged datasets (Dirichlet splits) are
@@ -12,30 +10,24 @@ aggregation — into a single XLA program:
   validity mask rides along, threaded through ``make_client_update`` so
   padded batches contribute no SGD step and no fitness term
   (DESIGN.md §5);
-* ``make_client_update`` runs across that axis under ``jax.vmap``, a
-  ``lax.scan`` device loop, or a Python-unrolled streaming loop,
-  selected by the ``vectorize`` knob on :class:`~repro.core.client.
-  ClientHP` (see :func:`resolve_vectorize` for the CPU/TPU tradeoff;
-  ``"scan:k"`` chunks the scan so compile time stays flat in the
-  client count);
-* FedAvg with ``client_ratio < 1`` samples its ``m`` participants on
-  host and gathers only their shards before dispatch
-  (sample-then-stack), so the round executable is compiled for shape
-  ``(m, ...)`` — one cached executable per participant count — instead
-  of tracing all ``n_clients``;
-* the FedX argmin runs **on device** and the winner's weights are
-  selected with a ``jnp.where`` streaming reduction — the scan carry
-  holds only ``(best_score, best_params)``, so peak weight memory is
-  O(2 x model) instead of O(n_clients x model);
-* FedAvg accumulates a running parameter sum in the carry the same way,
-  and the round function donates the incoming global-params buffer
+* ``make_client_update`` runs across that axis under ``jax.vmap`` or a
+  ``lax.scan`` device loop, selected by the ``vectorize`` knob on
+  :class:`~repro.core.client.ClientHP` (:func:`resolve_vectorize`;
+  ``"scan:k"`` chunks the scan so compile time stays flat in the client
+  count);
+* FedAvg with ``client_ratio < 1`` draws its ``m`` participants on
+  device and gathers their shards inside the program, so the block is
+  compiled for shape ``(m, ...)`` — one cached executable per
+  participant count;
+* under ``scan`` the FedX winner is selected with a ``jnp.where``
+  streaming reduction — the carry holds only ``(best_score,
+  best_params)``, so peak weight memory is O(2 x model) instead of
+  O(n_clients x model); FedAvg accumulates a running mean the same way;
+* the block donates its incoming ``(global_params, rng)`` buffers
   (``donate_argnums``) on backends that support aliasing.
 
-``repro.core.distributed`` builds the same per-client update into
-shard_map collective schedules; its round builders live here
-(:func:`make_sharded_fedx_round` / :func:`make_sharded_fedavg_round`)
-so the single-host batched engine and the mesh engine are two
-placements of one round-builder.
+:func:`make_sharded_fedx_round` / :func:`make_sharded_fedavg_round`
+place the same round bodies on a device mesh under ``shard_map``.
 """
 from __future__ import annotations
 
@@ -47,11 +39,9 @@ import jax.numpy as jnp
 from jax.flatten_util import ravel_pytree
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.analysis.walker import (CONV_PRIMITIVES, jaxpr_has_primitive,
-                                   loss_uses_conv)
 from repro.core import tracing
 from repro.core.client import ClientHP, Task, make_client_update
-from repro.core.knobs import VECTORIZE_MODES, parse_vectorize
+from repro.core.knobs import parse_vectorize
 from repro.metaheuristics import Metaheuristic
 
 
@@ -60,23 +50,11 @@ def resolve_vectorize(mode: str, backend: Optional[str] = None) -> str:
 
     ``vmap``   — one batched program over the client axis.  Fastest on
                  TPU/GPU, but vmapping *conv weights* lowers to grouped
-                 convolutions that are pathologically slow on XLA:CPU.
+                 convolutions that are slow on XLA:CPU.
     ``scan``   — ``lax.scan`` device loop, O(2 x model) weight memory,
-                 compact compile.  Measured fastest batched mode on CPU
-                 for dense models (GEMMs are loop-body-safe); XLA:CPU
-                 lacks fast conv thunks inside loop bodies, so conv
-                 models are ~5x slower here (DESIGN.md §4).  A
-                 ``"scan:k"`` suffix unrolls k scan iterations per loop
-                 step (repro.core.knobs).
-    ``unroll`` — the scan unrolled in Python: still one dispatch and
-                 the same streaming reduction.  Keeps CPU convs on the
-                 fast conv thunk, but compile time grows ~linearly with
-                 n_clients and the measured steady state still trails
-                 the sequential loop for conv models.
-    ``auto``   — ``scan`` on CPU, ``vmap`` elsewhere.  (Whether to
-                 batch *at all* on CPU is the server's engine="auto"
-                 decision, which checks the task for convolutions —
-                 see :func:`task_uses_conv`.)
+                 compact compile.  A ``"scan:k"`` suffix unrolls k scan
+                 iterations per loop step (repro.core.knobs).
+    ``auto``   — ``scan`` on CPU, ``vmap`` elsewhere.
     """
     base, _ = parse_vectorize(mode)
     if base != "auto":
@@ -85,56 +63,30 @@ def resolve_vectorize(mode: str, backend: Optional[str] = None) -> str:
     return "scan" if backend == "cpu" else "vmap"
 
 
-def _scan_unroll(vectorize: str, mode: str, n: int) -> int:
-    """lax.scan ``unroll`` for a client-axis scan of length ``n``:
-    the full length for mode="unroll", else the ':k' chunk."""
+def _scan_unroll(vectorize: str, n: int) -> int:
+    """lax.scan ``unroll`` for a client-axis scan of length ``n``: the
+    ':k' chunk."""
     _, chunk = parse_vectorize(vectorize)
-    return n if mode == "unroll" else max(1, min(chunk, max(n, 1)))
+    return max(1, min(chunk, max(n, 1)))
 
 
-_CONV_PRIMITIVES = CONV_PRIMITIVES
-
-# One walker, two callers (DESIGN.md §8): the recursive jaxpr traversal
-# used here for the conv-on-CPU auto policy is the same one flcheck's
-# rules run over full round programs — re-exported so existing engine
-# call sites keep working.
-_jaxpr_has_primitive = jaxpr_has_primitive
-
-
-def task_uses_conv(task: Task, params, sample_batch) -> bool:
-    """Abstractly trace ``task.loss_fn`` and report whether it lowers to
-    convolutions.  Drives the CPU engine="auto" decision: XLA:CPU runs
-    convolutions slower under every batched traversal (grouped convs
-    under vmap, no fast conv thunk in loop bodies, and measured ~1.5x
-    slower even fully unrolled) than as per-client dispatches, so conv
-    tasks stay on the sequential engine on CPU.  Returns True (the
-    conservative answer) when the trace fails.  Thin wrapper over
-    :func:`repro.analysis.walker.loss_uses_conv` (the shared walker).
-    """
-    return loss_uses_conv(task.loss_fn, params, sample_batch)
-
-
-def stack_clients(client_data: Sequence[Any], pad: bool = False):
+def stack_clients(client_data: Sequence[Any]):
     """Stack per-client pytrees along a new leading client axis.
 
-    With ``pad=False`` (legacy): returns the stacked pytree, or ``None``
-    when the clients are not exactly stackable (ragged batch counts or
-    mismatched structures).
-
-    With ``pad=True``: returns ``(stacked, mask)``.  Ragged *leading*
-    (batch-count) axes — e.g. a Dirichlet split — are zero-padded to the
-    longest client, and ``mask`` is a ``(n_clients, max_batches)`` bool
-    array marking the valid rows (all-True when the clients were already
-    uniform).  A zero-length leading axis (a client that received no
-    batches at all, possible under extreme Dirichlet skew) is handled
-    like any other ragged length: padded up to the longest client with
-    an all-``False`` mask row — callers that cannot train an empty
-    client (e.g. :class:`BatchedRoundEngine`) detect those rows and
-    raise.  ``(None, None)`` when the clients are genuinely
-    unstackable: mismatched tree structures, trailing batch shapes,
-    dtypes, or inconsistent leading dims within one client.
+    Returns ``(stacked, mask)``.  Ragged *leading* (batch-count) axes —
+    e.g. a Dirichlet split — are zero-padded to the longest client, and
+    ``mask`` is a ``(n_clients, max_batches)`` bool array marking the
+    valid rows (all-True when the clients were already uniform).  A
+    zero-length leading axis (a client that received no batches at all,
+    possible under extreme Dirichlet skew) is handled like any other
+    ragged length: padded up to the longest client with an all-``False``
+    mask row — callers that cannot train an empty client (e.g.
+    :class:`BatchedRoundEngine`) detect those rows and raise.
+    ``(None, None)`` when the clients are genuinely unstackable:
+    mismatched tree structures, trailing batch shapes, dtypes, or
+    inconsistent leading dims within one client.
     """
-    empty = (None, None) if pad else None
+    empty = (None, None)
     if not client_data:
         return empty
     ref = jax.tree.structure(client_data[0])
@@ -151,10 +103,6 @@ def stack_clients(client_data: Sequence[Any], pad: bool = False):
         if any(a.shape[1:] != b.shape[1:] or a.dtype != b.dtype
                for a, b in zip(leaves, ref_leaves)):
             return empty
-    if not pad:
-        if len(set(lens)) > 1:
-            return None
-        return jax.tree.map(lambda *xs: jnp.stack(xs), *client_data)
     max_len = max(lens)
 
     def pad_to(a):
@@ -194,10 +142,10 @@ def _fedx_round_body(task: Task, hp: ClientHP, mh: Metaheuristic,
                      backend: Optional[str] = None):
     """Un-jitted FedX round: ``round_fn(global_params, data, mask, keys)
     -> (best_params, scores, best_idx)``, and the clients' summed expert
-    slots last where the task counts them (``Task.slot_loss``).  Jitted
-    standalone by :func:`make_batched_fedx_round`; traced inline by the
-    multi-round fusion (:func:`make_fused_rounds`) so one XLA program
-    spans a whole block of rounds."""
+    slots last where the task counts them (``Task.slot_loss``).  Traced
+    inline by :func:`make_fused_rounds`, so one XLA program spans a
+    whole block of rounds, and per mesh slice by
+    :func:`make_sharded_fedx_round`."""
     mode = resolve_vectorize(vectorize, backend)
     client_update = make_client_update(task, hp, mh, masked=masked,
                                        backend=backend)
@@ -231,34 +179,12 @@ def _fedx_round_body(task: Task, hp: ClientHP, mh: Metaheuristic,
             init = (jnp.asarray(jnp.inf, jnp.float32), global_params)
             (_, winner), (scores, slots) = jax.lax.scan(
                 step, init, (data, mask, keys),
-                unroll=_scan_unroll(vectorize, mode, n))
+                unroll=_scan_unroll(vectorize, n))
             with jax.named_scope(tracing.SERVER_REDUCE):
                 best = jnp.argmin(scores)
             return (winner, scores, best) + tuple(s.sum(0) for s in slots)
 
     return round_fn
-
-
-def make_batched_fedx_round(task: Task, hp: ClientHP, mh: Metaheuristic,
-                            vectorize: str = "auto", donate: bool = True,
-                            masked: bool = False,
-                            backend: Optional[str] = None):
-    """Returns jit'd ``round_fn(global_params, data, mask, keys) ->
-    (best_params, scores, best_idx)``.
-
-    ``data``: client datasets stacked to ``(n_clients, ...)`` leaves.
-    ``mask``: ``(n_clients, n_batches)`` bool validity rows from
-    ``stack_clients(..., pad=True)``, or ``None`` for uniform data
-    (``masked=False`` — an empty pytree arg, so both builds share one
-    signature).
-    ``keys``: ``(n_clients, 2)`` uint32 PRNG keys, one per client.
-    ``backend``: target backend for the vectorize/donation decisions
-    (default: resolved once here via ``jax.default_backend()``).
-    """
-    backend = backend or jax.default_backend()
-    return jax.jit(_fedx_round_body(task, hp, mh, vectorize, masked,
-                                    backend),
-                   donate_argnums=_donate_argnums(donate, backend=backend))
 
 
 def _fedavg_round_body(task: Task, hp: ClientHP, vectorize: str = "auto",
@@ -268,7 +194,8 @@ def _fedavg_round_body(task: Task, hp: ClientHP, vectorize: str = "auto",
     """Un-jitted FedAvg round: ``round_fn(global_params, data, mask,
     keys) -> (avg_params, scores)`` over the (already gathered)
     participant axis, and the summed expert slots last where the task
-    counts them.  See :func:`_fedx_round_body`."""
+    counts them.  ``on_trace`` is called with the participant count
+    each time the body is traced.  See :func:`_fedx_round_body`."""
     mode = resolve_vectorize(vectorize, backend)
     client_update = make_client_update(task, hp, None, masked=masked,
                                        backend=backend)
@@ -299,34 +226,10 @@ def _fedavg_round_body(task: Task, hp: ClientHP, vectorize: str = "auto",
             acc0 = jax.tree.map(jnp.zeros_like, global_params)
         avg, (scores, slots) = jax.lax.scan(
             step, acc0, (data, mask, keys),
-            unroll=_scan_unroll(vectorize, mode, m))
+            unroll=_scan_unroll(vectorize, m))
         return (avg, scores) + tuple(s.sum(0) for s in slots)
 
     return round_fn
-
-
-def make_batched_fedavg_round(task: Task, hp: ClientHP,
-                              vectorize: str = "auto", donate: bool = True,
-                              masked: bool = False,
-                              on_trace: Optional[Callable[[int], None]]
-                              = None,
-                              backend: Optional[str] = None):
-    """Returns jit'd ``round_fn(global_params, data, mask, keys) ->
-    (avg_params, scores)``.
-
-    Shape-polymorphic over the leading participant axis (sample-then-
-    stack): the caller samples the ``m`` participants on host, gathers
-    their ``(m, ...)`` shards (plus mask rows and keys), and jit caches
-    one executable per distinct ``m`` — a round at ``client_ratio < 1``
-    never traces or compiles for the full ``n_clients``.  ``on_trace``
-    is called with ``m`` each time a new participant count is traced
-    (compile-cache accounting/tests).  ``backend`` as in
-    :func:`make_batched_fedx_round`.
-    """
-    backend = backend or jax.default_backend()
-    return jax.jit(_fedavg_round_body(task, hp, vectorize, masked, on_trace,
-                                      backend),
-                   donate_argnums=_donate_argnums(donate, backend=backend))
 
 
 # -------------------------------------------------------------- fused --
@@ -338,13 +241,16 @@ def make_fused_rounds(task: Task, strategy, hp: ClientHP,
                       backend: Optional[str] = None):
     """Fuse ``rounds_per_dispatch`` FL rounds into one XLA dispatch.
 
+    The one round program of the single-chip engine: a block of one is
+    ``Server.run_round``, a block of ``R`` is ``Server.run_block``.
     Wraps the single-round bodies (:func:`_fedx_round_body` /
-    :func:`_fedavg_round_body`) in an outer ``lax.scan`` over the round
-    axis, carrying ``(global_params, rng)``.  FedBWO's protocol has no
-    per-round host decision at full participation — clients upload a
-    4-byte score and the server adopts the winner on device — so entire
-    blocks of rounds are fusible: the host pays one dispatch and one
-    device->host log sync per ``R`` rounds instead of per round.
+    :func:`_fedavg_round_body`, looked up on this module at build time)
+    in an outer ``lax.scan`` over the round axis, carrying
+    ``(global_params, rng)``.  FedBWO's protocol has no per-round host
+    decision at full participation — clients upload a 4-byte score and
+    the server adopts the winner on device — so entire blocks of rounds
+    are fusible: the host pays one dispatch and one device->host log
+    sync per ``R`` rounds instead of per round.
 
     Returns jit'd ``block_fn(global_params, rng, data, mask, eval_batch,
     round_offset) -> (new_params, new_rng, logs)`` where ``logs`` holds
@@ -360,17 +266,13 @@ def make_fused_rounds(task: Task, strategy, hp: ClientHP,
       rounds the cadence skips, so accuracy curves no longer force a
       per-round sync.
 
-    Bit-exactness with ``Server.run_round``: the scan body derives each
-    round's keys with the same ``jax.random.split(rng, n_clients + 2)
-    -> (rng, sel_key, client_keys)`` schedule the server runs on host —
-    threefry is deterministic across the host/device boundary, so the
-    key sequence (and everything downstream) is identical.  FedAvg
-    ``client_ratio < 1`` moves the sample-then-stack participant choice
-    on device: the same ``jax.random.choice(sel_key, n, (m,),
-    replace=False)`` at fixed ``m``, followed by an in-program gather of
-    the participants' shards/mask rows/keys — the block executable is
-    still compiled for the participant count ``m`` only (one cached
-    program per distinct ``m``, like the single-round path).
+    Key schedule: each round derives its keys with ``jax.random.split(
+    rng, n_clients + 2) -> (rng, sel_key, client_keys)`` on device, so a
+    block of ``R`` is bit-identical to ``R`` blocks of one.  FedAvg
+    ``client_ratio < 1`` draws its participants with ``jax.random.
+    choice(sel_key, n, (m,), replace=False)`` at fixed ``m`` and gathers
+    their shards/mask rows/keys in the program — the executable is
+    compiled for the participant count ``m`` only.
 
     ``round_offset`` (traced scalar) anchors the eval cadence globally:
     round ``round_offset + i`` evaluates when ``(round_offset + i + 1) %
@@ -402,7 +304,7 @@ def make_fused_rounds(task: Task, strategy, hp: ClientHP,
 
         def one_round(carry, i):
             params, rng = carry
-            # Server.run_round's host key schedule, derived on device
+            # the round's key schedule, derived on device
             keys = jax.random.split(rng, n_clients + 2)
             rng, sel_key, ckeys = keys[0], keys[1], keys[2:]
             if is_fedx:
@@ -410,8 +312,8 @@ def make_fused_rounds(task: Task, strategy, hp: ClientHP,
                     params, data, mask, ckeys)
                 log = {"scores": scores, "best": best}
             else:
-                # on-device sample-then-stack: same choice op and key as
-                # the host path, gather inside the program at fixed m
+                # draw the participants on device and gather their
+                # shards inside the program at fixed m
                 sel = jax.random.choice(sel_key, n_clients, (m,),
                                         replace=False)
                 sub = jax.tree.map(lambda a: jnp.take(a, sel, axis=0),
@@ -446,34 +348,31 @@ def make_fused_rounds(task: Task, strategy, hp: ClientHP,
 
 
 class BatchedRoundEngine:
-    """Compiled whole-round executor used by :class:`repro.core.Server`.
+    """The round executor of :class:`repro.core.Server`: the stacked
+    client data on device and the fused block programs
+    (:func:`make_fused_rounds`) over it, one per block shape.
 
-    Holds the stacked client data on device and one jit'd round function
-    per (task, strategy).  Ragged client datasets are padded to the
-    longest client with a validity mask (``self.padded``); genuinely
-    unstackable datasets (mismatched structures / trailing shapes /
-    dtypes) raise ``ValueError`` at construction and the server falls
-    back to its sequential loop.
-
-    FedAvg participation is sample-then-stack: ``fedavg_round`` samples
-    the ``m = max(C * n, 1)`` participants on host, gathers their shards
-    and dispatches an executable compiled for shape ``(m, ...)``.
-    ``traced_participant_counts`` records every participant count the
-    round function was traced for (it should stay at one entry).
+    Ragged client datasets are padded to the longest client with a
+    validity mask (``self.padded``); genuinely unstackable datasets
+    (mismatched structures / trailing shapes / dtypes) and empty shards
+    raise ``ValueError`` at construction.  FedAvg's ``m = max(C * n,
+    1)`` participants are drawn and gathered inside the block.
+    ``traced_participant_counts`` records the participant count of every
+    block trace.
     """
 
     def __init__(self, task: Task, strategy, hp: ClientHP,
                  client_data: Sequence[Any],
                  vectorize: Optional[str] = None,
                  backend: Optional[str] = None):
-        stacked, mask = stack_clients(client_data, pad=True)
+        stacked, mask = stack_clients(client_data)
         if stacked is None:
             raise ValueError(
                 "client datasets are not stackable: tree structures, "
                 "trailing batch shapes, and dtypes must match across "
                 "clients (ragged batch counts alone are fine — they are "
                 "padded and masked)")
-        if mask is not None and not bool(mask.any(axis=1).all()):
+        if not bool(mask.any(axis=1).all()):
             empty = jnp.where(~mask.any(axis=1))[0].tolist()
             raise ValueError(
                 f"client shards {empty} are empty (0 batches): an "
@@ -486,29 +385,16 @@ class BatchedRoundEngine:
         self.n_batches = int(mask.shape[1])
         self.padded = not bool(mask.all())
         self.mask = mask if self.padded else None
-        self.is_fedx = strategy.is_fedx
+        self.n_participants = (self.n_clients if strategy.is_fedx else max(
+            int(strategy.client_ratio * self.n_clients), 1))
         # the target backend is resolved once, here, and passed through
-        # every round/block build so vectorize + donation decisions
-        # can't drift with a later jax.default_backend() change
+        # every block build so vectorize + donation decisions can't
+        # drift with a later jax.default_backend() change
         self.backend = backend or jax.default_backend()
-        spec = vectorize if vectorize is not None else hp.vectorize
-        self.vectorize = resolve_vectorize(spec, self.backend)
-        self._task, self._strategy, self._hp, self._spec = (
-            task, strategy, hp, spec)
+        self._task, self._strategy, self._hp = task, strategy, hp
+        self._spec = vectorize if vectorize is not None else hp.vectorize
         self._fused = {}
         self.traced_participant_counts: List[int] = []
-        if self.is_fedx:
-            self.n_participants = self.n_clients
-            self._round = make_batched_fedx_round(
-                task, hp, strategy.mh, vectorize=spec, masked=self.padded,
-                backend=self.backend)
-        else:
-            self.n_participants = max(
-                int(strategy.client_ratio * self.n_clients), 1)
-            self._round = make_batched_fedavg_round(
-                task, hp, vectorize=spec, masked=self.padded,
-                on_trace=self.traced_participant_counts.append,
-                backend=self.backend)
 
     def fused_rounds(self, rounds_per_dispatch: int, eval_every: int = 0):
         """The R-round fused block function (:func:`make_fused_rounds`)
@@ -538,27 +424,6 @@ class BatchedRoundEngine:
             eval_every if eval_batch is not None else 0)
         return block(global_params, rng, self.data, self.mask,
                      eval_batch, jnp.asarray(round_offset, jnp.int32))
-
-    def fedx_round(self, global_params, keys):
-        """-> (winner_params, scores, best_idx[, expert slots]); one
-        dispatch, no sync."""
-        return self._round(global_params, self.data, self.mask, keys)
-
-    def fedavg_round(self, global_params, sel_key, keys):
-        """-> (avg_params, scores, sel[, expert slots]).
-
-        Sample-then-stack: the participant choice is materialized on
-        host, the ``(m, ...)`` shards are gathered outside the round
-        program, and the dispatch is one executable shaped for ``m``.
-        """
-        sel = jax.random.choice(sel_key, self.n_clients,
-                                (self.n_participants,), replace=False)
-        sub = jax.tree.map(lambda a: jnp.take(a, sel, axis=0), self.data)
-        mask = (None if self.mask is None
-                else jnp.take(self.mask, sel, axis=0))
-        avg, scores, *slots = self._round(global_params, sub, mask,
-                                          jnp.take(keys, sel, axis=0))
-        return (avg, scores, sel) + tuple(slots)
 
 
 # ----------------------------------------------------------- pipeline --
@@ -621,8 +486,10 @@ def make_sharded_fedx_round(task: Task, hp: ClientHP, mh: Metaheuristic,
     (:func:`_fedx_round_body`) over its own clients with zero
     collectives, down to its local winner.  The cross-slice traffic is
     one fp32 all_gather of every score (N x 4 bytes) plus one
-    masked-psum fetch of the global winner's weights (M bytes) — see
-    repro.core.distributed.
+    masked-psum fetch of the global winner's weights (M bytes).  JAX has
+    no dynamic-source broadcast, so the fetch is ``psum(where(my_id ==
+    winner, w, 0))`` — physically an all-reduce of M bytes, logically
+    the paper's single model transfer (DESIGN.md §3).
     """
     backend = _mesh_backend(mesh)
     local_round = _fedx_round_body(task, hp, mh, hp.vectorize,

@@ -1,33 +1,27 @@
 """Single source of truth for the round-engine knob vocabulary.
 
-``Server`` (engine selection), the batched engine (client-axis
-traversal), the CLI driver (``repro.launch.fl_train``), and the
-:class:`repro.core.api.FLConfig` facade all validate their ``engine`` /
-``vectorize`` strings through these helpers instead of keeping separate
+The engine (client-axis traversal), the CLI driver
+(``repro.launch.fl_train``), and the :class:`repro.core.api.FLConfig`
+facade all validate their ``vectorize`` / ``rounds_per_dispatch`` /
+``audit`` values through these helpers instead of keeping separate
 choices lists.
 
 ``vectorize`` accepts an optional ``:k`` suffix (``"scan:4"``) setting
 the ``lax.scan`` unroll chunk: the scan body is replicated ``k`` times
 per loop iteration, so compile time stays O(model) while dispatch
-overhead amortizes over ``k`` clients — the middle ground between
-``scan`` (k=1) and ``unroll`` (k=n).  Only meaningful for ``scan`` and
+overhead amortizes over ``k`` clients.  Only meaningful for ``scan`` and
 for ``auto`` when it resolves to scan.
 """
 from __future__ import annotations
 
 from typing import Optional, Tuple, Union
 
-ENGINES = ("auto", "batched", "sequential")
-VECTORIZE_MODES = ("auto", "vmap", "scan", "unroll")
+VECTORIZE_MODES = ("auto", "vmap", "scan")
 
-# Measured default for rounds_per_dispatch="auto" on the batched engine
-# (DESIGN.md §6): enough rounds to amortize the per-dispatch host
-# round-trip without block-sized compile blowup or coarse stopping.
+# Default for rounds_per_dispatch="auto" (DESIGN.md §6): enough rounds
+# to amortize the per-dispatch host round-trip without block-sized
+# compile blowup or coarse stopping.
 DEFAULT_ROUNDS_PER_DISPATCH = 5
-
-# pipeline_blocks knob vocabulary (DESIGN.md §7): double-buffer fused
-# block dispatches against host-side log processing.
-PIPELINE_MODES = ("auto", "on", "off")
 
 # How many blocks may be in flight under the pipelined driver: 2 is
 # classic double buffering — one executing on device while the previous
@@ -37,35 +31,8 @@ PIPELINE_MODES = ("auto", "on", "off")
 DEFAULT_PIPELINE_DEPTH = 2
 
 
-def parse_pipeline_blocks(spec: Union[bool, str, None]) -> Optional[bool]:
-    """``"auto"``/``None`` -> ``None`` (the server resolves it: pipeline
-    exactly when there is a fused batched block to overlap, i.e. the
-    batched engine with ``rounds_per_dispatch > 1``); ``"on"``/``True``
-    -> ``True`` (forced — still requires the batched engine);
-    ``"off"``/``False`` -> ``False``."""
-    if spec is None or spec == "auto":
-        return None
-    if isinstance(spec, bool):
-        return spec
-    low = str(spec).lower()
-    if low in ("on", "true", "1"):
-        return True
-    if low in ("off", "false", "0"):
-        return False
-    raise ValueError(
-        f"pipeline_blocks={spec!r} must be one of {PIPELINE_MODES} "
-        f"(or a bool)")
-
-
-def validate_pipeline_blocks(spec):
-    parse_pipeline_blocks(spec)
-    return spec
-
-
 def parse_rounds_per_dispatch(spec: Union[int, str, None]) -> Optional[int]:
-    """``"auto"``/``None`` -> ``None`` (the server resolves it against
-    the engine policy: 1 when the round engine is sequential — e.g. conv
-    tasks on CPU, DESIGN.md §4 — else the measured
+    """``"auto"``/``None`` -> ``None`` (the server resolves it to
     ``DEFAULT_ROUNDS_PER_DISPATCH``); anything else must be a positive
     integer round count."""
     if spec is None or spec == "auto":
@@ -111,12 +78,6 @@ def parse_audit(spec: Union[bool, str, None]) -> str:
 def validate_audit(spec):
     parse_audit(spec)
     return spec
-
-
-def validate_engine(name: str) -> str:
-    if name not in ENGINES:
-        raise ValueError(f"engine={name!r} not in {ENGINES}")
-    return name
 
 
 def parse_vectorize(spec: str) -> Tuple[str, int]:

@@ -4,25 +4,19 @@
 2. accuracy above threshold ``tau``,
 3. round limit reached.
 
-When the server runs with ``rounds_per_dispatch > 1`` on the batched
-engine, the driver dispatches *blocks* of rounds through
-``Server.run_block`` — one XLA program and one device->host sync per
-block, with eval folded into the device program at the ``eval_every``
-cadence (DESIGN.md §6).  Stopping conditions are still checked per
-evaluated round, but a dispatched block is atomic: if tau/patience
-triggers mid-block, the remaining rounds of that block have already run
-(and are logged/accounted) — the fused path trades stopping granularity
-for dispatch overhead.
-
-With ``server.pipeline_blocks`` on, the fused blocks are additionally
-double-buffered (``Server.run_pipelined``, DESIGN.md §7): block k+1 is
-dispatched before block k's logs are fetched, so host-side log
-processing and stopping checks overlap device execution.  The cost is
-one more block of stopping overshoot: when tau/patience triggers in
-block k, block k+1 is already in flight and completes (it advances the
+When the server runs with ``rounds_per_dispatch`` R > 1, the driver
+runs every full block of R rounds through ``Server.run_pipelined``: one
+XLA program and one device->host sync per block, with eval folded into
+the device program at the ``eval_every`` cadence (DESIGN.md §6), and
+block k+1 dispatched before block k's logs are fetched, so host-side
+log processing and stopping checks overlap device execution (DESIGN.md
+§7).  Stopping conditions are still checked per evaluated round, but a
+dispatched block is atomic, and one more block is in flight: when
+tau/patience triggers in block k, block k+1 completes (it advances the
 server's params/round counter/meter), but its rounds are trimmed from
-the returned logs — the log list still ends at the triggering block,
-exactly like the serial fused driver's.
+the returned logs, which end at the triggering block.  Leftover rounds
+(fewer than R), and every round when R = 1, run as ``run_round`` +
+``evaluate``.
 """
 from __future__ import annotations
 
@@ -68,9 +62,7 @@ def run_federated(server: Server, eval_data, stop: StopConditions,
     """
     logs: List[RoundLog] = []
     best_acc, stale = -1.0, 0
-    rpd = int(getattr(server, "rounds_per_dispatch", 1))
-    fused = rpd > 1 and getattr(server, "engine", "sequential") == "batched"
-    pipelined = fused and bool(getattr(server, "pipeline_blocks", False))
+    rpd = int(server.rounds_per_dispatch)
     rnd, stop_now = 0, False
 
     def check_stop(acc):
@@ -84,14 +76,15 @@ def run_federated(server: Server, eval_data, stop: StopConditions,
         return acc >= stop.tau or stale >= stop.patience
 
     while rnd < stop.max_rounds and not stop_now:
-        if pipelined and stop.max_rounds - rnd >= rpd:
+        if rpd > 1 and stop.max_rounds - rnd >= rpd:
             # double-buffered: all remaining full blocks in one
             # pipelined drive; block k's log processing + stopping
             # checks overlap block k+1's device execution.  If a stop
             # triggers, the in-flight block completes (one-block
             # overshoot on the server's state/meter) but its rounds are
             # trimmed from the logs; leftover rounds (< rpd) fall
-            # through to the single-round path below.
+            # through to the single-round path below, so only one block
+            # shape ever compiles.
             n = ((stop.max_rounds - rnd) // rpd) * rpd
             t0 = time.perf_counter()
             res = server.run_pipelined(
@@ -110,25 +103,6 @@ def run_federated(server: Server, eval_data, stop: StopConditions,
                           f"pipelined) {info if rnd < 2 else ''}")
                 rnd += 1
             stop_now = res.stopped
-        elif fused and stop.max_rounds - rnd >= rpd:
-            # one dispatch + one log sync for the whole block; leftover
-            # rounds (< rpd) fall through to the single-round path below
-            # so only one block shape ever compiles
-            t0 = time.perf_counter()
-            infos = server.run_block(rpd, eval_data, eval_every=eval_every)
-            jax.block_until_ready(server.global_params)
-            dt = time.perf_counter() - t0
-            for info in infos:
-                loss = info.pop("eval_loss", float("nan"))
-                acc = info.pop("eval_acc", float("nan"))
-                logs.append(RoundLog(rnd, loss, acc, dt / rpd, info,
-                                     dt / rpd))
-                if verbose:
-                    print(f"  round {rnd:3d}  loss={loss:.4f} "
-                          f"acc={acc:.4f} ({dt / rpd:.2f}s amortized) "
-                          f"{info if rnd < 2 else ''}")
-                stop_now = check_stop(acc) or stop_now
-                rnd += 1
         else:
             t0 = time.perf_counter()
             info = server.run_round()

@@ -6,27 +6,14 @@
               (Alg. 3: ServerRun + GetBestModel).  X ∈ {BWO, PSO, GWO,
               SCA} only changes the client-side meta-heuristic.
 
-Two round engines execute the same protocol with identical ``CommMeter``
-accounting:
-
-``batched``    — one jit'd dispatch for the whole round via
-                 :class:`repro.core.engine.BatchedRoundEngine`; zero
-                 per-client host syncs (exactly one device->host
-                 transfer per round, for the round log).  Ragged
-                 (e.g. Dirichlet-partitioned) client datasets batch
-                 too, via pad+mask stacking (DESIGN.md §5); FedAvg
-                 partial participation is sample-then-stack, compiled
-                 for the participant count only.
-``sequential`` — the original per-client jit loop; kept as the fallback
-                 for genuinely unstackable client datasets (mismatched
-                 structures/shapes/dtypes) and as the baseline for the
-                 engine-parity tests/benchmarks.
-
-On top of the batched engine, ``rounds_per_dispatch > 1`` fuses whole
-*blocks* of rounds into one XLA program (``run_block``,
-:func:`repro.core.engine.make_fused_rounds`): the threefry key schedule
-moves on device bit-exactly, eval runs at an on-device cadence, and the
-host pays one dispatch + one log sync per R rounds (DESIGN.md §6).
+Every round runs as a block of the fused round program
+(:func:`repro.core.engine.make_fused_rounds`): ``run_round`` is a block
+of one, ``run_block`` a block of ``R`` rounds, and ``run_pipelined``
+double-buffers blocks of ``R`` against the host's log processing
+(DESIGN.md §6-§7).  Client datasets are stacked on device once (ragged,
+e.g. Dirichlet-partitioned, shards are padded and masked, DESIGN.md
+§5); the key schedule is derived on device, and each dispatch costs one
+device->host sync, for its round logs.
 """
 from __future__ import annotations
 
@@ -37,18 +24,14 @@ import time
 from typing import Any, Callable, List, Optional, Sequence, Tuple, Union
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 
 from repro.core import tracing
-from repro.core.client import ClientHP, Task, make_client_update
+from repro.core.client import ClientHP, Task
 from repro.core.comm import BlockTiming, CommMeter
-from repro.core.engine import (BatchedRoundEngine, pipeline_blocks,
-                               task_uses_conv)
+from repro.core.engine import BatchedRoundEngine, pipeline_blocks
 from repro.core.knobs import (DEFAULT_PIPELINE_DEPTH,
-                              DEFAULT_ROUNDS_PER_DISPATCH, ENGINES,
-                              parse_pipeline_blocks,
-                              parse_rounds_per_dispatch, validate_engine)
+                              DEFAULT_ROUNDS_PER_DISPATCH,
+                              parse_rounds_per_dispatch)
 from repro.metaheuristics import REGISTRY, Metaheuristic
 from repro.metaheuristics.base import init_rows
 from repro.models.attention import count_attention_paths
@@ -106,37 +89,25 @@ class PipelineResult:
 class Server:
     """Orchestrates FL rounds over in-process simulated clients.
 
-    ``engine``: "auto" (batched when the client datasets stack — ragged
-    batch counts are padded and masked, DESIGN.md §5 — and the batched
-    traversal is a measured win for the task/backend; on CPU conv tasks
-    stay sequential, see DESIGN.md §4), "batched" (forced), or
-    "sequential".
+    The client datasets must stack (ragged batch counts are padded and
+    masked, DESIGN.md §5): unstackable data or an empty shard raises
+    ``ValueError``.
 
-    ``rounds_per_dispatch``: how many rounds one device dispatch
-    executes (DESIGN.md §6).  1 = the classic one-dispatch-per-round
-    loop; R > 1 fuses blocks of R rounds into a single XLA program via
-    :func:`repro.core.engine.make_fused_rounds` (``run_block``), paying
-    one host round-trip per block.  "auto" resolves to 1 whenever the
-    round engine is sequential (conv tasks on CPU per the §4 policy —
-    there is no batched program to fuse) and to the measured
-    ``knobs.DEFAULT_ROUNDS_PER_DISPATCH`` otherwise.
-
-    ``pipeline_blocks``: double-buffer fused block dispatches against
-    the host-side log processing (``run_pipelined``, DESIGN.md §7).
-    "auto" turns the pipeline on exactly when there is a fused batched
-    block to overlap (batched engine, ``rounds_per_dispatch > 1``);
-    "on"/"off" force it (on the sequential engine "on" degrades to the
-    serial block loop — there is no async dispatch to overlap).
+    ``rounds_per_dispatch``: how many rounds one device dispatch of the
+    drivers executes (DESIGN.md §6).  1 = one block of one round per
+    ``run_round``; R > 1 lets ``run_federated`` drive pipelined blocks
+    of R rounds (``run_pipelined``).  "auto" resolves to
+    ``knobs.DEFAULT_ROUNDS_PER_DISPATCH``.
     """
+
+    engine = "batched"
+    """Constant; the benchmark harness reads it (``rounds_per_call``)."""
 
     def __init__(self, task: Task, strategy: Strategy, hp: ClientHP,
                  client_data: Sequence[Any], rng: jax.Array,
-                 model_bytes: Optional[int] = None, engine: str = "auto",
-                 rounds_per_dispatch: Union[int, str] = 1,
-                 pipeline_blocks: Union[bool, str] = "auto"):
-        validate_engine(engine)
+                 model_bytes: Optional[int] = None,
+                 rounds_per_dispatch: Union[int, str] = 1):
         rpd = parse_rounds_per_dispatch(rounds_per_dispatch)
-        pipe = parse_pipeline_blocks(pipeline_blocks)
         self.task = task
         self.strategy = strategy
         self.hp = hp
@@ -167,41 +138,11 @@ class Server:
         leaves = jax.tree.leaves(self.global_params)
         self._genes = (len(leaves) if hp.subspace
                        else sum(l.size for l in leaves))
-        self._engine: Optional[BatchedRoundEngine] = None
-        if engine != "sequential" and self.n_clients > 0:
-            # measured policy (DESIGN.md §4): on CPU, conv tasks run
-            # faster as per-client dispatches than under any batched
-            # client-axis traversal, so engine="auto" keeps them
-            # sequential; engine="batched" forces the batched engine
-            want = engine == "batched" or not (
-                jax.default_backend() == "cpu"
-                and task_uses_conv(
-                    task, self.global_params,
-                    jax.tree.map(lambda a: a[0], self.client_data[0])))
-            if want:
-                try:
-                    self._engine = BatchedRoundEngine(task, strategy, hp,
-                                                      self.client_data)
-                except ValueError:
-                    if engine == "batched":
-                        raise
-        self.engine = "batched" if self._engine is not None else "sequential"
-        # auto: fuse only where there is a batched round program to fuse
-        # (the §4 conv-on-CPU policy has already resolved to sequential)
-        if rpd is None:
-            rpd = (DEFAULT_ROUNDS_PER_DISPATCH
-                   if self._engine is not None else 1)
-        self.rounds_per_dispatch = rpd
-        # auto: overlap exactly when there is a fused batched block to
-        # overlap; forcing "on" without a batched engine degrades to the
-        # serial block loop inside run_pipelined
-        if pipe is None:
-            pipe = self._engine is not None and rpd > 1
-        self.pipeline_blocks = bool(pipe)
+        self._engine = BatchedRoundEngine(task, strategy, hp,
+                                          self.client_data)
+        self.rounds_per_dispatch = (DEFAULT_ROUNDS_PER_DISPATCH
+                                    if rpd is None else rpd)
         self.rounds_completed = 0
-        self._update = None
-        if self._engine is None:
-            self._update = jax.jit(make_client_update(task, hp, strategy.mh))
         # cache the jitted eval fn once: jax.jit(task.loss_fn) per
         # evaluate() call would re-trace and re-compile every round
         def eval_loss(params, batch):
@@ -219,29 +160,30 @@ class Server:
 
     # ------------------------------------------------------------ round --
     def run_round(self) -> dict:
-        with jax.profiler.TraceAnnotation("Server.run_round"), \
-                self._counting_paths():
-            keys = jax.random.split(self.rng, self.n_clients + 2)
-            self.rng, sel_key, ckeys = keys[0], keys[1], keys[2:]
-            self.rounds_completed += 1
-            if self._engine is not None:
-                return self._run_round_batched(sel_key, ckeys)
-            return self._run_round_sequential(sel_key, ckeys)
+        """Run one round as a block of one, with no evaluation in the
+        program, and return its info dict.  The round is recorded on the
+        meter as a block's rounds are, but adds no ``BlockTiming``: the
+        block ledger holds the fused blocks of ``run_block`` /
+        ``run_pipelined`` only."""
+        with jax.profiler.TraceAnnotation("Server.run_round"):
+            pending = self._dispatch(1)
+            with jax.profiler.TraceAnnotation("Server.run_round.sync"):
+                # the round's single device->host sync
+                out = jax.device_get(pending.logs)
+            return self._record(out, 1, "batched")[0]
 
     def _record_counters(self, participants: Optional[Sequence[int]]
                          = None):
         """Log one round's real and computed local SGD steps: the
-        participants' (default: every client's) valid batches, and on
-        the batched engine every participant's padded row.  Under BWO
-        also log the population rows that the participants' generations
-        and initial draws hashed, against the rows of full draws."""
+        participants' (default: every client's) valid batches, and every
+        participant's padded row.  Under BWO also log the population
+        rows that the participants' generations and initial draws
+        hashed, against the rows of full draws."""
         if participants is None:
             participants = range(self.n_clients)
         epochs = self.hp.local_epochs
         real = epochs * sum(self._client_batches[k] for k in participants)
-        computed = real
-        if self._engine is not None:
-            computed = epochs * len(participants) * self._engine.n_batches
+        computed = epochs * len(participants) * self._engine.n_batches
         self.meter.record_sgd_steps(real, computed)
         mh = self.strategy.mh
         if mh is not None and mh.mutation_rows is not None:
@@ -256,34 +198,19 @@ class Server:
     def run_block(self, n_rounds: Optional[int] = None, eval_data=None,
                   eval_every: int = 1) -> List[dict]:
         """Run ``n_rounds`` (default: ``rounds_per_dispatch``) rounds as
-        ONE fused device dispatch (engine="batched") and return one info
-        dict per round, in ``run_round``'s format plus ``eval_loss`` /
-        ``eval_acc`` entries on rounds the ``eval_every`` cadence (and
-        the block's final round) evaluated on device.
+        ONE fused device dispatch and return one info dict per round, in
+        ``run_round``'s format plus ``eval_loss`` / ``eval_acc`` entries
+        on rounds the ``eval_every`` cadence (and the block's final
+        round) evaluated on device.
 
         The fused program carries ``(global_params, rng)`` across rounds
-        with the server's exact host key schedule derived on device, so
-        a block is bit-identical to ``n_rounds`` ``run_round`` calls —
-        including the CommMeter ledger, reconstructed per round by
+        and derives each round's keys on device, so a block is
+        bit-identical to ``n_rounds`` ``run_round`` calls — including
+        the CommMeter ledger, reconstructed per round by
         ``CommMeter.record_rounds``.  The whole block costs one
-        device->host sync (the stacked round logs).
-
-        On the sequential engine this degrades gracefully to a loop of
-        ``run_round`` + cadenced ``evaluate`` with the same return
-        shape.
+        device->host sync (the stacked round logs).  This is the serial
+        form of ``run_pipelined``.
         """
-        n_rounds = int(n_rounds or self.rounds_per_dispatch)
-        if self._engine is None:
-            infos = []
-            for i in range(n_rounds):
-                info = self.run_round()
-                if eval_data is not None and eval_every > 0 and (
-                        self.rounds_completed % eval_every == 0
-                        or i == n_rounds - 1):
-                    loss, acc = self.evaluate(eval_data)
-                    info["eval_loss"], info["eval_acc"] = loss, acc
-                infos.append(info)
-            return infos
         return self.finish_block(
             self.dispatch_block(n_rounds, eval_data, eval_every))
 
@@ -299,16 +226,17 @@ class Server:
         ``dispatch_block`` enqueue before this block's device execution
         finishes.  Pair with :meth:`finish_block` — in dispatch order —
         to sync the logs, record the meter, and build the info dicts.
-        Requires the batched engine.
         """
-        if self._engine is None:
-            raise RuntimeError(
-                "dispatch_block requires the batched engine; the "
-                "sequential fallback has no async block dispatch to "
-                "pipeline — use run_block, which degrades gracefully")
-        n_rounds = int(n_rounds or self.rounds_per_dispatch)
-        with jax.profiler.TraceAnnotation("Server.dispatch_block"), \
-                self._counting_paths():
+        with jax.profiler.TraceAnnotation("Server.dispatch_block"):
+            return self._dispatch(
+                int(n_rounds or self.rounds_per_dispatch), eval_data,
+                eval_every)
+
+    def _dispatch(self, n_rounds: int, eval_data=None,
+                  eval_every: int = 1) -> PendingBlock:
+        """Enqueue a block of ``n_rounds`` and advance the server's
+        state to its (future) outputs."""
+        with self._counting_paths():
             t0 = time.perf_counter()
             offset = self.rounds_completed
             params, rng, logs = self._engine.run_block(
@@ -329,7 +257,6 @@ class Server:
         append a :class:`~repro.core.comm.BlockTiming` to the meter's
         block ledger.  Its ``sync_s`` and ``process_s`` time exactly the
         ``Server.finish_block.sync`` and ``.process`` spans."""
-        n_rounds = pending.n_rounds
         with jax.profiler.TraceAnnotation("Server.finish_block"):
             t0 = time.perf_counter()
             with jax.profiler.TraceAnnotation("Server.finish_block.sync"):
@@ -337,30 +264,30 @@ class Server:
                 out = jax.device_get(pending.logs)
             t1 = time.perf_counter()
             with jax.profiler.TraceAnnotation("Server.finish_block.process"):
-                for slots in out.get("expert_slots", ()):
-                    self.meter.record_expert_slots(slots)
-                if self.strategy.is_fedx:
-                    self.meter.record_rounds(self.strategy, n_rounds,
-                                             fetched_model=True)
-                    for _ in range(n_rounds):
-                        self._record_counters()
-                else:
-                    self.meter.record_rounds(
-                        self.strategy, n_rounds,
-                        n_participants=self._engine.n_participants)
-                    for sel in out["participants"]:
-                        self._record_counters(sel)
-                infos = self._block_infos(out, n_rounds)
+                infos = self._record(out, pending.n_rounds, "fused")
             t2 = time.perf_counter()
             self.meter.record_block_timing(BlockTiming(
-                n_rounds=n_rounds, dispatch_s=pending.dispatch_s,
+                n_rounds=pending.n_rounds, dispatch_s=pending.dispatch_s,
                 sync_s=t1 - t0, process_s=t2 - t1,
                 total_s=t2 - pending.t_dispatched))
             return infos
 
-    def _block_infos(self, out, n_rounds: int) -> List[dict]:
-        """Host-side reconstruction of ``run_round``-shaped info dicts
-        from a fused block's fetched log arrays."""
+    def _record(self, out, n_rounds: int, engine: str) -> List[dict]:
+        """Record a block's fetched logs on the meter and rebuild its
+        per-round info dicts (``engine`` labels them)."""
+        for slots in out.get("expert_slots", ()):
+            self.meter.record_expert_slots(slots)
+        if self.strategy.is_fedx:
+            self.meter.record_rounds(self.strategy, n_rounds,
+                                     fetched_model=True)
+            for _ in range(n_rounds):
+                self._record_counters()
+        else:
+            self.meter.record_rounds(
+                self.strategy, n_rounds,
+                n_participants=self._engine.n_participants)
+            for sel in out["participants"]:
+                self._record_counters(sel)
         infos = []
         for r in range(n_rounds):
             scores = out["scores"][r]
@@ -368,13 +295,13 @@ class Server:
                 best = int(out["best"][r])
                 info = {"best_client": best, "score": float(scores[best]),
                         "scores": [float(s) for s in scores],
-                        "engine": "fused"}
+                        "engine": engine}
             else:
                 # FedAvg scores align with the participants list
                 info = {"participants": [int(k)
                                          for k in out["participants"][r]],
                         "scores": [float(s) for s in scores],
-                        "engine": "fused"}
+                        "engine": engine}
             if "eval_loss" in out and not math.isnan(
                     float(out["eval_loss"][r])):
                 info["eval_loss"] = float(out["eval_loss"][r])
@@ -407,9 +334,6 @@ class Server:
         multiple of the block size) compiles a second block shape;
         drivers that care (``run_federated``) pass a multiple and run
         leftovers on the single-round path.
-
-        On the sequential engine this degrades to a serial ``run_block``
-        loop: same result shape, no overlap and no overshoot.
         """
         rounds = int(rounds)
         block = int(block_rounds or self.rounds_per_dispatch)
@@ -420,16 +344,6 @@ class Server:
         if stop_fn is not None:
             def should_stop(infos):
                 return any(stop_fn(i) for i in infos)
-        if self._engine is None:
-            infos, stopped = [], False
-            for n in sizes:
-                out = self.run_block(n, eval_data, eval_every)
-                infos.extend(out)
-                if should_stop is not None and should_stop(out):
-                    stopped = True
-                    break
-            return PipelineResult(infos=infos, kept=len(infos),
-                                  stopped=stopped)
         results, kept_blocks, stopped = pipeline_blocks(
             lambda n: self.dispatch_block(n, eval_data, eval_every),
             self.finish_block, sizes, depth=depth,
@@ -438,86 +352,6 @@ class Server:
             infos=[i for blk in results for i in blk],
             kept=sum(len(blk) for blk in results[:kept_blocks]),
             stopped=stopped)
-
-    def _run_round_batched(self, sel_key, ckeys) -> dict:
-        if self.strategy.is_fedx:
-            new_params, scores, best, *slots = self._engine.fedx_round(
-                self.global_params, ckeys)
-            self.global_params = new_params
-            self.meter.record_fedx_round(fetched_model=True)
-            self._record_counters()
-            with jax.profiler.TraceAnnotation("Server.run_round.sync"):
-                # the round's single device->host sync
-                scores, best, slots = jax.device_get((scores, best, slots))
-            for s in slots:
-                self.meter.record_expert_slots(s)
-            best = int(best)
-            return {"best_client": best, "score": float(scores[best]),
-                    "scores": [float(s) for s in scores],
-                    "engine": "batched"}
-        new_params, scores, sel, *slots = self._engine.fedavg_round(
-            self.global_params, sel_key, ckeys)
-        self.global_params = new_params
-        self.meter.record_fedavg_round(self._engine.n_participants)
-        with jax.profiler.TraceAnnotation("Server.run_round.sync"):
-            # the round's single device->host sync; scores align with the
-            # participants list (FedX scores cover all clients)
-            sel, scores, slots = jax.device_get((sel, scores, slots))
-        for s in slots:
-            self.meter.record_expert_slots(s)
-        self._record_counters(sel)
-        return {"participants": [int(k) for k in sel],
-                "scores": [float(s) for s in scores],
-                "engine": "batched"}
-
-    def _run_round_sequential(self, sel_key, ckeys) -> dict:
-        if self.strategy.is_fedx:
-            # every client trains + refines, uploads only its score
-            scores, params_list, slots = [], [], []
-            for k in range(self.n_clients):
-                score, params, *counts = self._update(
-                    self.global_params, self.client_data[k], ckeys[k])
-                scores.append(score)
-                params_list.append(params)
-                slots += counts
-            # one host sync per round, after all clients have dispatched
-            with jax.profiler.TraceAnnotation("Server.run_round.sync"):
-                scores, slots = jax.device_get((jnp.stack(scores), slots))
-                scores = np.asarray(scores)
-            if slots:
-                self.meter.record_expert_slots(sum(slots))
-            best = int(scores.argmin())
-            # GetBestModel: one full-model transfer from the winner only
-            self.global_params = params_list[best]
-            self.meter.record_fedx_round(fetched_model=True)
-            self._record_counters()
-            return {"best_client": best, "score": float(scores[best]),
-                    "scores": [float(s) for s in scores],
-                    "engine": "sequential"}
-        # ---- FedAvg ----
-        m = max(int(self.strategy.client_ratio * self.n_clients), 1)
-        sel = jax.random.choice(sel_key, self.n_clients, (m,), replace=False)
-        scores, new_params, slots = [], [], []
-        for k in sel.tolist():
-            score, params, *counts = self._update(
-                self.global_params, self.client_data[k], ckeys[k])
-            scores.append(score)
-            new_params.append(params)
-            slots += counts
-        self.global_params = jax.tree.map(
-            lambda *xs: jnp.mean(jnp.stack(xs), 0), *new_params)
-        # one host sync for the participants' scores, after all have
-        # dispatched; aligned with the participants list
-        with jax.profiler.TraceAnnotation("Server.run_round.sync"):
-            scores, slots = jax.device_get((jnp.stack(scores), slots))
-            scores = np.asarray(scores)
-        if slots:
-            self.meter.record_expert_slots(sum(slots))
-        self.meter.record_fedavg_round(m)
-        self._record_counters(sel.tolist())
-        return {"participants": sel.tolist(),
-                "scores": [float(s) for s in scores],
-                "engine": "sequential"}
 
     # ------------------------------------------------------------- eval --
     def evaluate(self, eval_data) -> Tuple[float, float]:

@@ -15,8 +15,7 @@ from repro.analysis.audit import (AuditContext, ProgramSubject,
                                   audit_experiment, collect_subjects)
 from repro.analysis.pylint_jax import lint_source
 from repro.analysis.rules import (RULES, check_cache_stability,
-                                  check_conv_policy, check_donation,
-                                  run_rules)
+                                  check_donation, run_rules)
 from repro.core.api import FLConfig, build_experiment
 from repro.core.knobs import parse_audit
 from repro.launch.hlo_analysis import (count_host_transfers,
@@ -36,9 +35,9 @@ def _subject(fn, *args, name="prog", compile=True, **kw):
         **kw)
 
 
-def _ctx(*subjects, backend="cpu", engine="batched"):
+def _ctx(*subjects, backend="cpu"):
     return AuditContext(subjects=list(subjects), backend=backend,
-                        engine=engine, strategy="fedbwo", task="mlp")
+                        strategy="fedbwo", task="mlp")
 
 
 def _with_callback(x):
@@ -227,27 +226,6 @@ def test_callback_outside_loop_passes_scan_rule():
                                  only=("no-host-callback-in-scan",)))
 
 
-# -------------------------------------------------------------- conv-policy
-
-def test_conv_policy_bad_combo():
-    errs = _errors(check_conv_policy(True, "cpu", "batched"))
-    assert errs and "sequential" in errs[0].message
-    for combo in ((False, "cpu", "batched"), (True, "gpu", "batched"),
-                  (True, "cpu", "sequential")):
-        assert not _errors(check_conv_policy(*combo))
-
-
-def test_conv_policy_rule_sees_conv_primitive():
-    def convf(x, k):
-        return jax.lax.conv_general_dilated(x, k, (1, 1), "SAME")
-    s = _subject(convf, jnp.zeros((1, 1, 8, 8), jnp.float32),
-                 jnp.zeros((1, 1, 3, 3), jnp.float32), compile=False,
-                 is_round=True)
-    assert _errors(run_rules(_ctx(s), only=("conv-policy",)))
-    assert not _errors(run_rules(_ctx(s, engine="sequential"),
-                                 only=("conv-policy",)))
-
-
 # -------------------------------------------------- compile-cache-stability
 
 def test_cache_stability_known_bad():
@@ -347,8 +325,7 @@ def _small_cfg(**kw):
 
 
 def test_e2e_fused_pipelined_mlp_build_audits_clean():
-    exp = build_experiment(_small_cfg(rounds_per_dispatch=3,
-                                      pipeline_blocks="on"))
+    exp = build_experiment(_small_cfg(rounds_per_dispatch=3))
     report = audit_experiment(exp)
     assert report.ok, report.render()
     names = {f.subject for f in report.findings}
@@ -379,13 +356,14 @@ def test_audit_strict_raises_on_error(monkeypatch):
         audit_experiment(exp, compile=False, lint=False, strict=True)
 
 
-def test_collect_subjects_sequential_engine():
-    exp = build_experiment(_small_cfg(engine="sequential"))
+def test_collect_subjects_follow_the_server():
+    """A server with single-round dispatches builds the block of one
+    only: it is the one round program audited, besides eval."""
+    exp = build_experiment(_small_cfg())
     subjects = collect_subjects(exp.server, eval_data=exp.eval_data,
                                 compile=False)
-    names = {s.name for s in subjects}
-    assert any(n.startswith("client_update[") for n in names)
-    assert "eval" in names
+    assert {s.name for s in subjects} == {"round[fedbwo]", "eval"}
+    assert all(s.jaxpr is not None for s in subjects)
 
 
 def test_cli_strict_exits_zero_on_main():

@@ -7,7 +7,8 @@ import pytest
 
 from repro.core import (ClientHP, FLConfig, build_experiment,
                         normalized_cost)
-from repro.core.knobs import (parse_vectorize, validate_engine,
+from repro.core.knobs import (parse_vectorize,
+                              validate_rounds_per_dispatch,
                               validate_vectorize)
 from repro.data.loader import batch_dataset
 from repro.data.partition import partition_iid
@@ -22,23 +23,23 @@ def test_parse_vectorize():
     assert parse_vectorize("auto:2") == ("auto", 2)
     assert parse_vectorize("vmap") == ("vmap", 1)
     for bad in ("bogus", "scan:0", "scan:-1", "scan:x", "vmap:2",
-                "unroll:3"):
+                "unroll", "unroll:3"):
         with pytest.raises(ValueError):
             parse_vectorize(bad)
 
 
 def test_validators_round_trip():
-    assert validate_engine("batched") == "batched"
+    assert validate_rounds_per_dispatch("auto") == "auto"
     assert validate_vectorize("scan:8") == "scan:8"
     with pytest.raises(ValueError):
-        validate_engine("turbo")
+        validate_rounds_per_dispatch("turbo")
     with pytest.raises(ValueError):
         validate_vectorize("scan:")
 
 
 # ---------------------------------------------------------- FLConfig --
 @pytest.mark.parametrize("bad", [
-    {"engine": "turbo"},
+    {"rounds_per_dispatch": 0},
     {"vectorize": "bogus"},
     {"vectorize": "vmap:2"},
     {"task": "resnet"},
@@ -64,15 +65,13 @@ def test_flconfig_derives_hp_and_stop():
 
 
 def test_build_experiment_smoke_mlp():
-    """End-to-end through the facade on the dense task: batched engine,
-    extended CommMeter summary, meter-based normalized cost."""
+    """End-to-end through the facade on the dense task: extended
+    CommMeter summary, meter-based normalized cost."""
     cfg = FLConfig(strategy="fedbwo", task="mlp", n_clients=3,
                    n_train=120, n_test=40, batch_size=10,
                    local_epochs=1, mh_pop=2, mh_generations=1,
                    max_rounds=1, tau=0.99)
     exp = build_experiment(cfg)
-    if jax.default_backend() == "cpu":
-        assert exp.server.engine == "batched"     # mlp is conv-free
     result = exp.run()
     s = result.summary()
     assert s["strategy"] == "fedbwo" and s["rounds"] == 1

@@ -15,8 +15,10 @@ SCRIPT = textwrap.dedent("""
     n_devices = int(sys.argv[1])
     jax.config.update("jax_num_cpu_devices", n_devices)
     from repro.core.client import Task, ClientHP, make_client_update
-    from repro.core.distributed import make_fedx_round, make_fedavg_round
-    from repro.core.engine import make_batched_fedx_round
+    from repro.core.engine import (make_fused_rounds,
+                                   make_sharded_fedavg_round,
+                                   make_sharded_fedx_round)
+    from repro.core.server import get_strategy
     from repro.launch.mesh import make_host_mesh
     from repro.metaheuristics import bwo
 
@@ -42,7 +44,7 @@ SCRIPT = textwrap.dedent("""
     keys = jax.vmap(jax.random.key_data)(jax.random.split(rng, N))
 
     # --- FedX: winner weights adopted identically on all clients ---
-    rnd = make_fedx_round(task, hp, bwo(), mesh)
+    rnd = make_sharded_fedx_round(task, hp, bwo(), mesh)
     params = task.init_params(rng)
     s_prev = None
     for r in range(4):
@@ -54,11 +56,17 @@ SCRIPT = textwrap.dedent("""
     assert np.isfinite(s), s
 
     # every client of every shard trains: scores and the adopted winner
-    # match the single-device batched round on the same data and keys
+    # match the single-device block of one on the same data, with the
+    # client keys the block derives from its rng
     p0 = task.init_params(rng)
-    got, got_scores = rnd(p0, data, keys)
-    want, want_scores, _ = make_batched_fedx_round(task, hp, bwo())(
-        p0, data, None, keys)
+    round_key = jax.random.PRNGKey(5)
+    got, got_scores = rnd(p0, data,
+                          jax.random.split(round_key, N + 2)[2:])
+    block = make_fused_rounds(task, get_strategy("fedbwo"), hp, 1,
+                              n_clients=N)
+    want, _, logs = block(p0, round_key, data, None, None,
+                          jnp.asarray(0, jnp.int32))
+    want_scores = logs["scores"][0]
     assert got_scores.shape == (N,), got_scores.shape
     np.testing.assert_allclose(np.asarray(got_scores),
                                np.asarray(want_scores), rtol=1e-5)
@@ -67,7 +75,7 @@ SCRIPT = textwrap.dedent("""
                                    rtol=1e-5, atol=1e-6)
 
     # --- FedAvg: averaged weights identical to manual mean ---
-    rnd2 = make_fedavg_round(task, hp, mesh)
+    rnd2 = make_sharded_fedavg_round(task, hp, mesh)
     p0 = task.init_params(rng)
     pavg, scores2 = rnd2(p0, data, keys)
     manual = []

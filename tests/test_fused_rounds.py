@@ -1,8 +1,8 @@
 """Multi-round fusion: R rounds scanned into one dispatch (DESIGN.md §6).
 
-Parity here is BIT-exact, not approximate: the fused block derives the
-server's host-side threefry key schedule on device, so R fused rounds
-must reproduce R individual ``run_round`` calls bit for bit — global
+Parity here is BIT-exact, not approximate: every block derives its
+rounds' threefry key schedule on device, so a block of R rounds must
+reproduce R ``run_round`` calls (R blocks of one) bit for bit — global
 params, scores, winner indices / participant sets, the PRNG carry, and
 the CommMeter ledger.
 """
@@ -21,7 +21,7 @@ from repro.core.protocol import StopConditions, run_federated
 from repro.data.loader import batch_dataset
 from repro.data.partition import partition_dirichlet
 
-from conftest import make_toy_data, make_toy_task
+from conftest import ReferenceRounds, make_toy_data, make_toy_task
 
 N_CLIENTS = 5
 R = 5
@@ -41,7 +41,7 @@ def _hp():
 
 def _server(strategy, clients, rounds_per_dispatch=1, task=None, **kw):
     return Server(task or make_toy_task(), get_strategy(strategy, **kw),
-                  _hp(), clients, jax.random.PRNGKey(3), engine="batched",
+                  _hp(), clients, jax.random.PRNGKey(3),
                   rounds_per_dispatch=rounds_per_dispatch)
 
 
@@ -54,8 +54,9 @@ def _assert_trees_bitexact(a, b):
                                          ("fedavg", {}),
                                          ("fedavg", {"client_ratio": 0.6})])
 def test_fused_block_bitexact_vs_single_rounds(strategy, kw):
-    """One R-round fused dispatch == R run_round calls, bit for bit:
-    params, scores, winners/participants, and the CommMeter ledger."""
+    """One R-round fused dispatch == R run_round calls (blocks of one),
+    bit for bit: params, scores, winners/participants, and the
+    CommMeter ledger."""
     clients = _clients()
     single = _server(strategy, clients, **kw)
     fused = _server(strategy, clients, rounds_per_dispatch=R, **kw)
@@ -157,9 +158,10 @@ def test_on_device_eval_cadence():
 
 def test_run_federated_fused_driver_matches_unfused():
     """End-to-end through run_federated: same accuracy curve and round
-    count with rounds_per_dispatch=R as with 1 (tau high enough that no
-    early stop hits, so block atomicity doesn't change the trajectory);
-    leftover rounds (max_rounds % R) run on the single-round path."""
+    count with rounds_per_dispatch=R (pipelined blocks) as with 1 (tau
+    high enough that no early stop hits, so block atomicity doesn't
+    change the trajectory); leftover rounds (max_rounds % R) run on the
+    single-round path."""
     clients = _clients()
     test = make_toy_data(jax.random.PRNGKey(7), 100)
     stop = StopConditions(max_rounds=7, patience=100, tau=1.1)
@@ -171,7 +173,7 @@ def test_run_federated_fused_driver_matches_unfused():
     for a, b in zip(logs[1], logs[R]):
         assert math.isclose(a.test_acc, b.test_acc, rel_tol=1e-6)
         assert math.isclose(a.test_loss, b.test_loss, rel_tol=1e-6)
-    # the 2 leftover rounds fall back to per-round dispatches
+    # the 2 leftover rounds run as blocks of one
     assert [l.info["engine"] for l in logs[R]] == \
         ["fused"] * 5 + ["batched"] * 2
 
@@ -199,45 +201,30 @@ def test_rounds_per_dispatch_knob():
     clients = _clients()
     auto = _server("fedbwo", clients, rounds_per_dispatch="auto")
     assert auto.rounds_per_dispatch == DEFAULT_ROUNDS_PER_DISPATCH
-    seq = Server(make_toy_task(), get_strategy("fedbwo"), _hp(), clients,
-                 jax.random.PRNGKey(3), engine="sequential",
-                 rounds_per_dispatch="auto")
-    assert seq.rounds_per_dispatch == 1    # nothing batched to fuse
-
-
-def test_sequential_run_block_fallback():
-    """run_block on the sequential engine degrades to a run_round loop
-    with the same info-dict shape (uniform caller API)."""
-    clients = _clients()
-    test = make_toy_data(jax.random.PRNGKey(7), 100)
-    seq = Server(make_toy_task(), get_strategy("fedbwo"), _hp(), clients,
-                 jax.random.PRNGKey(3), engine="sequential")
-    infos = seq.run_block(3, eval_data=test, eval_every=2)
-    assert len(infos) == 3
-    assert [("eval_acc" in i) for i in infos] == [False, True, True]
-    assert len(seq.meter.uplink) == 3
+    assert _server("fedbwo", clients).rounds_per_dispatch == 1
 
 
 @pytest.mark.parametrize("ratio", [1.0, 0.6])
 def test_fedavg_scores_parity_across_engines(ratio):
-    """FedAvg infos carry per-participant scores on the sequential,
-    batched single-round, AND fused block paths — the fused engine used
-    to drop them on the host side even though the device computed them."""
+    """FedAvg infos carry per-participant scores on the reference loop,
+    the block of one AND the block of R — the fused engine used to drop
+    them on the host side even though the device computed them."""
     clients = _clients()
-    seq = Server(make_toy_task(), get_strategy("fedavg", client_ratio=ratio),
-                 _hp(), clients, jax.random.PRNGKey(3), engine="sequential")
+    ref = ReferenceRounds(make_toy_task(),
+                          get_strategy("fedavg", client_ratio=ratio),
+                          _hp(), clients, jax.random.PRNGKey(3))
     single = _server("fedavg", clients, client_ratio=ratio)
     fused = _server("fedavg", clients, rounds_per_dispatch=R,
                     client_ratio=ratio)
-    infos_seq = [seq.run_round() for _ in range(R)]
+    infos_ref = [ref.run_round() for _ in range(R)]
     infos_s = [single.run_round() for _ in range(R)]
     infos_f = fused.run_block(R)
-    for a, b, c in zip(infos_seq, infos_s, infos_f):
+    for a, b, c in zip(infos_ref, infos_s, infos_f):
         assert a["participants"] == b["participants"] == c["participants"]
         for info in (a, b, c):
             assert len(info["scores"]) == len(info["participants"])
             assert all(isinstance(s, float) for s in info["scores"])
-        # batched single-round and fused are the same device program ->
-        # bit-exact; sequential differs only by reduction order
+        # blocks of one and of R run the same round body -> bit-exact;
+        # the reference loop differs only by reduction order
         assert b["scores"] == c["scores"]
         np.testing.assert_allclose(a["scores"], b["scores"], rtol=1e-5)

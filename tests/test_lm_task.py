@@ -78,13 +78,11 @@ def test_lm_round_runs_from_the_config_alone(tiny_arch):
     assert s["max_over_mean"] >= 1.0
 
 
-@pytest.mark.parametrize("other", [dict(engine="sequential"),
-                                   dict(rounds_per_dispatch=2)])
-def test_every_engine_counts_the_same_slots(tiny_arch, other):
-    """The sequential loop and a fused block record the batched round's
-    slots and scores."""
+def test_every_engine_counts_the_same_slots(tiny_arch):
+    """A fused block of two rounds records the slots and scores of two
+    blocks of one."""
     runs = [build_experiment(_cfg(tiny_arch, **kw)).run()
-            for kw in ({}, other)]
+            for kw in ({}, dict(rounds_per_dispatch=2))]
     a, b = (r.server.meter.expert_slots for r in runs)
     assert len(a) == len(b) == ROUNDS
     for x, y in zip(a, b):
@@ -114,10 +112,11 @@ def test_model_scopes_change_no_operation(tiny_arch, monkeypatch):
     def round_of():
         server = build_experiment(_cfg(tiny_arch)).server
         eng = server._engine
-        keys = jax.random.split(jax.random.PRNGKey(1), CLIENTS)
-        args = (server.global_params, eng.data, eng.mask, keys)
-        text = eng._round.lower(*args).compile().as_text()
-        return text, jax.device_get(eng._round(*args))
+        block = eng.fused_rounds(1)
+        args = (server.global_params, server.rng, eng.data, eng.mask, None,
+                jnp.asarray(0, jnp.int32))
+        text = block.lower(*args).compile().as_text()
+        return text, jax.device_get(block(*args))
 
     text, out = round_of()
     paths = re.findall(r'op_name="([^"]*)"', text)

@@ -18,9 +18,7 @@ from repro.core import (ClientHP, Server, Task, get_strategy,
                         stack_clients)
 from repro.core.engine import (BatchedRoundEngine, _donate_argnums,
                                pipeline_blocks)
-from repro.core.knobs import (DEFAULT_PIPELINE_DEPTH,
-                              parse_pipeline_blocks,
-                              validate_pipeline_blocks)
+from repro.core.knobs import DEFAULT_PIPELINE_DEPTH
 from repro.core.protocol import StopConditions, run_federated
 from repro.data.loader import batch_dataset
 from repro.data.partition import partition_dirichlet, partition_iid
@@ -42,12 +40,10 @@ def _hp():
                     fitness_batches=2)
 
 
-def _server(strategy, clients, rounds_per_dispatch=R, task=None,
-            pipeline="auto", **kw):
+def _server(strategy, clients, rounds_per_dispatch=R, task=None, **kw):
     return Server(task or make_toy_task(), get_strategy(strategy, **kw),
-                  _hp(), clients, jax.random.PRNGKey(3), engine="batched",
-                  rounds_per_dispatch=rounds_per_dispatch,
-                  pipeline_blocks=pipeline)
+                  _hp(), clients, jax.random.PRNGKey(3),
+                  rounds_per_dispatch=rounds_per_dispatch)
 
 
 def _assert_trees_bitexact(a, b):
@@ -131,8 +127,8 @@ def test_run_pipelined_bitexact_vs_serial_run_block(strategy, kw):
     ledger + per-round kinds."""
     clients = _clients()
     test = make_toy_data(jax.random.PRNGKey(7), 100)
-    serial = _server(strategy, clients, pipeline=False, **kw)
-    piped = _server(strategy, clients, pipeline=True, **kw)
+    serial = _server(strategy, clients, **kw)
+    piped = _server(strategy, clients, **kw)
     infos_s = []
     for _ in range(3):
         infos_s += serial.run_block(R, eval_data=test, eval_every=2)
@@ -174,9 +170,8 @@ def test_run_pipelined_bitexact_on_ragged_dirichlet():
                                 {"x": raw["x"], "labels": raw["y"]},
                                 4, alpha=0.5, num_classes=3)
     clients = [batch_dataset(p, 8) for p in parts]
-    serial = _server("fedbwo", clients, task=labeled_task(),
-                     pipeline=False)
-    piped = _server("fedbwo", clients, task=labeled_task(), pipeline=True)
+    serial = _server("fedbwo", clients, task=labeled_task())
+    piped = _server("fedbwo", clients, task=labeled_task())
     assert piped._engine.padded
     infos_s = serial.run_block(R) + serial.run_block(R)
     res = piped.run_pipelined(2 * R)
@@ -191,7 +186,7 @@ def test_run_pipelined_stopping_overshoot():
     overshoot) but ``kept`` trims the returned logs at block k's end."""
     clients = _clients()
     test = make_toy_data(jax.random.PRNGKey(7), 100)
-    server = _server("fedbwo", clients, pipeline=True)
+    server = _server("fedbwo", clients)
     res = server.run_pipelined(4 * R, eval_data=test, eval_every=1,
                                stop_fn=lambda info: True)
     assert res.stopped
@@ -201,37 +196,26 @@ def test_run_pipelined_stopping_overshoot():
     assert len(server.meter.uplink) == 2 * R
 
 
-def test_run_pipelined_sequential_fallback_no_overshoot():
-    """On the sequential engine run_pipelined degrades to a serial
-    run_block loop: same results, no in-flight overshoot."""
-    clients = _clients()
-    seq = Server(make_toy_task(), get_strategy("fedbwo"), _hp(), clients,
-                 jax.random.PRNGKey(3), engine="sequential")
-    assert seq.pipeline_blocks is False  # auto: nothing to overlap
-    res = seq.run_pipelined(6, block_rounds=3,
-                            stop_fn=lambda info: True)
-    assert res.stopped
-    assert res.kept == len(res.infos) == 3
-    assert seq.rounds_completed == 3
-
-
 def test_run_federated_pipelined_matches_serial_fused():
-    """End-to-end: the pipelined driver's logs match the serial fused
-    driver's round for round (tau never triggers)."""
+    """End-to-end: the pipelined driver's logs match a serial run_block
+    loop with the same leftover single rounds, round for round (tau
+    never triggers)."""
     clients = _clients()
     test = make_toy_data(jax.random.PRNGKey(7), 100)
     stop = StopConditions(max_rounds=12, patience=100, tau=1.1)
-    logs = {}
-    for pipe in (False, True):
-        server = _server("fedbwo", clients, pipeline=pipe)
-        logs[pipe] = run_federated(server, test, stop)
-    assert len(logs[False]) == len(logs[True]) == 12
-    for a, b in zip(logs[False], logs[True]):
-        assert a.round == b.round
-        assert a.test_acc == b.test_acc or (
-            math.isnan(a.test_acc) and math.isnan(b.test_acc))
-        assert a.test_loss == b.test_loss or (
-            math.isnan(a.test_loss) and math.isnan(b.test_loss))
+    piped = _server("fedbwo", clients)
+    logs = run_federated(piped, test, stop)
+    serial = _server("fedbwo", clients)
+    want = [(i.get("eval_loss"), i.get("eval_acc"))
+            for i in serial.run_block(R, test) + serial.run_block(R, test)]
+    for _ in range(2):       # the leftover rounds run as blocks of one
+        serial.run_round()
+        want.append(serial.evaluate(test))
+    assert len(logs) == len(want) == 12
+    for rnd, (log, (loss, acc)) in enumerate(zip(logs, want)):
+        assert log.round == rnd
+        assert (log.test_loss, log.test_acc) == (loss, acc)
+    _assert_trees_bitexact(serial.global_params, piped.global_params)
 
 
 def test_run_federated_pipelined_trims_overshoot_from_logs():
@@ -239,8 +223,7 @@ def test_run_federated_pipelined_trims_overshoot_from_logs():
     block even though the in-flight block ran (and is accounted)."""
     clients = _clients()
     test = make_toy_data(jax.random.PRNGKey(7), 100)
-    server = _server("fedbwo", clients, rounds_per_dispatch=2,
-                     pipeline=True)
+    server = _server("fedbwo", clients, rounds_per_dispatch=2)
     stop = StopConditions(max_rounds=20, patience=1000, tau=0.0)
     logs = run_federated(server, test, stop)
     assert len(logs) == 2                       # triggering block only
@@ -248,35 +231,25 @@ def test_run_federated_pipelined_trims_overshoot_from_logs():
     assert len(server.meter.uplink) == 4
 
 
-# ------------------------------------------------------------ knob + auto
-
-def test_pipeline_blocks_knob():
-    assert parse_pipeline_blocks("auto") is None
-    assert parse_pipeline_blocks(None) is None
-    assert parse_pipeline_blocks(True) is True
-    assert parse_pipeline_blocks("on") is True
-    assert parse_pipeline_blocks("off") is False
-    assert parse_pipeline_blocks(False) is False
-    for bad in ("maybe", 2, 1.5):
-        with pytest.raises(ValueError):
-            validate_pipeline_blocks(bad)
-    assert DEFAULT_PIPELINE_DEPTH == 2
-
+# ---------------------------------------------------------- derived rule
 
 def test_pipeline_blocks_auto_resolution():
+    """run_federated pipelines exactly when rounds_per_dispatch R > 1
+    and a full block of R remains: R = 1 runs single rounds (no block
+    timing), R = 2 over 5 rounds runs two pipelined blocks and one
+    leftover single round."""
     clients = _clients()
-    # batched + fused blocks -> auto pipelines
-    assert _server("fedbwo", clients).pipeline_blocks is True
-    # rpd=1: nothing to overlap
-    assert _server("fedbwo", clients,
-                   rounds_per_dispatch=1).pipeline_blocks is False
-    # explicit off wins
-    assert _server("fedbwo", clients,
-                   pipeline="off").pipeline_blocks is False
-    seq = Server(make_toy_task(), get_strategy("fedbwo"), _hp(), clients,
-                 jax.random.PRNGKey(3), engine="sequential",
-                 rounds_per_dispatch="auto")
-    assert seq.pipeline_blocks is False
+    test = make_toy_data(jax.random.PRNGKey(7), 100)
+    stop = StopConditions(max_rounds=5, patience=100, tau=1.1)
+    single = _server("fedbwo", clients, rounds_per_dispatch=1)
+    logs = run_federated(single, test, stop)
+    assert single.meter.block_timings == []
+    assert [l.info["engine"] for l in logs] == ["batched"] * 5
+    piped = _server("fedbwo", clients, rounds_per_dispatch=2)
+    logs = run_federated(piped, test, stop)
+    assert [t.n_rounds for t in piped.meter.block_timings] == [2, 2]
+    assert [l.info["engine"] for l in logs] == ["fused"] * 4 + ["batched"]
+    assert DEFAULT_PIPELINE_DEPTH == 2
 
 
 def test_block_timing_ledger():
@@ -284,7 +257,7 @@ def test_block_timing_ledger():
     fields; summary() stays byte-ledger-only (fused parity tests compare
     it across engines)."""
     clients = _clients()
-    server = _server("fedbwo", clients, pipeline=True)
+    server = _server("fedbwo", clients)
     server.run_pipelined(2 * R)
     ts = server.meter.block_timings
     assert len(ts) == 2
@@ -314,11 +287,11 @@ def test_server_rejects_empty_client_shard():
 
 
 def test_stack_clients_zero_length_shard_masks_out():
-    """stack_clients(pad=True) represents a zero-batch shard as an
-    all-False mask row instead of crashing."""
+    """stack_clients represents a zero-batch shard as an all-False mask
+    row instead of crashing."""
     clients = _clients(n_clients=3)
     clients[1] = jax.tree.map(lambda a: a[:0], clients[1])
-    stacked, mask = stack_clients(clients, pad=True)
+    stacked, mask = stack_clients(clients)
     assert stacked is not None
     assert not bool(mask[1].any())
     assert bool(mask[0].all()) and bool(mask[2].all())

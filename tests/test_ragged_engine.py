@@ -1,10 +1,11 @@
 """Pad+mask batching on Dirichlet (ragged) partitions and the FedAvg
-sample-then-stack compile-cache policy (DESIGN.md §5).
+participant-count compile-cache policy (DESIGN.md §5).
 
-Parity is exact, not just approximate: the masked client update skips
-padded batches' SGD steps AND holds the PRNG carry so the per-batch key
-sequence matches the unpadded sequential run, and the fitness slice
-replicates the sequential clamp-indexing semantics for short clients.
+Parity with the unpadded per-client loop (``conftest.ReferenceRounds``)
+holds because the masked client update skips padded batches' SGD steps
+AND holds the PRNG carry, so the per-batch key sequence matches the
+unpadded run, and the fitness slice replicates the unpadded
+clamp-indexing semantics for short clients.
 """
 import jax
 import jax.numpy as jnp
@@ -15,7 +16,7 @@ from repro.core import ClientHP, Server, Task, get_strategy
 from repro.data.loader import batch_dataset
 from repro.data.partition import partition_dirichlet
 
-from conftest import make_toy_data
+from conftest import ReferenceRounds, make_toy_data
 
 N_CLIENTS = 4
 CLASSES = 3
@@ -50,9 +51,11 @@ def _dirichlet_clients(n: int = 480, batch: int = 8):
 def _servers(strategy, clients, **kw):
     hp = ClientHP(local_epochs=1, mh_pop=4, mh_generations=2, lr=0.05,
                   fitness_batches=2)
-    return {e: Server(_labeled_toy_task(), get_strategy(strategy, **kw),
-                      hp, clients, jax.random.PRNGKey(3), engine=e)
-            for e in ("sequential", "batched")}
+    strat = get_strategy(strategy, **kw)
+    return {"reference": ReferenceRounds(_labeled_toy_task(), strat, hp,
+                                         clients, jax.random.PRNGKey(3)),
+            "batched": Server(_labeled_toy_task(), strat, hp, clients,
+                              jax.random.PRNGKey(3))}
 
 
 @pytest.mark.parametrize("strategy,kw", [("fedbwo", {}),
@@ -60,8 +63,8 @@ def _servers(strategy, clients, **kw):
                                          ("fedavg", {"client_ratio": 0.5})])
 def test_dirichlet_parity(strategy, kw):
     """Identical winners/scores/participants, CommMeter bytes, and
-    global weights between the masked batched engine and the sequential
-    loop on a label-skewed (ragged) partition."""
+    global weights between the masked round program and the per-client
+    reference loop on a label-skewed (ragged) partition."""
     clients = _dirichlet_clients()
     lens = [jax.tree.leaves(c)[0].shape[0] for c in clients]
     assert len(set(lens)) > 1, f"partition not ragged: {lens}"
@@ -70,17 +73,17 @@ def test_dirichlet_parity(strategy, kw):
     assert servers["batched"]._engine.padded
     infos = {e: [s.run_round() for _ in range(2)]
              for e, s in servers.items()}
-    seq, bat = servers["sequential"], servers["batched"]
-    assert seq.meter.uplink == bat.meter.uplink
-    assert seq.meter.downlink == bat.meter.downlink
-    assert seq.meter.summary() == bat.meter.summary()
-    for a, b in zip(infos["sequential"], infos["batched"]):
+    ref, bat = servers["reference"], servers["batched"]
+    assert ref.meter.uplink == bat.meter.uplink
+    assert ref.meter.downlink == bat.meter.downlink
+    assert ref.meter.summary() == bat.meter.summary()
+    for a, b in zip(infos["reference"], infos["batched"]):
         if strategy == "fedbwo":
             assert a["best_client"] == b["best_client"]
             np.testing.assert_allclose(a["scores"], b["scores"], rtol=1e-4)
         else:
             assert a["participants"] == b["participants"]
-    for x, y in zip(jax.tree.leaves(seq.global_params),
+    for x, y in zip(jax.tree.leaves(ref.global_params),
                     jax.tree.leaves(bat.global_params)):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y),
                                    rtol=1e-4, atol=1e-5)
@@ -98,7 +101,7 @@ def test_fedavg_sample_then_stack_compiles_for_m():
     hp = ClientHP(local_epochs=1, mh_pop=2, mh_generations=1, lr=0.05)
     server = Server(_labeled_toy_task(), get_strategy(
         "fedavg", client_ratio=0.5), hp, clients,
-        jax.random.PRNGKey(3), engine="batched")
+        jax.random.PRNGKey(3))
     eng = server._engine
     assert eng.n_participants == 3 and eng.n_clients == 6
     for _ in range(3):

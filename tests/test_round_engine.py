@@ -1,9 +1,11 @@
-"""Batched round engine vs the sequential per-client loop.
+"""The round program against the per-client reference loop.
 
-Parity: identical CommMeter byte accounting and numerically-close
-scores/weights for FedBWO and FedAvg on a tiny synthetic task.
-Memory shape: the FedX batched scan path never materializes an
-(n_clients, n_params) weights array.
+``Server.run_round`` runs a block of one of the fused round program;
+``conftest.ReferenceRounds`` runs the same round as a loop of jitted
+per-client updates.  Parity: identical CommMeter byte accounting,
+winners and participants, and numerically-close scores/weights.
+Memory shape: the FedX scan path never materializes an (n_clients,
+n_params) weights array.
 """
 import jax
 import jax.numpy as jnp
@@ -12,51 +14,103 @@ import pytest
 from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from repro.core import ClientHP, Server, get_strategy
-from repro.core.engine import (BatchedRoundEngine, make_batched_fedx_round,
-                               resolve_vectorize, stack_clients)
+from repro.core.engine import (_fedx_round_body, resolve_vectorize,
+                               stack_clients)
 from repro.data.loader import batch_dataset
 from repro.data.partition import partition_iid
 from repro.metaheuristics import bwo
 
-from conftest import make_toy_data, make_toy_task
+from conftest import ReferenceRounds, make_toy_data, make_toy_task
 
 N_CLIENTS = 5
 
 
-def _servers(strategy, engines=("sequential", "batched"), **kw):
+def _iid_clients(n=400, n_clients=N_CLIENTS):
+    data = make_toy_data(jax.random.PRNGKey(0), n)
+    return [batch_dataset(d, 8) for d in
+            partition_iid(jax.random.PRNGKey(1), data, n_clients)]
+
+
+def _ragged_clients(sizes=(48, 24, 72, 16, 40)):
+    raw = make_toy_data(jax.random.PRNGKey(0), sum(sizes))
+    edges = np.cumsum((0,) + tuple(sizes))
+    return [batch_dataset(jax.tree.map(lambda a: a[lo:hi], raw), 8)
+            for lo, hi in zip(edges[:-1], edges[1:])]
+
+
+def _pair(strategy, clients, vectorize="auto", **kw):
+    """A server and the reference loop, from the same task, data and
+    key."""
     task = make_toy_task()
-    data = make_toy_data(jax.random.PRNGKey(0), 400)
-    clients = [batch_dataset(d, 8) for d in
-               partition_iid(jax.random.PRNGKey(1), data, N_CLIENTS)]
     hp = ClientHP(local_epochs=1, mh_pop=4, mh_generations=2, lr=0.05,
-                  fitness_batches=2)
-    return {e: Server(task, get_strategy(strategy, **kw), hp, clients,
-                      jax.random.PRNGKey(3), engine=e) for e in engines}
+                  fitness_batches=2, vectorize=vectorize)
+    strat = get_strategy(strategy, **kw)
+    return (Server(task, strat, hp, clients, jax.random.PRNGKey(3)),
+            ReferenceRounds(task, strat, hp, clients,
+                            jax.random.PRNGKey(3)))
+
+
+def _assert_rounds_match(a, b, strategy):
+    """``a`` from the reference loop, ``b`` from the server."""
+    if strategy == "fedbwo":
+        assert a["best_client"] == b["best_client"]
+    else:
+        assert a["participants"] == b["participants"]
+    np.testing.assert_allclose(a["scores"], b["scores"], rtol=1e-4)
+
+
+def _assert_params_close(x, y):
+    for a, b in zip(jax.tree.leaves(x), jax.tree.leaves(y)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-5)
 
 
 @pytest.mark.parametrize("strategy,kw", [("fedbwo", {}),
                                          ("fedavg", {}),
                                          ("fedavg", {"client_ratio": 0.6})])
 def test_engine_parity(strategy, kw):
-    servers = _servers(strategy, **kw)
-    infos = {e: [s.run_round() for _ in range(2)]
-             for e, s in servers.items()}
-    seq, bat = servers["sequential"], servers["batched"]
-    assert seq.engine == "sequential" and bat.engine == "batched"
+    bat, ref = _pair(strategy, _iid_clients(), **kw)
+    infos = [(ref.run_round(), bat.run_round()) for _ in range(2)]
+    assert bat.engine == "batched"
     # identical byte accounting (the paper's Eqs. 1-2 per round)
-    assert seq.meter.uplink == bat.meter.uplink
-    assert seq.meter.downlink == bat.meter.downlink
-    assert seq.meter.total == bat.meter.total
-    for a, b in zip(infos["sequential"], infos["batched"]):
-        if strategy == "fedbwo":
-            assert a["best_client"] == b["best_client"]
-            np.testing.assert_allclose(a["scores"], b["scores"], rtol=1e-4)
-        else:
-            assert a["participants"] == b["participants"]
-    for x, y in zip(jax.tree.leaves(seq.global_params),
-                    jax.tree.leaves(bat.global_params)):
-        np.testing.assert_allclose(np.asarray(x), np.asarray(y),
-                                   rtol=1e-4, atol=1e-5)
+    assert ref.meter.uplink == bat.meter.uplink
+    assert ref.meter.downlink == bat.meter.downlink
+    assert ref.meter.total == bat.meter.total
+    for a, b in infos:
+        _assert_rounds_match(a, b, strategy)
+    _assert_params_close(ref.global_params, bat.global_params)
+
+
+@pytest.mark.parametrize("layout", ["uniform", "ragged"])
+@pytest.mark.parametrize("vectorize", ["vmap", "scan"])
+@pytest.mark.parametrize("strategy,kw", [("fedbwo", {}),
+                                         ("fedavg", {}),
+                                         ("fedavg", {"client_ratio": 0.6})])
+def test_run_round_matches_reference_loop(strategy, kw, vectorize, layout):
+    """A block of one == the per-client loop: winner or participants,
+    scores, adopted weights and the byte ledger, under either client-
+    axis traversal, on uniform and on padded (ragged) client data."""
+    clients = _iid_clients() if layout == "uniform" else _ragged_clients()
+    server, ref = _pair(strategy, clients, vectorize, **kw)
+    assert server._engine.padded == (layout == "ragged")
+    _assert_rounds_match(ref.run_round(), server.run_round(), strategy)
+    _assert_params_close(ref.global_params, server.global_params)
+    np.testing.assert_array_equal(np.asarray(ref.rng),
+                                  np.asarray(server.rng))
+    assert ref.meter.uplink == server.meter.uplink
+    assert ref.meter.downlink == server.meter.downlink
+
+
+def test_run_round_records_no_block_timing():
+    """run_round is a block of one but stays out of the block ledger:
+    only run_block / run_pipelined record a BlockTiming."""
+    server, _ = _pair("fedbwo", _iid_clients())
+    server.run_round()
+    server.run_round()
+    assert server.meter.block_timings == []
+    assert len(server.meter.uplink) == 2
+    server.run_block(2)
+    assert [t.n_rounds for t in server.meter.block_timings] == [2]
 
 
 def test_vectorize_modes_agree():
@@ -65,12 +119,12 @@ def test_vectorize_modes_agree():
     clients = [batch_dataset(d, 8) for d in
                partition_iid(jax.random.PRNGKey(1), data, 3)]
     hp = ClientHP(local_epochs=1, mh_pop=4, mh_generations=2, lr=0.05)
-    stacked = stack_clients(clients)
+    stacked, _ = stack_clients(clients)
     params = task.init_params(jax.random.PRNGKey(9))
     keys = jax.random.split(jax.random.PRNGKey(3), 3)
     scores = {}
     for mode in ("vmap", "scan", "scan:2"):
-        fn = make_batched_fedx_round(task, hp, bwo(), vectorize=mode)
+        fn = jax.jit(_fedx_round_body(task, hp, bwo(), vectorize=mode))
         _, s, best = fn(params, stacked, None, keys)
         scores[mode] = np.asarray(s)
         assert int(best) == int(np.argmin(scores[mode]))
@@ -82,56 +136,25 @@ def test_vectorize_modes_agree():
 def test_resolve_vectorize():
     assert resolve_vectorize("auto", backend="cpu") == "scan"
     assert resolve_vectorize("auto", backend="tpu") == "vmap"
-    assert resolve_vectorize("unroll", backend="cpu") == "unroll"
     assert resolve_vectorize("scan:4", backend="cpu") == "scan"
-    with pytest.raises(ValueError):
-        resolve_vectorize("bogus")
-
-
-def test_auto_engine_keeps_conv_tasks_sequential_on_cpu():
-    """DESIGN.md §4: on CPU, conv tasks measured faster as per-client
-    dispatches — engine="auto" must detect the convs and stay
-    sequential, while engine="batched" still forces the batched path."""
-    from repro.core.engine import task_uses_conv
-    from repro.data import cnn_task, make_cifar_like, mlp_task
-    from repro.data.loader import client_batches
-    from repro.data.partition import partition_iid
-
-    train, _ = make_cifar_like(jax.random.PRNGKey(0), 40, 8)
-    clients = client_batches(
-        partition_iid(jax.random.PRNGKey(1), train, 2), 10)
-    sample = jax.tree.map(lambda a: a[0], clients[0])
-    conv, dense = cnn_task(), mlp_task()
-    assert task_uses_conv(conv, conv.init_params(jax.random.PRNGKey(2)),
-                          sample)
-    assert not task_uses_conv(dense,
-                              dense.init_params(jax.random.PRNGKey(2)),
-                              sample)
-    if jax.default_backend() == "cpu":
-        hp = ClientHP(local_epochs=1, mh_pop=2, mh_generations=1)
-        server = Server(conv, get_strategy("fedbwo"), hp, clients,
-                        jax.random.PRNGKey(3), engine="auto")
-        assert server.engine == "sequential"
-        server = Server(dense, get_strategy("fedbwo"), hp, clients,
-                        jax.random.PRNGKey(3), engine="auto")
-        assert server.engine == "batched"
+    for bad in ("bogus", "unroll"):
+        with pytest.raises(ValueError):
+            resolve_vectorize(bad)
 
 
 def test_ragged_clients_batch_via_pad_and_mask():
-    """Ragged batch counts no longer force the sequential fallback: the
-    engine pads to the longest client and masks (DESIGN.md §5)."""
+    """Ragged batch counts stack: the engine pads to the longest client
+    and masks (DESIGN.md §5)."""
     task = make_toy_task()
     clients = [batch_dataset(make_toy_data(jax.random.PRNGKey(i), n), 8)
                for i, n in enumerate([64, 96])]   # ragged: 8 vs 12 batches
-    assert stack_clients(clients) is None         # legacy exact stacking
-    stacked, mask = stack_clients(clients, pad=True)
+    stacked, mask = stack_clients(clients)
     assert jax.tree.leaves(stacked)[0].shape[0] == 2
     assert mask.shape == (2, 12)
     assert int(mask.sum()) == 8 + 12
     hp = ClientHP(local_epochs=1, mh_pop=4, mh_generations=2)
     server = Server(task, get_strategy("fedbwo"), hp, clients,
-                    jax.random.PRNGKey(3), engine="auto")
-    assert server.engine == "batched"
+                    jax.random.PRNGKey(3))
     assert server._engine.padded
     info = server.run_round()
     assert info["engine"] == "batched"
@@ -140,20 +163,17 @@ def test_ragged_clients_batch_via_pad_and_mask():
 
 def test_unstackable_clients_fall_back_to_sequential():
     """Mismatched trailing shapes (not just ragged batch counts) are
-    genuinely unstackable: auto falls back, batched raises."""
+    genuinely unstackable: there is no per-client fallback, so the
+    server raises the engine's ValueError."""
     task = make_toy_task()
     clients = [batch_dataset(make_toy_data(jax.random.PRNGKey(0), 64), 8),
                batch_dataset(make_toy_data(jax.random.PRNGKey(1), 64,
                                            d=16), 8)]   # feature dim 8 vs 16
-    assert stack_clients(clients) is None
-    assert stack_clients(clients, pad=True) == (None, None)
+    assert stack_clients(clients) == (None, None)
     hp = ClientHP(local_epochs=1, mh_pop=4, mh_generations=2)
-    server = Server(task, get_strategy("fedbwo"), hp, clients,
-                    jax.random.PRNGKey(3), engine="auto")
-    assert server.engine == "sequential"
-    with pytest.raises(ValueError):
+    with pytest.raises(ValueError, match="not stackable"):
         Server(task, get_strategy("fedbwo"), hp, clients,
-               jax.random.PRNGKey(3), engine="batched")
+               jax.random.PRNGKey(3))
 
 
 # --------------------------------------------------- memory shape ----
@@ -190,18 +210,18 @@ def test_fedx_scan_path_streams_weights():
     clients = [batch_dataset(make_toy_data(jax.random.PRNGKey(i), 8, d=d,
                                            classes=classes), 4)
                for i in range(n_clients)]
-    stacked = stack_clients(clients)
+    stacked, _ = stack_clients(clients)
     params = task.init_params(jax.random.PRNGKey(9))
     keys = jax.random.split(jax.random.PRNGKey(3), n_clients)
     hp = ClientHP(local_epochs=1, mh_pop=4, mh_generations=2,
                   fitness_batches=2)
     threshold = n_clients * n_params
 
-    fn = make_batched_fedx_round(task, hp, bwo(), vectorize="scan")
+    fn = _fedx_round_body(task, hp, bwo(), vectorize="scan")
     assert _max_intermediate_size(fn, params, stacked, None, keys) < threshold
 
     # positive control: the vmap path DOES stack all client weights,
     # so the detector is actually measuring what we think it measures
-    fn_vmap = make_batched_fedx_round(task, hp, bwo(), vectorize="vmap")
+    fn_vmap = _fedx_round_body(task, hp, bwo(), vectorize="vmap")
     assert _max_intermediate_size(fn_vmap, params, stacked, None,
                                   keys) >= threshold

@@ -36,13 +36,12 @@ def _eval_data():
     return make_toy_data(jax.random.PRNGKey(9), 16)
 
 
-def _server(strategy="fedbwo", sizes=SIZES, engine="batched",
-            vectorize="auto", **kw):
+def _server(strategy="fedbwo", sizes=SIZES, vectorize="auto", **kw):
     ratio = kw.pop("client_ratio", 1.0)
     return Server(make_toy_task(), get_strategy(strategy,
                                                 client_ratio=ratio),
                   _hp(vectorize), _clients(sizes), jax.random.PRNGKey(3),
-                  engine=engine, **kw)
+                  **kw)
 
 
 def _scopes(fn, *args):
@@ -87,7 +86,10 @@ def test_fused_block_with_eval_carries_every_scope():
 
 def test_fedavg_round_and_eval_carry_their_scopes():
     server = _server("fedavg")
-    assert _scopes(server._engine._round, *_round_args(server)) == {
+    eng = server._engine
+    args = (server.global_params, server.rng, eng.data, eng.mask, None,
+            jnp.asarray(0, jnp.int32))
+    assert _scopes(eng.fused_rounds(1), *args) == {
         tracing.LOCAL_SGD, tracing.BWO_FITNESS, tracing.SERVER_REDUCE}
     assert _scopes(server._eval, server.global_params,
                    _eval_data()) == {tracing.EVAL}
@@ -198,15 +200,6 @@ def test_fedavg_partial_fused_steps_follow_the_participants():
         EPOCHS * 2 * mask.shape[1])
 
 
-@pytest.mark.parametrize("strategy", ["fedbwo", "fedavg"])
-def test_sequential_engine_computes_only_real_steps(strategy):
-    server = _server(strategy, engine="sequential", client_ratio=1.0)
-    server.run_round()
-    real = EPOCHS * int(_batches().sum())
-    assert server.meter.sgd_steps == [(real, real)]
-    assert server.meter.sgd_step_summary()["real_frac"] == 1.0
-
-
 def test_uniform_clients_compute_no_padding():
     server = _server(sizes=(16, 16, 16))
     server.run_round()
@@ -214,12 +207,10 @@ def test_uniform_clients_compute_no_padding():
 
 
 # ----------------------------------------------------------- row ledger --
-@pytest.mark.parametrize("engine", ["batched", "sequential"])
-def test_bwo_rows_count_parent_rows_of_two_rounds(engine):
+def test_bwo_rows_count_parent_rows_of_two_rounds():
     """pop 3, 1 generation: BWO mutates n_par = 2 of 3 rows and draws
     rows 1..2 of its initial population, per client and round."""
-    server = _server(engine=engine)
-    assert server.engine == engine
+    server = _server()
     server.run_round()
     server.run_round()
     n = len(SIZES)
